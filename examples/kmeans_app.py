@@ -4,7 +4,10 @@ Shows the ``CollectiveApp`` / ``mapCollective`` programming model (Harp L4)
 on synthetic data; the production implementation with the fused MXU path
 and on-device iteration loop is ``harp_tpu.models.kmeans``.
 
-Run:  python examples/kmeans_app.py [--cpu8] [--n 4096] [--k 8] [--iters 10]
+Run:  python examples/kmeans_app.py [--n 4096] [--k 8] [--iters 10]
+
+Runs on whatever devices JAX finds; to simulate 8 workers on the host:
+JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8
 """
 
 import argparse
@@ -16,24 +19,13 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 def main():
     p = argparse.ArgumentParser()
-    p.add_argument("--cpu8", action="store_true",
-                   help="simulate 8 workers on host CPU")
     p.add_argument("--n", type=int, default=4096)
     p.add_argument("--d", type=int, default=16)
     p.add_argument("--k", type=int, default=8)
     p.add_argument("--iters", type=int, default=10)
     args = p.parse_args()
 
-    if args.cpu8:
-        os.environ["XLA_FLAGS"] = (
-            os.environ.get("XLA_FLAGS", "")
-            + " --xla_force_host_platform_device_count=8"
-        )
     import jax
-
-    if args.cpu8:
-        jax.config.update("jax_platforms", "cpu")
-
     import jax.numpy as jnp
     import numpy as np
     from jax.sharding import PartitionSpec as P

@@ -9,7 +9,10 @@ collective verbs: sequence-sharded activations, shard-local RoPE
 through the same `collective.allreduce` verb every app uses — one training
 step of a transformer layer whose sequence never fits on one chip.
 
-Run:  python examples/longctx_layer.py [--cpu8] [--seq 512] [--window 64]
+Run:  python examples/longctx_layer.py [--seq 512] [--window 64]
+
+Runs on whatever devices JAX finds; to simulate 8 workers on the host:
+JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8
 """
 
 import argparse
@@ -21,8 +24,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 def main():
     p = argparse.ArgumentParser()
-    p.add_argument("--cpu8", action="store_true",
-                   help="simulate 8 workers on host CPU")
     p.add_argument("--seq", type=int, default=512)
     p.add_argument("--heads", type=int, default=8)
     p.add_argument("--kv-heads", type=int, default=2)
@@ -33,16 +34,7 @@ def main():
     if args.steps < 1:
         p.error("--steps must be >= 1")
 
-    if args.cpu8:
-        os.environ["XLA_FLAGS"] = (
-            os.environ.get("XLA_FLAGS", "")
-            + " --xla_force_host_platform_device_count=8"
-        )
     import jax
-
-    if args.cpu8:
-        jax.config.update("jax_platforms", "cpu")
-
     import jax.numpy as jnp
     import numpy as np
     from jax.sharding import PartitionSpec as P

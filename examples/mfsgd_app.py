@@ -7,8 +7,11 @@ checkpoint/resume) is ``harp_tpu.models.mfsgd``; this example drives it
 through the ``CollectiveApp`` lifecycle the way a Harp ``mapCollective``
 program would.
 
-Run:  python examples/mfsgd_app.py [--cpu8] [--users 600] [--items 400]
+Run:  python examples/mfsgd_app.py [--users 600] [--items 400]
       [--nnz 20000] [--epochs 10]
+
+Runs on whatever devices JAX finds; to simulate 8 workers on the host:
+JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8
 """
 
 import argparse
@@ -20,8 +23,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 def main():
     p = argparse.ArgumentParser()
-    p.add_argument("--cpu8", action="store_true",
-                   help="simulate 8 workers on host CPU")
     p.add_argument("--users", type=int, default=600)
     p.add_argument("--items", type=int, default=400)
     p.add_argument("--nnz", type=int, default=20_000)
@@ -30,16 +31,6 @@ def main():
     args = p.parse_args()
     if args.epochs < 1:
         p.error("--epochs must be >= 1")
-
-    if args.cpu8:
-        os.environ["XLA_FLAGS"] = (
-            os.environ.get("XLA_FLAGS", "")
-            + " --xla_force_host_platform_device_count=8"
-        )
-    import jax
-
-    if args.cpu8:
-        jax.config.update("jax_platforms", "cpu")
 
     from harp_tpu import CollectiveApp, run_app
     from harp_tpu.models.mfsgd import MFSGD, MFSGDConfig, synthetic_ratings

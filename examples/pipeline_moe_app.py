@@ -13,7 +13,10 @@ carry the design notes) the way a Harp app composes verbs:
    routed by a gating argmax through ONE `regroup` (all-to-all) each
    way — checked against the dense host reference.
 
-Run:  python examples/pipeline_moe_app.py [--cpu8] [--steps 20]
+Run:  python examples/pipeline_moe_app.py [--steps 20]
+
+Runs on whatever devices JAX finds; to simulate 8 workers on the host:
+JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8
 """
 
 import argparse
@@ -25,8 +28,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 def main():
     p = argparse.ArgumentParser()
-    p.add_argument("--cpu8", action="store_true",
-                   help="simulate 8 workers on host CPU")
     p.add_argument("--steps", type=int, default=20)
     p.add_argument("--width", type=int, default=16)
     p.add_argument("--microbatches", type=int, default=4)
@@ -36,16 +37,7 @@ def main():
         p.error("--steps must be >= 2 (the descent check compares "
                 "first and last step)")
 
-    if args.cpu8:
-        os.environ["XLA_FLAGS"] = (
-            os.environ.get("XLA_FLAGS", "")
-            + " --xla_force_host_platform_device_count=8"
-        )
     import jax
-
-    if args.cpu8:
-        jax.config.update("jax_platforms", "cpu")
-
     import jax.numpy as jnp
     import numpy as np
     from jax.sharding import PartitionSpec as P

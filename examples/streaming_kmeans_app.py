@@ -8,7 +8,10 @@ verified against the device-resident ``kmeans.fit`` on the same data.
 The production north-star config swaps the toy shapes for
 ``--n 1000000000 --d 300 --k 1000`` and a real corpus.
 
-Run:  python examples/streaming_kmeans_app.py [--cpu8] [--n 20000]
+Run:  python examples/streaming_kmeans_app.py [--n 20000]
+
+Runs on whatever devices JAX finds; to simulate 8 workers on the host:
+JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=8
 """
 
 import argparse
@@ -21,24 +24,12 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 def main():
     p = argparse.ArgumentParser()
-    p.add_argument("--cpu8", action="store_true",
-                   help="simulate 8 workers on host CPU")
     p.add_argument("--n", type=int, default=20_000)
     p.add_argument("--d", type=int, default=16)
     p.add_argument("--k", type=int, default=8)
     p.add_argument("--iters", type=int, default=6)
     p.add_argument("--chunk", type=int, default=4096)
     args = p.parse_args()
-
-    if args.cpu8:
-        os.environ["XLA_FLAGS"] = (
-            os.environ.get("XLA_FLAGS", "")
-            + " --xla_force_host_platform_device_count=8"
-        )
-    import jax
-
-    if args.cpu8:
-        jax.config.update("jax_platforms", "cpu")
 
     import numpy as np
 

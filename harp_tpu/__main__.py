@@ -48,7 +48,7 @@ APPS = {
                "health sentinel: summarize kind:'health' findings, grade "
                "fresh bench rows, run the fail-closed model gate"),
     "lint": ("harp_tpu.analysis.cli",
-             "harplint: static relay-burner analysis (AST + jaxpr + Mosaic)"),
+             "harplint: static analysis (AST + jaxpr + Mosaic + threads)"),
     "plan": ("harp_tpu.plan.cli",
              "topology-aware collective planner over the lint byte sheets"),
     "predict": ("harp_tpu.perfmodel.cli",
@@ -72,8 +72,15 @@ def main(argv=None) -> int:
     if app not in APPS:
         print(f"unknown app {app!r}; run with --list", file=sys.stderr)
         return 2
-    mod = import_module(APPS[app][0])
-    return mod.main(rest) or 0
+    target = APPS[app][0]
+    if target.startswith(("harp_tpu.models.", "harp_tpu.serve.",
+                          "harp_tpu.benchmark")):
+        # the apps that compile for the chip share one persistent cache
+        # (the reader/analysis tools never reach a device)
+        from harp_tpu.utils import chip
+
+        chip.setup_compile_cache()
+    return import_module(target).main(rest) or 0
 
 
 if __name__ == "__main__":

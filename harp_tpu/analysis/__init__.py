@@ -1,4 +1,5 @@
-"""harplint — static relay-burner analysis for harp-tpu.
+"""harplint — static analysis for harp-tpu: the traps a CPU sandbox
+cannot execute its way into.
 
 Reference parity (SURVEY.md §6): Harp has no static analysis; its
 communication discipline is convention only.  This package machine-checks

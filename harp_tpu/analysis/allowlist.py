@@ -6,10 +6,10 @@ table per exception:
 .. code-block:: toml
 
     [[allow]]
-    rule = "HL001"
-    path = "harp_tpu/parallel/mesh.py"
-    match = "lax.psum(1, axis_name)"   # optional line-content anchor
-    reason = "old-jax axis_size shim; psum(1) is the documented fallback"
+    rule = "HL002"
+    path = "scripts/drive_check.py"
+    match = "jax.random.PRNGKey(_fr_seed)"   # optional line-content anchor
+    reason = "golden reference: the trap is the oracle here"
 
 ``rule`` + ``path`` are required and must match the violation exactly;
 ``match`` (optional) additionally requires the flagged source line to
@@ -23,13 +23,9 @@ by the CLI (``--prune`` lists them) so the file cannot silently rot.
 from __future__ import annotations
 
 import os
+import tomllib as _toml
 
 from harp_tpu.analysis import Violation
-
-try:
-    import tomllib as _toml
-except ModuleNotFoundError:  # pragma: no cover - py<3.11 (this image)
-    import tomli as _toml
 
 DEFAULT_PATH = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                             "allowlist.toml")

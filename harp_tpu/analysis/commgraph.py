@@ -156,16 +156,13 @@ def _eqn_axis(eqn) -> str:
 def _eqn_site(eqn) -> str:
     """The eqn's user call site, under the SAME frame-exclusion rules as
     the CommLedger's ``record_comm`` — the whole point of the matcher."""
+    from jax._src import source_info_util
+
     from harp_tpu.utils.telemetry import is_ledger_user_frame, site_key
 
-    try:
-        from jax._src import source_info_util
-
-        for f in source_info_util.user_frames(eqn.source_info):
-            if is_ledger_user_frame(f.file_name):
-                return site_key(f.file_name, f.start_line)
-    except Exception:  # pragma: no cover - jax internals moved
-        pass
+    for f in source_info_util.user_frames(eqn.source_info.traceback):
+        if is_ledger_user_frame(f.file_name):
+            return site_key(f.file_name, f.start_line)
     return "?:0"
 
 
@@ -276,18 +273,12 @@ class _Walker:
 def _donation_info(traced) -> tuple[list[int], list[str]]:
     """Flat donated-arg indices + avals from a ``.trace()`` result's
     ``args_info`` (ArgInfo carries the ``donated`` flag)."""
-    try:
-        import jax
+    import jax
 
-        flat = jax.tree.leaves(traced.args_info)
-        idx = [i for i, a in enumerate(flat)
-               if bool(getattr(a, "donated", False))]
-        # ArgInfo stores its aval as _aval (no public accessor)
-        avals = [getattr(flat[i], "aval", None) or flat[i]._aval
-                 for i in idx]
-        return idx, [a.str_short() for a in avals]
-    except Exception:  # pragma: no cover - older jax without args_info
-        return [], []
+    flat = jax.tree.leaves(traced.args_info)
+    idx = [i for i, a in enumerate(flat) if a.donated]
+    # ArgInfo stores its aval as _aval (no public accessor)
+    return idx, [flat[i]._aval.str_short() for i in idx]
 
 
 def extract(name: str, fn, args) -> CommGraph:

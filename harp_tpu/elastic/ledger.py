@@ -23,7 +23,7 @@ Rows are recorded unconditionally (they describe ACTIONS, not
 observations — the zero-cost-when-disabled contract governs the
 sentinel that *triggers* them, not the evidence that they happened) and
 exported through ``telemetry.export`` with the flight recorder's
-provenance stamp, so a CPU-sim drill can never read as relay evidence
+provenance stamp, so a CPU-sim drill can never read as chip evidence
 (the invariant-4 inversion guard).
 """
 

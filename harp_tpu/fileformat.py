@@ -125,7 +125,7 @@ def load_sharded_csv(pattern_or_paths, num_workers: int,
     # order, so the per-worker concatenation — and the output — is
     # bit-identical to the old serial loop.  compiles=0 under the
     # warn-mode budget: a loader that silently traces a program would
-    # be a relay trap at ingest time.
+    # pay a compile at ingest time.
     flat = [(w, p) for w, files in enumerate(splits) for p in files]
     loaded: list = [None] * len(flat)
     if flat:

@@ -18,10 +18,10 @@ freshly measured bench row is judged against two baselines —
 blocking the next sprint pruning: :func:`model_gate` re-runs the
 perfmodel's full self-grade against ALL committed evidence and
 ``measure_all.py --predicted-top`` REFUSES (fail closed) when it fails
-— a model invalidated by fresh silicon evidence cannot prune the sprint
-that would re-measure it.  ``measure_on_relay.sh`` runs
-``python -m harp_tpu health --grade-model`` right after a sprint lands
-new rows, so the verdict is committed evidence, not a scrolled warning.
+— a model invalidated by fresh silicon evidence cannot prune the run
+that would re-measure it.  Run ``python -m harp_tpu health
+--grade-model`` right after a measurement run lands new rows, so the
+verdict is committed evidence, not a scrolled warning.
 """
 
 from __future__ import annotations

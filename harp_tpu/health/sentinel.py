@@ -36,7 +36,7 @@ Four detector families, each grounded in a landed mechanism:
   recorder WARN-mode budget violations (``flightrec.budget`` /
   ``SteadyState``, the bench/production action) aggregate into one row
   per site (violation count + worst offender) instead of scrolling past
-  as RuntimeWarnings — a relay trap that fires mid-sprint finally
+  as RuntimeWarnings — a driver-loop trap that fires mid-run finally
   leaves committed evidence.
 - **evidence regression** (:mod:`harp_tpu.health.grade`) — fresh bench
   rows judged against the committed incumbent and the perfmodel's
@@ -504,7 +504,7 @@ def reset() -> None:
 def export_jsonl(fh) -> None:
     """Append health rows (telemetry.export calls this); stamped with
     the flight recorder's provenance triple — a CPU-sim finding must
-    never read as relay evidence (the invariant-4 inversion guard)."""
+    never read as chip evidence (the invariant-4 inversion guard)."""
     if not monitor._rows:
         return
     from harp_tpu.utils import flightrec

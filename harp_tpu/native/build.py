@@ -4,6 +4,7 @@ from __future__ import annotations
 
 import ctypes
 import hashlib
+import logging
 import os
 import shutil
 import subprocess
@@ -13,6 +14,8 @@ _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "loader.cpp")
 _LIB = None
 _TRIED = False
+
+log = logging.getLogger("harp_tpu.native")
 
 
 def _so_path() -> str:
@@ -40,7 +43,9 @@ def native_available() -> bool:
 
 
 def load_native():
-    """Return the ctypes library, building it if needed; None if impossible."""
+    """Return the ctypes library, building it if needed; None if
+    impossible — the callers then run their numpy parsers (same results,
+    slower).  Which of the two runs is logged once per process."""
     global _LIB, _TRIED
     if _LIB is not None or _TRIED:
         return _LIB
@@ -48,6 +53,8 @@ def load_native():
     so = _so_path()
     if not os.path.exists(so):
         if shutil.which("g++") is None:
+            log.warning("no g++ and no built %s: the numpy parsers run",
+                        os.path.basename(so))
             return None
         # build to a temp file then atomically rename (parallel-safe)
         fd, tmp = tempfile.mkstemp(suffix=".so", dir=_DIR)
@@ -57,9 +64,13 @@ def load_native():
         try:
             subprocess.run(cmd, check=True, capture_output=True)
             os.replace(tmp, so)
-        except subprocess.CalledProcessError:
+        except subprocess.CalledProcessError as e:
             os.unlink(tmp)
+            log.warning("g++ could not build loader.cpp, the numpy "
+                        "parsers run: %s",
+                        e.stderr.decode(errors="replace")[-500:])
             return None
+    log.info("native parsers: %s", os.path.basename(so))
     lib = ctypes.CDLL(so)
     i64p = ctypes.POINTER(ctypes.c_int64)
     lib.harp_count_rows.argtypes = [ctypes.c_char_p, ctypes.c_int, i64p, i64p]

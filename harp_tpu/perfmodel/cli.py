@@ -1,7 +1,7 @@
 """``python -m harp_tpu predict`` — price configs and programs offline.
 
-Three modes, all CPU-only (a *predictor* must never touch — or hang on
-— the relay, exactly like the lint and plan CLIs):
+Three modes, all CPU-only (a *predictor* never touches the chip,
+exactly like the lint and plan CLIs):
 
 - default / ``--json``: one provenance-stamped ``kind: "model"`` row
   per registered byte-sheet program (the CommGraph extraction the lint

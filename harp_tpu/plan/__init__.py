@@ -30,6 +30,7 @@ from harp_tpu.plan.topology import (
     sim_ring,
     single_chip,
     v4_32,
+    v5e_2x2,
 )
 
 __all__ = [
@@ -49,4 +50,5 @@ __all__ = [
     "sim_ring",
     "single_chip",
     "v4_32",
+    "v5e_2x2",
 ]

@@ -12,10 +12,10 @@ its host grouping, and two **link classes** (intra-host ICI vs.
 inter-host ICI/DCN) with declared-or-probed rates; :meth:`Topology.
 cost_s` prices one collective site as ``bytes × hops / rate`` per link
 class — deliberately a *ranking* model (which schedule is cheapest
-here), not a wall-clock predictor (ROADMAP's relay-free autotuning item
-is the calibration story).
+here), not a wall-clock predictor (calibration against chip rows is
+ROADMAP Design 6).
 
-Three named instances are frozen into the plan-row vocabulary
+Four named instances are frozen into the plan-row vocabulary
 (``scripts/check_jsonl.py`` invariant 10 — a plan row naming an unknown
 topology is not evidence about this repo's meshes):
 
@@ -23,10 +23,12 @@ topology is not evidence about this repo's meshes):
 - ``sim_ring_8``    — the 8-simulated-CPU-worker test mesh (declared
   loopback rate; absolute numbers meaningless, *ratios* still rank
   schedules identically, which is all the fail-closed planner uses).
+- ``v5e_2x2``       — one host of four v5e chips (the four-chip machine
+  chip_smoke.py runs on): one link class, declared ICI rate.
 - ``v4_32``         — the north-star v4-32 slice: 16 chips over 4 hosts
   (4 chips/host), declared ICI rates with the inter-host class slower
   (the hierarchical-psum win condition).  Rates are DECLARED
-  assumptions until a relay window probes them (:func:`probed`), and
+  assumptions until :func:`probed` measures them on the mesh, and
   every consumer stamps ``rates_source`` so a declared ranking can
   never masquerade as a measured one.
 """
@@ -36,16 +38,17 @@ from __future__ import annotations
 import dataclasses
 
 #: the frozen topology-tag vocabulary (check_jsonl invariant 10 pins it)
-TOPOLOGY_NAMES = ("single_chip", "sim_ring_8", "v4_32")
+TOPOLOGY_NAMES = ("single_chip", "sim_ring_8", "v5e_2x2", "v4_32")
 
 #: declared per-chip HBM by topology tag (PR 19, the memory spine's
 #: denominator): v4 ships 32 GiB HBM2 per chip (public spec); the CPU
 #: sim targets model a v5e-class 16 GiB so headroom_frac is meaningful
-#: on the test mesh.  DECLARED, like the link rates — a relay window
-#: can overwrite via memrec.set_hbm_capacity.
+#: on the test mesh.  DECLARED, like the link rates — on a chip the
+#: memory ledger reads the device's own ``bytes_limit`` instead.
 HBM_BYTES_PER_CHIP = {
     "single_chip": 16 << 30,
     "sim_ring_8": 16 << 30,
+    "v5e_2x2": 16 << 30,
     "v4_32": 32 << 30,
 }
 
@@ -155,21 +158,31 @@ def sim_ring(n: int = 8) -> Topology:
     return Topology(f"sim_ring_{n}", n, n, intra_gbs=10.0, inter_gbs=10.0)
 
 
+def v5e_2x2() -> Topology:
+    """One host of four v5e chips on a 2×2 ICI mesh.  DECLARED rate, not
+    a measurement (2026-09-26, no probe run): Google Cloud
+    documentation, "TPU v5e", gives 1,600 Gbit/s of interconnect per
+    chip = 4 links × 50 GB/s; a 1-D worker ring rides one link per
+    direction — :func:`probed` before believing absolute seconds."""
+    return Topology("v5e_2x2", 4, 4, intra_gbs=50.0, inter_gbs=50.0)
+
+
 def v4_32() -> Topology:
     """The north-star v4-32 slice: 16 chips over 4 hosts.  DECLARED
     rates, not measurements (2026-08-04, no chip touched: ~45 GB/s/dir
     intra-host ICI from the public v4 ICI spec, ~25 GB/s effective
     across the host-boundary torus links — the BASELINE.md scaling
-    section's assumption class) — probe on a live relay
-    (:func:`probed`) before believing absolute seconds."""
+    section's assumption class) — :func:`probed` before believing
+    absolute seconds."""
     return Topology("v4_32", 16, 4, intra_gbs=45.0, inter_gbs=25.0)
 
 
 def detect(mesh=None) -> Topology:
     """The topology of the ACTIVE mesh: single_chip for one device, the
-    sim ring for the CPU backend, v4_32 for a 16-chip TPU mesh; any
-    other shape falls back to a one-host ring of the right size (a
-    conservative price list — no inter-host class to mis-model)."""
+    sim ring for the CPU backend, v5e_2x2 / v4_32 for a 4- / 16-chip TPU
+    mesh.  A TPU mesh of any other size has no price list and raises —
+    pricing real chips at the CPU loopback's declared rate would be a
+    wrong number, not a conservative one."""
     import jax
 
     from harp_tpu.parallel.mesh import current_mesh
@@ -178,18 +191,22 @@ def detect(mesh=None) -> Topology:
     n = mesh.num_workers
     if n == 1:
         return single_chip()
-    backend = jax.default_backend()
-    if backend == "tpu" and n == 16:
-        return v4_32()
-    return sim_ring(n)
+    if jax.default_backend() != "tpu":
+        return sim_ring(n)
+    by_size = {4: v5e_2x2, 16: v4_32}
+    if n not in by_size:
+        raise ValueError(
+            f"plan.topology: no declared topology for a {n}-chip TPU mesh "
+            f"(known: {sorted(by_size)}) — add one with its link rates "
+            "and their source")
+    return by_size[n]()
 
 
 def probed(topo: Topology, mesh=None, size_mb: float = 4.0) -> Topology:
     """Replace a topology's DECLARED intra-class rate with one measured
     through :func:`harp_tpu.benchmark.bench_verb` (allreduce at
     ``size_mb``) — the probed-rates half of the ISSUE's "probed/declared"
-    contract.  Runs wherever the mesh runs (CPU sim included); on the
-    relay, probe inside a watched window only (CLAUDE.md).  The
+    contract.  Runs wherever the mesh runs (CPU sim included).  The
     inter-host rate keeps its declared value until a multi-host probe
     exists — the stamp says ``probed`` either way so consumers can ask.
     """

@@ -1,7 +1,7 @@
 """Request micro-batcher — a fixed ladder of padded batch shapes.
 
-A serving loop that traces a fresh program per request size would pay the
-~140 ms remote-compile trap on every novel batch (CLAUDE.md relay traps);
+A serving loop that traces a fresh program per request size would pay a
+compile on every novel batch (CLAUDE.md driver-loop traps);
 one that pads everything to the maximum batch would waste most of its
 compute on padding at low load.  The ladder is the standard middle
 ground: requests coalesce into the smallest rung that fits, so the

@@ -29,7 +29,7 @@ interleave with in-flight data responses, unlike the stdio plane's
 flush-first rule), ``{"cmd": "quit"}`` (or EOF) closes that connection
 once its outstanding responses have flushed, ``{"cmd": "shutdown"}``
 drains the pipeline and stops the whole server — scripts/drive_check.py
-uses it to exercise the transport end to end without a relay.
+uses it to exercise the transport end to end without a chip.
 
 Failure behavior (PR 10, the fault plane): a client that disconnects —
 cleanly or mid-flight with responses outstanding — costs exactly its

@@ -57,8 +57,8 @@ _PROVENANCE: dict | None = None
 def _provenance() -> dict:
     """backend/date/jax/commit stamp, computed once per process.
 
-    Round 5 (review finding): `flip_decision.latest_rows` and bench.py's
-    `_last_measured` exclude CPU-sim evidence via ``backend == "cpu"`` —
+    Round 5 (review finding): `flip_decision.latest_rows` excludes
+    CPU-sim evidence via ``backend == "cpu"`` —
     a config-keyed CLI row WITHOUT the field (e.g. the teed
     `kmeans_stream_cli` 1B record) would pass as TPU evidence, exactly
     the CPU-inversion failure those filters exist for.  Stamping here
@@ -94,9 +94,9 @@ def _provenance() -> dict:
 def benchmark_json(config: str, result: dict) -> str:
     """One JSON line for a CLI benchmark result.
 
-    Every app CLI prints its benchmark dict through this (round 4): the
-    relay sprint tees CLI output into BENCH_local.jsonl, and a Python
-    dict repr there is an unparseable line every JSONL reader must skip.
+    Every app CLI prints its benchmark dict through this (round 4): CLI
+    output gets teed into BENCH_local.jsonl, and a Python dict repr
+    there is an unparseable line every JSONL reader must skip.
     numpy scalars coerce to plain Python so json never chokes.  Rows
     carry the same provenance fields measure_all stamps (round 5), so
     downstream TPU-evidence filters can classify them.

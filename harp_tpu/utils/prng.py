@@ -1,9 +1,9 @@
 """Raw threefry key bits — the PRNGKey-specialization trap, fixed at the
 source.
 
-CLAUDE.md relay trap: ``jax.random.PRNGKey(python_int)`` specializes on
+CLAUDE.md trap: ``jax.random.PRNGKey(python_int)`` specializes on
 the int — a step function that bakes a fresh seed into its traced program
-pays a fresh (~140 ms remote) compile per seed.  The fix is always the
+pays a fresh compile per seed.  The fix is always the
 same two lines: build the key's raw uint32[2] bits with numpy (no jax
 computation at all), and pass them *as an argument* so the compiled
 program is seed-independent.  Before this module each driver open-coded

@@ -387,7 +387,7 @@ class ReqTracer:
 
     def export_jsonl(self, fh) -> None:
         """Provenance-stamped trace rows (telemetry.export rides this —
-        a CPU-sim request timeline must never read as relay latency
+        a CPU-sim request timeline must never read as chip latency
         evidence, same inversion guard as the flight recorder)."""
         rows = self.rows()
         if not rows:

@@ -11,7 +11,8 @@ The models are deliberately lower-bound byte models (inputs read once,
 outputs written once — XLA fusion can't do better) and exact FLOP
 counts for the dominant kernels; percentages can therefore slightly
 UNDERSTATE achieved bandwidth but never flatter it.  Peaks are the
-public TPU v5e datasheet figures.
+published per-chip figures, keyed by the ``device_kind`` JAX reports; a
+device that is not in the table is an error, not a default.
 
 PR 13: these work models are also the FLOOR layer of the predictive
 cost model (:mod:`harp_tpu.perfmodel.model`), which adds per-variant
@@ -22,13 +23,36 @@ grading (tier-1) re-checks every committed ranking it feeds.
 
 from __future__ import annotations
 
-# Public v5e (v5 lite) per-chip datasheet peaks.
-V5E_PEAKS = {
-    "bf16_flops": 197e12,   # MXU bf16 FLOP/s
-    "int8_ops": 394e12,     # MXU int8 OP/s
-    "f32_flops": 49.25e12,  # bf16/4: HIGHEST-precision f32 (3+ MXU passes)
-    "hbm_gbs": 819e9,       # HBM bandwidth, bytes/s
+V5E = "TPU v5 lite"  # jax.devices()[0].device_kind on a v5e
+
+# Per-chip peaks by device_kind.
+PEAKS = {
+    # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB HBM
+    # at 819 GB/s.  int8 is 2× the bf16 MXU rate (the page rounds it to
+    # 393); f32 is the bf16 rate / 4 (HIGHEST precision, 3+ MXU passes).
+    V5E: {
+        "bf16_flops": 197e12,   # MXU bf16 FLOP/s
+        "int8_ops": 394e12,     # MXU int8 OP/s
+        "f32_flops": 49.25e12,
+        "hbm_gbs": 819e9,       # HBM bandwidth, bytes/s
+    },
 }
+V5E_PEAKS = PEAKS[V5E]  # the perfmodel prices a v5e by name
+
+
+def peaks_for(device_kind: str) -> dict | None:
+    """The peak table of ``device_kind``; None on the CPU (a CPU run has
+    no roofline), ValueError for any other kind the table lacks."""
+    if device_kind == "cpu":
+        return None
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"roofline: no published peaks for device kind "
+            f"{device_kind!r} — add it to roofline.PEAKS with its "
+            "source") from None
+
 
 # Matmul-dominated configs with f32 arrays compare against the bf16 peak:
 # jax's DEFAULT matmul precision executes f32 dots as single bf16 MXU
@@ -53,7 +77,7 @@ def _kmeans_work(r):
     n, d, k = r["n"], r["d"], r["k"]
     dsize = 1 if r.get("quantize") == "int8" else 4
     # value check, not key presence: the streaming benchmark reports
-    # ex_gen=None when gen time swamps the epoch (relay noise)
+    # ex_gen=None when gen time swamps the epoch (timing noise)
     metric = ("iters_per_sec_ex_gen"
               if r.get("iters_per_sec_ex_gen") is not None
               else "iters_per_sec")
@@ -141,13 +165,19 @@ WORK_MODELS = {
 }
 
 
-def annotate(config: str, result: dict, peaks: dict = V5E_PEAKS) -> dict:
+def annotate(config: str, result: dict, device_kind: str) -> dict:
     """Add roofline fields to a benchmark result dict (returns a copy).
 
     Adds ``achieved_tflops``, ``achieved_gbs``, ``pct_peak_flops``,
     ``pct_peak_bw`` and ``bound`` ("compute" | "memory" — whichever wall
-    is closer).  Configs without a work model pass through unchanged.
+    is closer) against the peaks of ``device_kind`` (the kind the result
+    was measured on).  Adds nothing on ``"cpu"`` and raises on a kind
+    :data:`PEAKS` lacks; configs without a work model pass through
+    unchanged.
     """
+    peaks = peaks_for(device_kind)
+    if peaks is None:
+        return dict(result)
     model = WORK_MODELS.get(config)
     if model is None:
         return dict(result)

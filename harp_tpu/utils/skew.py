@@ -326,14 +326,15 @@ def suggest_rebalance(phase: str) -> dict | None:
     return ledger.suggest_rebalance(phase)
 
 
-def wasted_pct_of_peak(config: str, result: dict,
-                       phase: str) -> float | None:
+def wasted_pct_of_peak(config: str, result: dict, phase: str,
+                       device_kind: str) -> float | None:
     """Skew waste stated in percent-of-peak (the roofline composition).
 
-    ``roofline.annotate(config, result)`` gives the percent of datasheet
-    peak the measured rate achieves; the phase's wasted fraction says how
-    much of that a balanced partition would reclaim.  None when either
-    half is unavailable (no work model, phase unknown, zero work).
+    ``roofline.annotate`` gives the percent of datasheet peak the
+    measured rate achieves on ``device_kind``; the phase's wasted
+    fraction says how much of that a balanced partition would reclaim.
+    None when either half is unavailable (no work model, a CPU run,
+    phase unknown, zero work).
     """
     from harp_tpu.utils import roofline
 
@@ -343,7 +344,7 @@ def wasted_pct_of_peak(config: str, result: dict,
     imb = SkewLedger._imbalance(rec)
     if not imb.get("wasted_frac"):
         return None
-    ann = roofline.annotate(config, result)
+    ann = roofline.annotate(config, result, device_kind)
     pct = ann.get("pct_peak_flops")
     if pct is None:
         return None
@@ -353,7 +354,7 @@ def wasted_pct_of_peak(config: str, result: dict,
 def export_jsonl(fh) -> None:
     """Append skew rows (telemetry.export calls this); stamped with the
     flight recorder's provenance triple — a CPU-sim work sheet must never
-    read as relay evidence (same inversion guard as invariant 4)."""
+    read as chip evidence (same inversion guard as invariant 4)."""
     if not ledger._phases:
         return
     from harp_tpu.utils import flightrec

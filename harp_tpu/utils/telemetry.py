@@ -40,8 +40,8 @@ merge both.
 
 Everything is **zero-cost when disabled** (the default): ``record_comm``
 returns before touching the tree, ``span`` yields without bookkeeping, and
-neither ever does per-element work — so telemetry can stay on for relay
-sprints without perturbing BENCH numbers.  Enable with ``HARP_TELEMETRY=1``
+neither ever does per-element work — so telemetry can stay on for
+measurement runs without perturbing BENCH numbers.  Enable with ``HARP_TELEMETRY=1``
 in the environment or :func:`enable` in code; ``HARP_TELEMETRY_OUT=<path>``
 makes instrumented CLIs export the raw JSONL for ``python -m harp_tpu
 report``.

@@ -1,6 +1,6 @@
 """Shared benchmark-shape presets for the measurement scripts.
 
-THE smoke shapes, in one place: `measure_all.py`, `profile_on_relay.py`
+THE smoke shapes, in one place: `measure_all.py`, `profile_configs.py`
 and `sweep_pallas.py` all shrink the graded configs to these for fast
 CPU-safe passes — a shape change must hit all three identically or the
 scripts silently measure different programs (review finding, round 3).

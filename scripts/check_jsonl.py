@@ -4,7 +4,7 @@
 Two invariants, enforced as a tier-1 test (tests/test_check_jsonl.py) and
 runnable standalone (``python scripts/check_jsonl.py [--repo DIR]``):
 
-1. **Every line parses as JSON.**  The relay sprint tees CLI stdout into
+1. **Every line parses as JSON.**  The measurement run tees CLI stdout into
    these files; a Python dict repr or a line truncated by a killed sprint
    is a record every downstream reader silently skips — make it loud.
 
@@ -12,8 +12,8 @@ runnable standalone (``python scripts/check_jsonl.py [--repo DIR]``):
    ``commit`` — the fields :func:`harp_tpu.utils.metrics._provenance`
    writes).  This is the CPU-inversion guard from metrics.py: a
    config-keyed row WITHOUT ``backend`` can pass downstream TPU-evidence
-   filters (``flip_decision.latest_rows``, bench.py ``_last_measured``
-   exclude only ``backend == "cpu"``), so an unstamped CPU record reads
+   filters (``flip_decision.latest_rows`` excludes only
+   ``backend == "cpu"``), so an unstamped CPU record reads
    as silicon evidence.  Rows committed before the stamp existed are
    grandfathered BY LINE INDEX (the history is append-only; reannotate.py
    rewrites rows in place), so every row appended after this check landed
@@ -30,7 +30,7 @@ not bench evidence: they get the parse check only — plus invariants 3/4:
 4. **Flight-recorder rows are coherent evidence** (any file): a ``kind:
    "compile"`` / ``kind: "transfer"`` row must parse, carry the
    backend/date/commit provenance stamp (a CPU-sim compile count must
-   never read as relay evidence — the same inversion guard as check 2),
+   never read as chip evidence — the same inversion guard as check 2),
    and its counters (count/dur/total_s/bytes/calls) must be non-negative
    numbers, with a compile row's cumulative ``count``/``total_s``
    monotone non-decreasing down the file (a decrease means two runs'
@@ -40,7 +40,7 @@ not bench evidence: they get the parse check only — plus invariants 3/4:
 5. **Skew rows are coherent load evidence** (any file): a ``kind:
    "skew"`` row (the SkewLedger export, :mod:`harp_tpu.utils.skew`) must
    carry the provenance stamp (a CPU-sim load sheet must never read as
-   relay evidence), its per-worker ``work`` counts must be non-negative
+   chip evidence), its per-worker ``work`` counts must be non-negative
    numbers that SUM to the row's ``total`` (a mismatch means the
    imbalance ratio describes a different workload than the total
    claims), and ``padding_frac`` — when present — must lie in [0, 1].
@@ -84,7 +84,7 @@ not bench evidence: they get the parse check only — plus invariants 3/4:
 8. **Ingest rows are coherent streaming evidence** (any file): a ``kind:
    "ingest"`` row (``kmeans_stream.benchmark_ingest`` /
    ``scripts/bench_ingest.py``, PR 8) must carry the provenance stamp
-   (a CPU host-chain rate must never read as relay-tunnel evidence),
+   (a CPU host-chain rate must never read as chip evidence),
    its ``overlap_efficiency`` (the host pipeline's stage-overlap score)
    must lie in [0, 1], and its rates must be positive:
    ``host_gb_per_sec > 0`` and ``points_per_sec > 0`` — a zero or
@@ -122,7 +122,7 @@ not bench evidence: they get the parse check only — plus invariants 3/4:
 11. **Trace rows are a complete causal timeline** (any file): a ``kind:
     "trace"`` row (``harp_tpu.utils.reqtrace`` — ``telemetry.export`` /
     ``export_timeline``, PR 12) must carry the provenance stamp (a
-    CPU-sim request timeline must never read as relay latency
+    CPU-sim request timeline must never read as chip latency
     evidence), declare a known row shape (``ev`` ∈
     ``KNOWN_TRACE_EVS``), and carry a numeric non-negative ``ts`` that
     is MONOTONE non-decreasing down the file (the exporters sort — a
@@ -161,7 +161,7 @@ not bench evidence: they get the parse check only — plus invariants 3/4:
     ``kind: "health"`` row (the PR-14 sentinel — ``harp_tpu.health``,
     exported by ``telemetry.export`` / emitted by ``python -m harp_tpu
     health --grade-model``) must carry the provenance stamp (a CPU-sim
-    finding must never read as relay degradation evidence), name a
+    finding must never read as chip degradation evidence), name a
     registered detector and severity (``KNOWN_HEALTH_DETECTORS`` /
     ``KNOWN_HEALTH_SEVERITIES`` — frozen standalone and sync-pinned
     against ``harp_tpu.health`` by tests), carry non-negative integer
@@ -179,7 +179,7 @@ not bench evidence: they get the parse check only — plus invariants 3/4:
     ``kind:"elastic"`` row (the PR-15 acting half —
     :mod:`harp_tpu.elastic`, exported by ``telemetry.export``) must
     carry the provenance stamp (a CPU-sim drill must never read as
-    relay elasticity evidence), name an event from the frozen
+    chip elasticity evidence), name an event from the frozen
     vocabulary (``KNOWN_ELASTIC_EVENTS``: rebalance / shrink / resume —
     sync-pinned against ``harp_tpu.elastic.EVENTS`` by
     tests/test_check_jsonl.py), carry per-worker load lists of
@@ -218,7 +218,7 @@ not bench evidence: they get the parse check only — plus invariants 3/4:
     file): a ``kind:"steptrace"`` row (the PR-18 superstep flightpath —
     :mod:`harp_tpu.utils.steptrace`, exported by ``telemetry.export`` /
     ``export_timeline``) must carry the provenance stamp (a CPU-sim
-    training timeline must never read as relay evidence), declare a
+    training timeline must never read as chip evidence), declare a
     known row shape (``ev`` ∈ ``KNOWN_STEPTRACE_EVS``), and carry a
     numeric non-negative ``ts`` MONOTONE non-decreasing across the
     file's steptrace rows.  Every superstep span must terminate with an
@@ -275,8 +275,9 @@ import sys
 
 # line counts at the commit where this check landed (2026-08-04); rows up
 # to these indices predate the provenance stamp and are exempt from check
-# 2 (never from check 1).  Bump ONLY when deliberately rewriting history.
-GRANDFATHERED = {"BENCH_local.jsonl": 73}
+# 2 (never from check 1).  Bump ONLY when deliberately rewriting history
+# (73 → 72 in PR 21, which removed legacy row 4, a record with no number).
+GRANDFATHERED = {"BENCH_local.jsonl": 72}
 
 PARSE_ONLY = ("PROFILE_local.jsonl", "FLIP_DECISIONS.jsonl",
               "PROFILE_attrib.jsonl")
@@ -615,7 +616,7 @@ def _check_degraded_serve_row(name: str, i: int, row: dict) -> list[str]:
 # lint rule ids and sync-pinned by tests/test_plan.py against
 # harp_tpu.plan (topology.TOPOLOGY_NAMES / planner.SCHEDULES /
 # planner.predicted_bytes)
-KNOWN_PLAN_TOPOLOGIES = ("single_chip", "sim_ring_8", "v4_32")
+KNOWN_PLAN_TOPOLOGIES = ("single_chip", "sim_ring_8", "v5e_2x2", "v4_32")
 KNOWN_PLAN_SCHEDULES = ("keep", "hier_psum", "chunked_pipeline",
                         "wire_bf16", "wire_int8")
 

@@ -363,8 +363,7 @@ def decide(candidate_row: dict, incumbent_row: dict, spec: dict) -> dict:
 def latest_rows(path: str) -> dict:
     """config → last full-shape non-error TPU row (later lines win).
 
-    CPU-sim rows are skipped like bench.py's ``_last_measured`` does:
-    relative CPU speeds are explicitly non-predictive of TPU here
+    CPU-sim rows are skipped: relative CPU speeds are explicitly non-predictive of TPU here
     (BASELINE.md's onehot-vs-segment 7.8× CPU inversion), so they must
     never authorize a flip.
     """

@@ -12,13 +12,12 @@ Read the output asking two questions per config:
    memory-bound scatter/gather) actually dominate the trace?
 2. is there an op eating >10% that the model has no term for?
 
-`./scripts/measure_on_relay.sh` runs this AFTER the sweep (bounded
-2400 s; a relay death then costs only the partial PROFILE_local).
-Works on CPU too for plumbing checks (--smoke --platform cpu), but CPU
-traces have no device track so compile/host events appear in the table
-(op_breakdown's device filter only engages on TPU, where each
-benchmark's internal compile lands on the host track and the op table
-is pure device time).
+Runs on the chip (one process; it is the process that holds the chip
+that can trace it).  ``--smoke`` under ``JAX_PLATFORMS=cpu`` checks the
+plumbing only: CPU traces have no device track, so compile/host events
+appear in the table (op_breakdown's device filter only engages on TPU,
+where each benchmark's internal compile lands on the host track and the
+op table is pure device time).
 """
 
 import argparse
@@ -84,16 +83,16 @@ def main(argv=None):
     p.add_argument("--smoke", action="store_true")
     p.add_argument("--top", type=int, default=15)
     p.add_argument("--only", nargs="+", default=None)
-    p.add_argument("--platform", choices=["cpu"], default=None)
     args = p.parse_args(argv)
-    if args.platform == "cpu":
-        import jax
 
-        jax.config.update("jax_platforms", "cpu")
-
+    from harp_tpu.utils import chip
     from harp_tpu.utils.profiling import op_breakdown, trace
     from harp_tpu.utils.roofline import annotate
     from harp_tpu.utils.timing import HangWatchdog
+
+    chip.setup_compile_cache()
+    device = (chip.device_info() if args.smoke
+              else chip.require_tpu("profile_configs.py"))
 
     sink = open(args.out, "a")
     watchdog = HangWatchdog(on_fire=lambda what: (
@@ -114,16 +113,17 @@ def main(argv=None):
             rec = {"config": name, "error": f"{type(e).__name__}: {e}",
                    "trace_dir": logdir}
         else:
-            # an empty op table (relay died mid-trace, all spans filtered)
-            # is a per-config error, not a sweep-aborting ZeroDivision
+            # an empty op table (all spans filtered) is a per-config
+            # error, not a sweep-aborting ZeroDivision
             traced = sum(t for _, t in ops) or 1.0
             raw = op_breakdown(logdir, top=args.top, self_time=False)
             rec = {"config": name,
                    **{k: (round(v, 4) if isinstance(v, float) else v)
-                      for k, v in annotate(name, result).items()},
-                   # op_breakdown has never parsed a REAL TPU trace; keep
-                   # the trace dir + the raw (non-self-time) table so the
-                   # window's capture can be re-analyzed from disk if the
+                      for k, v in annotate(
+                          name, result, device["device_kind"]).items()},
+                   **device,
+                   # keep the trace dir + the raw (non-self-time) table so
+                   # the capture can be re-analyzed from disk if the
                    # self-time parse turns out wrong on device tracks
                    "trace_dir": logdir,
                    "top_ops": [{"op": o, "sec": round(t, 5),

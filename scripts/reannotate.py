@@ -29,7 +29,7 @@ ROOF_KEYS = ("achieved_tflops", "achieved_gbs", "pct_peak_flops",
 
 
 def reannotate_file(path: str) -> int:
-    from harp_tpu.utils.roofline import annotate
+    from harp_tpu.utils.roofline import V5E, annotate
 
     changed = 0
     rows = []
@@ -44,7 +44,11 @@ def reannotate_file(path: str) -> int:
         if not config:
             continue
         stripped = {k: v for k, v in row.items() if k not in ROOF_KEYS}
-        fresh = annotate(config, stripped)
+        # rows that predate the device_kind field were all measured on
+        # one v5e (backend "tpu", n_devices 1); a CPU row has no roofline
+        kind = row.get("device_kind") or (
+            "cpu" if row.get("backend") == "cpu" else V5E)
+        fresh = annotate(config, stripped, kind)
         if any(fresh.get(k) != row.get(k) for k in ROOF_KEYS):
             import datetime
 

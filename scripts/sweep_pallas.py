@@ -1,12 +1,12 @@
 #!/usr/bin/env python
-"""Tile-size sweep for the fused Pallas kernels — relay-sprint tooling.
+"""Tile-size sweep for the fused Pallas kernels.
 
 The dense-algo tiling was tuned on TPU (512×512 best, see
 MFSGDConfig.u_tile); the fused kernels change the cost model (one-hots
 never leave VMEM), so their best tiles may differ.  Sweeps
 algo="pallas" over tile sizes for MF-SGD and LDA at the graded shapes,
-one JSON line each; run AFTER measure_on_relay.sh's main sweep commits
-(each point is a full-scale benchmark, minutes of prep on this host).
+one JSON line each (each point is a full-scale benchmark, minutes of
+host prep).  On the chip, or ``--smoke`` under ``JAX_PLATFORMS=cpu``.
 
 Usage: python scripts/sweep_pallas.py [--model mfsgd lda] [--smoke]
 """
@@ -27,15 +27,15 @@ def main(argv=None):
                    choices=["mfsgd", "lda"])
     p.add_argument("--tiles", nargs="+", type=int, default=[256, 512, 1024])
     p.add_argument("--smoke", action="store_true")
-    p.add_argument("--platform", choices=["cpu"], default=None)
     p.add_argument("--out", default="SWEEP_pallas.jsonl")
     args = p.parse_args(argv)
-    if args.platform == "cpu":
-        import jax
 
-        jax.config.update("jax_platforms", "cpu")
-
+    from harp_tpu.utils import chip
     from harp_tpu.utils.timing import HangWatchdog
+
+    chip.setup_compile_cache()
+    if not args.smoke:
+        chip.require_tpu("sweep_pallas.py")
 
     sink = open(args.out, "a")
     watchdog = HangWatchdog(on_fire=lambda what: (

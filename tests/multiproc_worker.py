@@ -32,9 +32,7 @@ if local_devices > 1:
     os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
                                f" --xla_force_host_platform_device_count={local_devices}")
 
-import jax
-
-jax.config.update("jax_platforms", "cpu")
+import jax  # the backend is the parent's JAX_PLATFORMS=cpu
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 

@@ -73,7 +73,7 @@ def test_example_kmeans_app_runs():
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     out = subprocess.run(
         [sys.executable, os.path.join(root, "examples", "kmeans_app.py"),
-         "--cpu8", "--n", "512", "--d", "4", "--k", "2", "--iters", "2"],
+         "--n", "512", "--d", "4", "--k", "2", "--iters", "2"],
         capture_output=True, text=True, timeout=300,
     )
     assert out.returncode == 0, out.stderr[-2000:]
@@ -227,7 +227,7 @@ def test_example_mfsgd_app_runs():
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     out = subprocess.run(
         [sys.executable, os.path.join(root, "examples", "mfsgd_app.py"),
-         "--cpu8", "--users", "64", "--items", "48", "--nnz", "600",
+         "--users", "64", "--items", "48", "--nnz", "600",
          "--rank", "4", "--epochs", "4"],
         capture_output=True, text=True, timeout=240)
     assert out.returncode == 0, out.stderr[-800:]
@@ -244,7 +244,7 @@ def test_example_longctx_layer_runs():
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     out = subprocess.run(
         [sys.executable, os.path.join(root, "examples", "longctx_layer.py"),
-         "--cpu8", "--seq", "128", "--steps", "12", "--window", "24"],
+         "--seq", "128", "--steps", "12", "--window", "24"],
         capture_output=True, text=True, timeout=300)
     assert out.returncode == 0, out.stderr[-800:]
     rec = ast.literal_eval(out.stdout.strip().splitlines()[-1])
@@ -261,7 +261,7 @@ def test_example_pipeline_moe_app_runs():
     out = subprocess.run(
         [sys.executable,
          os.path.join(root, "examples", "pipeline_moe_app.py"),
-         "--cpu8", "--steps", "8"],
+         "--steps", "8"],
         capture_output=True, text=True, timeout=300)
     assert out.returncode == 0, out.stderr[-800:]
     assert "pipeline[8 stages" in out.stdout

@@ -128,7 +128,7 @@ def test_exported_ledger_rows_satisfy_the_checker(tmp_path):
 
 def test_flight_row_must_carry_provenance(tmp_path):
     """Invariant 4: a compile/transfer row without backend/date/commit is
-    ambiguous evidence — a CPU-sim compile count must never read as relay
+    ambiguous evidence — a CPU-sim compile count must never read as chip
     evidence (the same inversion guard as the bench-row check)."""
     stamp = {"backend": "cpu", "date": "2026-08-04", "commit": "abc1234"}
     rows = [

@@ -1,0 +1,138 @@
+"""chip_smoke.py's full-width programs, COMPILED for a v5e without one.
+
+The sandbox's libtpu gives a compile-only TPU client
+(``jax.experimental.topologies``): real XLA:TPU and Mosaic compiles —
+VMEM limits, layout and tiling refusals — against ``TPU v5 lite``
+devices that cannot execute.  This is the check that runs before chip
+time is spent: a shape the compiler refuses (the int8 tile search on
+250,000 rows/worker, the MF-SGD 128-multiple gate on eight half-slices)
+fails here, on the CPU.  HL201 only LOWERS the registry's toy shapes.
+Shapes only, no data; what the chip adds is execution.
+
+The compiles run in ONE child process (this file, as a script): a
+process that has created the TPU client gets empty ``/device:TPU``
+planes in every later ``jax.profiler`` trace, which would starve
+``op_breakdown``'s device filter in the tests that follow.
+"""
+
+import json
+import os
+import subprocess
+import sys
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _compile_all() -> dict:
+    """Every check, in the child: {check name: {program: mosaic calls}}."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import topologies
+
+    import chip_smoke
+    from harp_tpu.models import kmeans, mfsgd
+    from harp_tpu.ops.kernel_registry import KERNELS
+    from harp_tpu.parallel.mesh import WorkerMesh
+
+    devices = topologies.get_topology_desc(
+        platform="tpu", topology_name="v5e:2x2").devices
+    out = {"device_kind": devices[0].device_kind, "n_devices": len(devices)}
+
+    def mosaic_calls(fn, sds):
+        return fn.lower(*sds).compile().as_text().count(chip_smoke.MOSAIC_CALL)
+
+    for n_dev in (1, 4):
+        mesh = WorkerMesh(devices[:n_dev])
+        rows = mesh.sharding(mesh.spec(0, ndim=2))
+        progs = {}
+
+        def sds(shape, dtype, sharding=None):
+            return jax.ShapeDtypeStruct(
+                shape, dtype, sharding=sharding or mesh.sharding(
+                    mesh.spec(0, ndim=len(shape))))
+
+        # KMeans graded config #1: the three arms phase_kmeans runs
+        km = chip_smoke.KMEANS_FULL
+        n, d, k = km["n"], km["d"], km["k"]
+        for arm, kw in (
+                ("xla_f32", {"use_pallas": False}),
+                ("pallas_int8", {"quantize": "int8", "use_pallas": True}),
+                ("xla_int8", {"quantize": "int8", "use_pallas": False})):
+            cfg = kmeans.KMeansConfig(k=k, iters=km["iters"], **kw)
+            assert kmeans.partials_arm(cfg, n // n_dev, d) == arm
+            cents = sds((k, d), jnp.float32, mesh.replicated())
+            pts = ((sds((n, d), jnp.int8, rows),
+                    sds((d,), jnp.float32, mesh.replicated()))
+                   if cfg.quantize else sds((n, d), jnp.float32, rows))
+            progs[f"kmeans.{arm}"] = mosaic_calls(
+                kmeans.make_fit_fn(mesh, cfg), (pts, cents))
+
+        # MF-SGD at the MovieLens-20M shape (benchmark()'s defaults)
+        for algo in ("pallas", "dense"):
+            cfg = mfsgd.MFSGDConfig(rank=64, algo=algo)
+            ut, it = mfsgd.tiles(cfg)
+            ns = mfsgd.rotate_chunks_resolved(cfg) * n_dev
+            _, _, u_bound, ibc = mfsgd._dense_bounds(
+                138_493, 26_744, n_dev, ns, ut, it)
+            # one entry per (u_tile × i_tile) sub-tile of a block: 20M
+            # ratings over the grid average well under entry_cap per tile
+            ne, c = (u_bound // ut) * (ibc // it), cfg.entry_cap
+            i32, f32 = jnp.int32, jnp.float32
+            shapes = [((u_bound * n_dev, 64), f32), ((ibc * ns, 64), f32),
+                      ((ns * n_dev, ne, c), i32), ((ns * n_dev, ne, c), i32),
+                      ((ns * n_dev, ne, c), f32), ((ns * n_dev, ne), i32),
+                      ((ns * n_dev, ne), i32)]
+            progs[f"mfsgd.{algo}"] = mosaic_calls(
+                mfsgd.make_multi_epoch_fn(mesh, cfg, epochs=3),
+                [sds(s, dt) for s, dt in shapes])
+        out[f"full_width_{n_dev}"] = progs
+
+    # every builder in the registry through the real Mosaic compiler
+    one = jax.sharding.SingleDeviceSharding(devices[0])
+    out["registry"] = {
+        name: mosaic_calls(jax.jit(fn), [
+            jax.ShapeDtypeStruct(jnp.shape(a), jnp.result_type(a),
+                                 sharding=one) for a in args])
+        for name, (fn, args) in ((n, KERNELS[n]()) for n in sorted(KERNELS))}
+    return out
+
+
+@pytest.fixture(scope="module")
+def compiled():
+    r = subprocess.run(
+        [sys.executable, os.path.abspath(__file__)], cwd=ROOT,
+        # off the chip interpret mode is the default; this is the documented
+        # switch for compiling the Mosaic path without executing it
+        env={**os.environ, "HARP_PALLAS_FORCE_MOSAIC": "1"},
+        capture_output=True, text=True, timeout=600)
+    assert r.returncode == 0, r.stderr[-3000:]
+    return json.loads(r.stdout.strip().splitlines()[-1])
+
+
+def test_the_client_is_a_v5e_2x2(compiled):
+    assert compiled["device_kind"] == "TPU v5 lite"
+    assert compiled["n_devices"] == 4
+
+
+@pytest.mark.parametrize("n_dev", [1, 4])
+def test_full_width_programs_compile_for_v5e(compiled, n_dev):
+    progs = compiled[f"full_width_{n_dev}"]
+    assert set(progs) == {"kmeans.xla_f32", "kmeans.pallas_int8",
+                          "kmeans.xla_int8", "mfsgd.pallas", "mfsgd.dense"}
+    # a Mosaic call in each Pallas program, none in its XLA twin
+    for name, calls in progs.items():
+        assert (calls > 0) == ("pallas" in name), (name, calls)
+
+
+def test_registered_kernels_compile_for_v5e(compiled):
+    from harp_tpu.ops.kernel_registry import KERNELS
+
+    assert set(compiled["registry"]) == set(KERNELS)
+    assert all(calls > 0 for calls in compiled["registry"].values())
+
+
+if __name__ == "__main__":
+    sys.path.insert(0, ROOT)
+    print(json.dumps(_compile_all()))

@@ -333,20 +333,12 @@ def test_measure_all_script_smoke(tmp_path):
 
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     out = tmp_path / "res.jsonl"
-    env = dict(os.environ)
-    env["JAX_PLATFORMS"] = ""  # let the script's process pick CPU via conftest-style forcing
-    code = (
-        "import os\n"
-        "os.environ['XLA_FLAGS'] = os.environ.get('XLA_FLAGS','') + "
-        "' --xla_force_host_platform_device_count=8'\n"
-        "import jax\n"
-        "jax.config.update('jax_platforms','cpu')\n"
-        f"import sys; sys.argv = ['m','--smoke','--only','kmeans','--out',{str(out)!r}]\n"
-        f"import runpy; runpy.run_path({os.path.join(root,'scripts','measure_all.py')!r},"
-        " run_name='__main__')\n"
-    )
-    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                       text=True, timeout=240, env=env)
+    # the child inherits JAX_PLATFORMS=cpu and the 8-device XLA_FLAGS
+    # from conftest's environment
+    r = subprocess.run(
+        [sys.executable, os.path.join(root, "scripts", "measure_all.py"),
+         "--smoke", "--only", "kmeans", "--out", str(out)],
+        capture_output=True, text=True, timeout=240)
     assert r.returncode == 0, r.stderr[-2000:]
     recs = [json.loads(l) for l in out.read_text().splitlines()]
     assert recs and recs[0]["config"] == "kmeans"
@@ -355,7 +347,7 @@ def test_measure_all_script_smoke(tmp_path):
 
 def test_measure_all_full_mode_kwargs_bind(monkeypatch):
     """Every FULL-shape sweep config must CONSTRUCT correctly with no
-    relay: the lambdas' kwargs are bound against the real benchmark
+    chip: the lambdas' kwargs are bound against the real benchmark
     signatures via stubs, so a typo'd/removed kwarg (or a config name
     missing from SPRINT_ORDER) fails HERE — not twenty minutes into a
     scarce TPU window.  Smoke mode only ever validates the smoke shapes;
@@ -392,7 +384,7 @@ def test_measure_all_full_mode_kwargs_bind(monkeypatch):
     stubbed(serve_bench, "benchmark_sustained")
     monkeypatch.setattr(ma, "_bench_ingest",
                         lambda smoke, quantize=None: {"stub": 1.0})
-    monkeypatch.setattr(roofline, "annotate", lambda name, res: res)
+    monkeypatch.setattr(roofline, "annotate", lambda name, res, kind: res)
 
     rows = list(ma.run_all(smoke=False, only=None))
     bad = [r for r in rows if "error" in r]
@@ -636,8 +628,8 @@ def test_health_cli_grades_fresh_bench_rows(capsys, tmp_path,
 def test_health_cli_grade_model_emits_checker_clean_row(capsys):
     """--grade-model on the real repo: the committed evidence grades
     clean (tier-1 pins perfmodel.grade ok), the CLI exits 0, and the
-    one emitted kind:'health' row passes invariant 13 — the line
-    measure_on_relay.sh tees into the evidence file."""
+    one emitted kind:'health' row passes invariant 13 — the line that
+    gets appended to the evidence file after a measurement run."""
     import json
     import os
     import sys

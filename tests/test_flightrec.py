@@ -6,7 +6,7 @@ Three layers of evidence, all on the CPU backend with zero hardware:
    backend compiles with span attribution; TransferLedger counts
    shard_array H2D bytes, device_sync/readback round trips, tracked
    dispatches);
-2. the budget guard catches the documented CLAUDE.md relay traps — a
+2. the budget guard catches the documented CLAUDE.md driver-loop traps — a
    per-step ``PRNGKey(int)`` re-seed trips ``compiles=1``, a per-epoch
    readback loop trips ``readbacks=1``;
 3. the shipped kmeans/lda/mfsgd epoch loops PASS their pinned budgets
@@ -24,16 +24,10 @@ import pytest
 
 from harp_tpu.utils import flightrec, prng, telemetry
 
-needs_compile_events = pytest.mark.skipif(
-    not flightrec.COMPILE_EVENTS_AVAILABLE,
-    reason="this jax lacks the monitoring hook")
-
-
 # ---------------------------------------------------------------------------
 # collectors
 # ---------------------------------------------------------------------------
 
-@needs_compile_events
 def test_compile_watch_counts_and_attributes_spans(mesh):
     with telemetry.scope():
         with telemetry.span("phase"):
@@ -169,10 +163,9 @@ def test_mapper_budget_warns_on_violation(mesh):
 
 
 # ---------------------------------------------------------------------------
-# the documented relay traps, machine-checked (acceptance criteria)
+# the documented driver-loop traps, machine-checked (acceptance criteria)
 # ---------------------------------------------------------------------------
 
-@needs_compile_events
 def test_reseeding_prngkey_per_step_trips_compile_budget(mesh):
     """CLAUDE.md trap: a step function that bakes a fresh
     ``PRNGKey(python_int)`` into its traced program compiles once PER
@@ -232,7 +225,6 @@ def test_per_epoch_readback_trips_readback_budget(mesh):
 # pinned budgets for the shipped epoch loops (acceptance criteria)
 # ---------------------------------------------------------------------------
 
-@needs_compile_events
 def test_mfsgd_epoch_loop_passes_pinned_budget(mesh):
     """One AOT compile per epoch count, then one dispatch + ONE stacked
     readback per train_epochs run, and ZERO recompiles on rerun."""
@@ -260,7 +252,6 @@ def test_mfsgd_epoch_loop_passes_pinned_budget(mesh):
         assert b.spent()["readbacks"] == 1
 
 
-@needs_compile_events
 def test_lda_epoch_loop_passes_pinned_budget(mesh):
     """One AOT compile per epoch count; each sample_epochs run is one
     dispatch + one readback + only the per-worker keys' H2D (64 B at 8
@@ -288,7 +279,6 @@ def test_lda_epoch_loop_passes_pinned_budget(mesh):
             assert b.spent()["readbacks"] == 1
 
 
-@needs_compile_events
 def test_kmeans_fit_passes_pinned_budget(mesh):
     """Steady-state fit: one compile (the per-call jit), one dispatch
     for ALL iterations, two readbacks (inertia + centroids), and H2D of
@@ -346,7 +336,6 @@ def test_zero_cost_when_disabled(mesh):
 # export / report / checker round trips
 # ---------------------------------------------------------------------------
 
-@needs_compile_events
 def test_export_rows_carry_provenance_and_pass_check_jsonl(mesh, tmp_path):
     import os
     import sys
@@ -369,7 +358,6 @@ def test_export_rows_carry_provenance_and_pass_check_jsonl(mesh, tmp_path):
     assert check_jsonl.check_file(str(p)) == []
 
 
-@needs_compile_events
 def test_live_report_surfaces_compile_and_transfer_sections(mesh):
     from harp_tpu import report
 

@@ -419,7 +419,7 @@ def test_model_gate_passes_on_committed_evidence():
 
 def test_predicted_top_refuses_when_model_invalidated(monkeypatch):
     """ROADMAP autotuning item (3), the gate pin: an invalidated model
-    must not choose what the next relay window measures — measure_all
+    must not choose what the next chip run measures — measure_all
     --predicted-top exits 1 BEFORE computing any selection."""
     import importlib.util
 

@@ -22,11 +22,6 @@ from harp_tpu.models import kmeans_stream as KS
 from harp_tpu.models import mlp as M
 from harp_tpu.utils import flightrec, telemetry
 
-needs_compile_events = pytest.mark.skipif(
-    not flightrec.COMPILE_EVENTS_AVAILABLE,
-    reason="this jax lacks the monitoring hook")
-
-
 def _blobs(n=4096, d=24, c=4, seed=0):
     rng = np.random.default_rng(seed)
     return (rng.normal(size=(n, d)).astype(np.float32)
@@ -219,7 +214,6 @@ def test_kmeans_stream_h2d_budget_exact(mesh):
         assert b.spent()["h2d_bytes"] == exact
 
 
-@needs_compile_events
 def test_kmeans_stream_zero_postwarmup_compiles(mesh):
     """Epochs after the first compile NOTHING: a 4-epoch fit spends no
     more backend compiles than a 1-epoch fit does for its per-epoch
@@ -240,10 +234,8 @@ def test_kmeans_stream_zero_postwarmup_compiles(mesh):
 def test_interior_epoch_budget_fires_on_recompiling_chunk_loop(mesh,
                                                                monkeypatch):
     """Liveness of the warn-mode guard inside _stream_train: a chunk fn
-    that recompiles per call (the classic relay trap) must trip the
+    that recompiles per call (the classic driver-loop trap) must trip the
     epoch budget's compiles=0 arm on every post-warmup epoch."""
-    if not flightrec.COMPILE_EVENTS_AVAILABLE:
-        pytest.skip("this jax lacks the monitoring hook")
     orig = KS._make_accum_fn
 
     def recompiling(mesh_, cfg_):

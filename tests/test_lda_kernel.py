@@ -310,7 +310,7 @@ def test_kernel_vmem_gate():
 ])
 def test_kernel_lowers_for_tpu(ndk_dtype, shape, bounds):
     """Pallas->Mosaic verification at the graded tile shapes, no hardware
-    (caught the uint32->f32 cast Mosaic rejects, pre-relay)."""
+    (caught the uint32->f32 cast Mosaic rejects, before any chip run)."""
     import functools
 
     import jax

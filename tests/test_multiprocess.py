@@ -26,10 +26,10 @@ def _run_workers(n_procs: int, local_devices: int = 1,
     here = os.path.dirname(os.path.abspath(__file__))
     script = os.path.join(here, "multiproc_worker.py")
     port = str(_free_port())
-    # strip the harness overrides: conftest forces 8 CPU devices per process
-    # via XLA_FLAGS; the worker sets its own per-process device count
-    env = {k: v for k, v in os.environ.items()
-           if k not in ("JAX_PLATFORMS", "XLA_FLAGS")}
+    # strip the harness's XLA_FLAGS: conftest forces 8 CPU devices per
+    # process; the worker sets its own per-process device count
+    env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
+    env["JAX_PLATFORMS"] = "cpu"
     procs = [
         subprocess.Popen([sys.executable, script, str(i), port,
                           str(n_procs), str(local_devices)],

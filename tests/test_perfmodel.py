@@ -5,7 +5,7 @@ Four contracts, all tier-1:
 1. **Self-grading passes on the committed evidence** — the model's
    ranking agrees with every BENCH_local / FLIP_DECISIONS pair and
    SWEEP_pallas sweep it can price (a model edit that drifts from the
-   measurements fails HERE, before it can mis-prune a relay sprint).
+   measurements fails HERE, before it can mis-prune a measurement run).
 2. **Exported rows are invariant-12 evidence** — kind:"model" rows
    round-trip through scripts/check_jsonl.py, and the frozen
    vocabularies stay in sync.
@@ -184,7 +184,7 @@ def test_vocabulary_and_sprint_sync():
 
 
 def test_unpriceable_config_raises_keyerror():
-    # subgraph became priceable in PR 16; kmeans_ingest (relay-tunnel
+    # subgraph became priceable in PR 16; kmeans_ingest (host-link
     # bound, priced by bench_ingest itself) remains deliberately out
     with pytest.raises(KeyError, match="unpriceable"):
         M.price("kmeans_ingest", None, _topo())

@@ -18,7 +18,7 @@ Contract under test:
    reproduces the one-shot fit within float-reassociation tolerance
    (and exactly on integer payloads).
 4. Flight-budget pins: each comm lowering is ONE dispatch and ZERO
-   post-warmup compiles (the CLAUDE.md relay traps, machine-checked).
+   post-warmup compiles (the CLAUDE.md driver-loop traps, machine-checked).
 5. The CommLedger sees every wire: verb "reshard", payload at wire
    width, chunk-sized for the chunked lowering.
 """

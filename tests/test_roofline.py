@@ -1,14 +1,17 @@
 """Roofline annotation math (utils/roofline.py)."""
 
 import numpy as np
+import pytest
 
 from harp_tpu.utils import roofline as R
+from harp_tpu.utils.roofline import V5E
 
 
 def test_kmeans_annotation_math():
     # 1M×300 k=100 at 400 iter/s: flops = 4ndk·rate
     r = R.annotate("kmeans", {"n": 1_000_000, "d": 300, "k": 100,
-                              "iters_per_sec": 400.0, "quantize": None})
+                              "iters_per_sec": 400.0, "quantize": None},
+                   V5E)
     want_tflops = 4 * 1e6 * 300 * 100 * 400 / 1e12
     np.testing.assert_allclose(r["achieved_tflops"], round(want_tflops, 3))
     assert 0 < r["pct_peak_flops"] < 100
@@ -21,8 +24,8 @@ def test_kmeans_annotation_math():
 
 def test_int8_uses_int8_peak_and_smaller_bytes():
     base = {"n": 1_000_000, "d": 300, "k": 100, "iters_per_sec": 400.0}
-    f32 = R.annotate("kmeans", {**base, "quantize": None})
-    i8 = R.annotate("kmeans_int8", {**base, "quantize": "int8"})
+    f32 = R.annotate("kmeans", {**base, "quantize": None}, V5E)
+    i8 = R.annotate("kmeans_int8", {**base, "quantize": "int8"}, V5E)
     assert i8["roofline_peak"] == "int8_ops"
     assert i8["pct_peak_flops"] < f32["pct_peak_flops"]  # higher peak
     assert i8["achieved_gbs"] < f32["achieved_gbs"]      # 1-byte points
@@ -34,20 +37,20 @@ def test_mesh_aggregate_metrics_divided_per_chip():
     # must not report 8x the per-chip utilization
     base = {"n": 1_000_000, "d": 300, "k": 100, "iters_per_sec": 400.0,
             "quantize": None}
-    one = R.annotate("kmeans", {**base, "num_workers": 1})
-    eight = R.annotate("kmeans", {**base, "num_workers": 8})
+    one = R.annotate("kmeans", {**base, "num_workers": 1}, V5E)
+    eight = R.annotate("kmeans", {**base, "num_workers": 8}, V5E)
     np.testing.assert_allclose(eight["pct_peak_flops"] * 8,
                                one["pct_peak_flops"], rtol=1e-2)  # 2-dp rounding
 
 
 def test_unmodeled_config_passes_through():
     r = {"trees_per_sec": 7.0}
-    assert R.annotate("rf", r) == r
-    assert R.annotate("rf", r) is not r  # copy, not alias
+    assert R.annotate("rf", r, V5E) == r
+    assert R.annotate("rf", r, V5E) is not r  # copy, not alias
 
 
 def test_missing_metric_passes_through():
-    assert "pct_peak_flops" not in R.annotate("kmeans", {"n": 1})
+    assert "pct_peak_flops" not in R.annotate("kmeans", {"n": 1}, V5E)
 
 
 def test_memory_vs_compute_bound_classification():
@@ -56,15 +59,37 @@ def test_memory_vs_compute_bound_classification():
     # tiny d·k (ratio 1.6) is memory-bound and the graded k=1000 shape
     # (ratio ≈ 997) is compute-bound.
     lo_k = R.annotate("kmeans", {"n": 1 << 20, "d": 4, "k": 2,
-                                 "iters_per_sec": 100.0, "quantize": None})
+                                 "iters_per_sec": 100.0, "quantize": None},
+                      V5E)
     hi_k = R.annotate("kmeans", {"n": 1 << 20, "d": 300, "k": 1000,
-                                 "iters_per_sec": 100.0, "quantize": None})
+                                 "iters_per_sec": 100.0, "quantize": None},
+                      V5E)
     assert lo_k["bound"] == "memory"
     assert hi_k["bound"] == "compute"
 
 
-def test_measure_all_smoke_record_carries_roofline(mesh):
-    # end-to-end: the measure_all pipeline annotates modeled configs
+def test_cpu_run_gets_no_roofline_fields():
+    # a number from a CPU run is never a share of a device's peak
+    r = {"n": 1_000_000, "d": 300, "k": 100, "iters_per_sec": 400.0,
+         "quantize": None}
+    assert R.annotate("kmeans", r, "cpu") == r
+    assert R.peaks_for("cpu") is None
+
+
+def test_unknown_device_kind_is_an_error_not_a_default():
+    r = {"n": 1_000_000, "d": 300, "k": 100, "iters_per_sec": 400.0,
+         "quantize": None}
+    with pytest.raises(ValueError, match="TPU v9 imaginary"):
+        R.annotate("kmeans", r, "TPU v9 imaginary")
+    # even for a config with no work model: the device is checked first
+    with pytest.raises(ValueError, match="no published peaks"):
+        R.annotate("rf", {"trees_per_sec": 7.0}, "TPU v9 imaginary")
+    assert R.peaks_for(V5E)["hbm_gbs"] == 819e9
+
+
+def test_measure_all_smoke_record_names_its_device(mesh):
+    # end-to-end: the measure_all pipeline stamps the device on every row
+    # and — on the CPU simulation — adds no roofline fields
     import importlib.util
     import os
 
@@ -74,7 +99,9 @@ def test_measure_all_smoke_record_carries_roofline(mesh):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     recs = list(mod.run_all(smoke=True, only=["kmeans"]))
-    assert len(recs) == 1 and "pct_peak_flops" in recs[0], recs
+    assert len(recs) == 1, recs
+    assert recs[0]["platform"] == "cpu" and recs[0]["n_devices"] == 8
+    assert "device_kind" in recs[0] and "pct_peak_flops" not in recs[0]
 
 
 def test_variant_configs_share_their_family_model():
@@ -86,8 +113,6 @@ def test_variant_configs_share_their_family_model():
     too, not just the six that existed when this was written."""
     import importlib.util
     import os
-
-    from harp_tpu.utils import roofline as R
 
     spec = importlib.util.spec_from_file_location(
         "measure_all_rr", os.path.join(os.path.dirname(__file__), "..",

@@ -1,7 +1,7 @@
 """harp serve — micro-batcher, AOT executable cache, engines, server.
 
 The acceptance gates of the serving subsystem, all on the 8-sim-worker
-CPU mesh (no relay):
+CPU mesh (no chip):
 
 - shape-ladder bucketing is minimal (padding bounded), ragged tails pad
   to their rung, oversized requests span batches and reassemble;
@@ -1056,13 +1056,16 @@ def test_cli_bench_emits_valid_serve_row(mesh, capsys):
     assert row["p50_ms"] <= row["p95_ms"] <= row["p99_ms"]
 
 
-def test_serve_bench_mfsgd_row(mesh):
+def test_serve_bench_mfsgd_row(mesh, tmp_path):
     from harp_tpu.serve.bench import benchmark
 
+    # a fresh cache_dir: the default is a fixed directory (a second run
+    # starts warm), and this row asserts the cold start
     res = benchmark(app="mfsgd", n_requests=24, rows_per_request=2,
                     burst=8, ladder=(1, 8),
                     state_shape={"n_users": 64, "n_items": 48,
-                                 "rank": 8}, topk=4)
+                                 "rank": 8}, topk=4,
+                    cache_dir=str(tmp_path))
     assert res["kind"] == "serve" and res["app"] == "mfsgd"
     assert res["steady_compiles"] == 0 and res["budget_violations"] == 0
     assert res["p50_ms"] <= res["p95_ms"] <= res["p99_ms"]

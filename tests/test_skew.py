@@ -34,11 +34,6 @@ sys.path.insert(0, os.path.join(ROOT, "scripts"))
 
 import check_jsonl  # noqa: E402
 
-needs_compile_events = pytest.mark.skipif(
-    not flightrec.COMPILE_EVENTS_AVAILABLE,
-    reason="this jax lacks the monitoring hook")
-
-
 def _skewed_lda_corpus(seed=0):
     """64 docs, 48 vocab: docs 0-7 (worker 0's range at 8 workers) carry
     40 tokens each, the rest 4 — worker 0 holds ~4.7x the mean load."""
@@ -136,7 +131,6 @@ def test_kmeans_fit_records_balanced_execution_skew(mesh):
 # flagship budgets UNCHANGED with skew collection enabled (satellite pin)
 # ---------------------------------------------------------------------------
 
-@needs_compile_events
 def test_lda_flagship_budget_unchanged_with_skew_enabled(mesh):
     """The acceptance pin: with skew collection on (it rides the
     HARP_TELEMETRY switch), the lda flagship budget from
@@ -181,12 +175,16 @@ def test_imbalance_model_and_roofline_composition():
         # roofline composition: lda's work model at 1e9 tok/s/chip &
         # K=100 achieves 1.4e12/197e12 = 0.7107% of bf16 peak; skew
         # predicts 60% of that lost to the barrier
-        pct = skew.wasted_pct_of_peak(
-            "lda", {"n_topics": 100, "tokens_per_sec_per_chip": 1e9}, "p")
+        from harp_tpu.utils.roofline import V5E
+
+        res = {"n_topics": 100, "tokens_per_sec_per_chip": 1e9}
+        pct = skew.wasted_pct_of_peak("lda", res, "p", V5E)
         assert pct == pytest.approx(0.7107 * 0.6, abs=1e-3)
-        # unknown phase / config without a work model → None, not garbage
-        assert skew.wasted_pct_of_peak("lda", {}, "nope") is None
-        assert skew.wasted_pct_of_peak("no_model", {}, "p") is None
+        # unknown phase / config without a work model / a CPU run →
+        # None, not garbage
+        assert skew.wasted_pct_of_peak("lda", {}, "nope", V5E) is None
+        assert skew.wasted_pct_of_peak("no_model", {}, "p", V5E) is None
+        assert skew.wasted_pct_of_peak("lda", res, "p", "cpu") is None
 
 
 def test_suggest_rebalance_fractional_plan():
