@@ -52,7 +52,7 @@ from harp_tpu.parallel import collective as C
 from harp_tpu.parallel.mesh import WorkerMesh, current_mesh
 from harp_tpu.parallel.rotate import (ROTATE_WIRES, resident_chunk_index,
                                       rotate_pipeline)
-from harp_tpu.utils import flightrec, prng, skew
+from harp_tpu.utils import flightrec, prng, skew, telemetry
 
 
 @dataclasses.dataclass
@@ -194,50 +194,53 @@ def partition_ratings(users, items, vals, n_users, n_items, n_workers, chunk,
     partition id; padding replaces the dynamic per-block sizes because XLA
     needs static shapes.)
     """
-    users = np.asarray(users)
-    items = np.asarray(items)
-    vals = np.asarray(vals, dtype=np.float32)
     n = n_workers
     ns = n_slices if n_slices is not None else 2 * n
     u_bound = -(-n_users // n)  # users per range (ceil)
     i_bound = -(-n_items // ns)  # items per slice
 
-    wid = users // u_bound  # owning worker (user range)
-    sid = items // i_bound  # item slice
+    with telemetry.span("mfsgd.partition.sort"):
+        users = np.asarray(users)
+        items = np.asarray(items)
+        vals = np.asarray(vals, dtype=np.float32)
+        wid = users // u_bound  # owning worker (user range)
+        sid = items // i_bound  # item slice
 
-    # bucket sort triples by (worker, slice)
-    order = np.lexsort((items, sid, wid))
-    users, items, vals, wid, sid = (
-        a[order] for a in (users, items, vals, wid, sid)
-    )
-    counts = np.zeros((n, ns), np.int64)
-    np.add.at(counts, (wid, sid), 1)
-    bmax = int(counts.max())
-    if bmax >= chunk:
-        B = -(-bmax // chunk) * chunk  # pad to chunk multiple
-    else:
-        # small data: don't pad every block up to a full chunk (400× waste
-        # at the tuned 32768 default on 10k-rating datasets) — one
-        # sublane-aligned sub-chunk suffices; the device side clamps its
-        # scan chunk to the block width (see _block_update).  Cap at chunk:
-        # sublane alignment may otherwise overshoot it when chunk % 8 != 0,
-        # and the device reshape needs B % min(chunk, B) == 0.
-        B = min(chunk, max(8, -(-bmax // 8) * 8))
+        # bucket sort triples by (worker, slice)
+        order = np.lexsort((items, sid, wid))
+        users, items, vals, wid, sid = (
+            a[order] for a in (users, items, vals, wid, sid)
+        )
+    with telemetry.span("mfsgd.partition.pack"):
+        counts = np.zeros((n, ns), np.int64)
+        np.add.at(counts, (wid, sid), 1)
+        bmax = int(counts.max())
+        if bmax >= chunk:
+            B = -(-bmax // chunk) * chunk  # pad to chunk multiple
+        else:
+            # small data: don't pad every block up to a full chunk (400×
+            # waste at the tuned 32768 default on 10k-rating datasets) —
+            # one sublane-aligned sub-chunk suffices; the device side
+            # clamps its scan chunk to the block width (see
+            # _block_update).  Cap at chunk: sublane alignment may
+            # otherwise overshoot it when chunk % 8 != 0, and the device
+            # reshape needs B % min(chunk, B) == 0.
+            B = min(chunk, max(8, -(-bmax // 8) * 8))
 
-    u = np.zeros((n, ns, B), np.int32)
-    i = np.zeros((n, ns, B), np.int32)
-    v = np.zeros((n, ns, B), np.float32)
-    m = np.zeros((n, ns, B), np.float32)
-    starts = np.zeros((n, ns), np.int64)
-    starts.flat[1:] = counts.cumsum()[:-1]
-    for w in range(n):
-        for s in range(ns):
-            lo, c = starts[w, s], counts[w, s]
-            sl = slice(lo, lo + c)
-            u[w, s, :c] = users[sl] - w * u_bound
-            i[w, s, :c] = items[sl] - s * i_bound
-            v[w, s, :c] = vals[sl]
-            m[w, s, :c] = 1.0
+        u = np.zeros((n, ns, B), np.int32)
+        i = np.zeros((n, ns, B), np.int32)
+        v = np.zeros((n, ns, B), np.float32)
+        m = np.zeros((n, ns, B), np.float32)
+        starts = np.zeros((n, ns), np.int64)
+        starts.flat[1:] = counts.cumsum()[:-1]
+        for w in range(n):
+            for s in range(ns):
+                lo, c = starts[w, s], counts[w, s]
+                sl = slice(lo, lo + c)
+                u[w, s, :c] = users[sl] - w * u_bound
+                i[w, s, :c] = items[sl] - s * i_bound
+                v[w, s, :c] = vals[sl]
+                m[w, s, :c] = 1.0
     return (
         u.reshape(n * ns, B), i.reshape(n * ns, B),
         v.reshape(n * ns, B), m.reshape(n * ns, B),
@@ -282,63 +285,67 @@ def partition_ratings_tiles(users, items, vals, n_users, n_items, n_workers,
     plus ``(u_own, i_own, u_bound, ib2)`` from :func:`_dense_bounds`
     (balanced ownership sizes + tile-rounded storage sizes).
     """
-    users = np.asarray(users)
-    items = np.asarray(items)
-    vals = np.asarray(vals, dtype=np.float32)
     n = n_workers
     ns = n_slices if n_slices is not None else 2 * n
     u_own, i_own, u_bound, ib2 = _dense_bounds(
         n_users, n_items, n, ns, u_tile, i_tile)
-
-    wid = users // u_own
-    sid = items // i_own
-    lu = users - wid * u_own
-    li = items - sid * i_own
-    tu = lu // u_tile
-    ti = li // i_tile
     ntu, nti = u_bound // u_tile, ib2 // i_tile
 
-    # global tile id, sorted so each (worker, slice) lists tiles u-major
-    gtile = ((wid * ns + sid) * ntu + tu) * nti + ti
-    order = np.argsort(gtile, kind="stable")
-    lu, li, vals, gtile = lu[order], li[order], vals[order], gtile[order]
+    with telemetry.span("mfsgd.partition.sort"):
+        users = np.asarray(users)
+        items = np.asarray(items)
+        vals = np.asarray(vals, dtype=np.float32)
+        wid = users // u_own
+        sid = items // i_own
+        lu = users - wid * u_own
+        li = items - sid * i_own
+        tu = lu // u_tile
+        ti = li // i_tile
 
-    n_tiles = n * ns * ntu * nti
-    counts = np.bincount(gtile, minlength=n_tiles)
-    C = int(min(entry_cap, max(8, 8 * _ceil_div(int(counts.max(initial=0)), 8))))
-    ent_per_tile = _ceil_div(counts, C)  # elementwise ceil; 0 for empty tiles
-    ws_of_tile = np.arange(n_tiles) // (ntu * nti)
-    NE = max(1, int(np.bincount(ws_of_tile, weights=ent_per_tile,
-                                minlength=n * ns).max()))
+        # global tile id, sorted so each (worker, slice) lists tiles u-major
+        gtile = ((wid * ns + sid) * ntu + tu) * nti + ti
+        order = np.argsort(gtile, kind="stable")
+        lu, li, vals, gtile = lu[order], li[order], vals[order], gtile[order]
 
-    eu = np.full((n * ns, NE, C), u_tile, np.int32)
-    ei = np.full((n * ns, NE, C), i_tile, np.int32)
-    ev = np.zeros((n * ns, NE, C), np.float32)
-    ou = np.zeros((n * ns, NE), np.int32)
-    oi = np.zeros((n * ns, NE), np.int32)
-    starts = np.zeros(n_tiles, np.int64)
-    starts[1:] = counts.cumsum()[:-1]
-    e_next = np.zeros(n * ns, np.int64)
-    # Deliberately a per-entry loop: it copies CONTIGUOUS slices of the
-    # tile-sorted data (memcpy-speed, ~15k iterations at ML-20M).  A fully
-    # vectorized fancy-index formulation measured 2× SLOWER (12.6 s vs
-    # 6.3 s, 2026-07-30) — five 20M-element bounds-checked scatters beat
-    # no Python loop but lose to 15k memcpys.
-    for t in np.nonzero(counts)[0]:
-        ws = t // (ntu * nti)
-        t_u = (t // nti) % ntu
-        t_i = t % nti
-        lo, cnt = int(starts[t]), int(counts[t])
-        for off in range(0, cnt, C):
-            e = int(e_next[ws])
-            e_next[ws] = e + 1
-            c = min(C, cnt - off)
-            sl = slice(lo + off, lo + off + c)
-            eu[ws, e, :c] = lu[sl] - t_u * u_tile
-            ei[ws, e, :c] = li[sl] - t_i * i_tile
-            ev[ws, e, :c] = vals[sl]
-            ou[ws, e] = t_u * u_tile
-            oi[ws, e] = t_i * i_tile
+    with telemetry.span("mfsgd.partition.pack"):
+        n_tiles = n * ns * ntu * nti
+        counts = np.bincount(gtile, minlength=n_tiles)
+        C = int(min(entry_cap,
+                    max(8, 8 * _ceil_div(int(counts.max(initial=0)), 8))))
+        # elementwise ceil; 0 for empty tiles
+        ent_per_tile = _ceil_div(counts, C)
+        ws_of_tile = np.arange(n_tiles) // (ntu * nti)
+        NE = max(1, int(np.bincount(ws_of_tile, weights=ent_per_tile,
+                                    minlength=n * ns).max()))
+
+        eu = np.full((n * ns, NE, C), u_tile, np.int32)
+        ei = np.full((n * ns, NE, C), i_tile, np.int32)
+        ev = np.zeros((n * ns, NE, C), np.float32)
+        ou = np.zeros((n * ns, NE), np.int32)
+        oi = np.zeros((n * ns, NE), np.int32)
+        starts = np.zeros(n_tiles, np.int64)
+        starts[1:] = counts.cumsum()[:-1]
+        e_next = np.zeros(n * ns, np.int64)
+        # Deliberately a per-entry loop: it copies CONTIGUOUS slices of
+        # the tile-sorted data (memcpy-speed, ~15k iterations at ML-20M).
+        # A fully vectorized fancy-index formulation measured 2× SLOWER
+        # (12.6 s vs 6.3 s, 2026-07-30) — five 20M-element bounds-checked
+        # scatters beat no Python loop but lose to 15k memcpys.
+        for t in np.nonzero(counts)[0]:
+            ws = t // (ntu * nti)
+            t_u = (t // nti) % ntu
+            t_i = t % nti
+            lo, cnt = int(starts[t]), int(counts[t])
+            for off in range(0, cnt, C):
+                e = int(e_next[ws])
+                e_next[ws] = e + 1
+                c = min(C, cnt - off)
+                sl = slice(lo + off, lo + off + c)
+                eu[ws, e, :c] = lu[sl] - t_u * u_tile
+                ei[ws, e, :c] = li[sl] - t_i * i_tile
+                ev[ws, e, :c] = vals[sl]
+                ou[ws, e] = t_u * u_tile
+                oi[ws, e] = t_i * i_tile
     return eu, ei, ev, ou, oi, u_own, i_own, u_bound, ib2
 
 
@@ -635,42 +642,59 @@ class MFSGD:
         self.skew_units = None
 
     def set_ratings(self, users, items, vals):
-        from harp_tpu.utils import telemetry
-
         n = self.mesh.num_workers
         nc = rotate_chunks_resolved(self.cfg)
-        if self.cfg.algo in _DENSE_ALGOS:
-            eu, ei, ev, ou, oi, uo, io, ub, ibc = partition_ratings_tiles(
-                users, items, vals, self.n_users, self.n_items, n,
-                *tiles(self.cfg), self.cfg.entry_cap,
-                n_slices=self._n_slices,
-            )
-            assert (uo, io) == (self.u_own, self.i_own)
-            if telemetry.enabled():
-                # ingest skew record from the REAL ratings (before the
-                # pallas coverage entries, which carry no rating mass)
-                valid = eu < tiles(self.cfg)[0]
-                skew.record_partition(
-                    "mfsgd.partition", valid.reshape(n, -1).sum(1),
-                    unit="ratings", padded_total=valid.size)
-            if self.cfg.algo == "pallas":
-                from harp_tpu.ops.mfsgd_kernel import insert_coverage_entries
+        work = None  # valid ratings a worker, counted with telemetry on
+        with telemetry.span("mfsgd.set_ratings"):
+            if self.cfg.algo in _DENSE_ALGOS:
+                u_tile, i_tile = tiles(self.cfg)
+                eu, ei, ev, ou, oi, uo, io, ub, ibc = partition_ratings_tiles(
+                    users, items, vals, self.n_users, self.n_items, n,
+                    u_tile, i_tile, self.cfg.entry_cap,
+                    n_slices=self._n_slices,
+                )
+                assert (uo, io) == (self.u_own, self.i_own)
+                if telemetry.enabled():
+                    # ingest skew record from the REAL ratings (before the
+                    # pallas coverage entries, which carry no rating mass)
+                    valid = eu < u_tile
+                    work = valid.reshape(n, -1).sum(1)
+                    skew.record_partition(
+                        "mfsgd.partition", work, unit="ratings",
+                        padded_total=valid.size)
+                if self.cfg.algo == "pallas":
+                    # outside the span: the module's first import brings
+                    # Pallas in, which is no work on the ratings
+                    from harp_tpu.ops.mfsgd_kernel import (
+                        insert_coverage_entries)
 
-                eu, ei, ev, ou, oi = insert_coverage_entries(
-                    eu, ei, ev, ou, oi, ub, tiles(self.cfg)[0])
-            blocks = (eu, ei, ev, ou, oi)
-        else:
-            bu, bi, bv, bm, ub, ibc = partition_ratings(
-                users, items, vals, self.n_users, self.n_items, n,
-                self.cfg.chunk, n_slices=self._n_slices,
-            )
-            if telemetry.enabled():
-                skew.record_partition(
-                    "mfsgd.partition", (bm > 0).reshape(n, -1).sum(1),
-                    unit="ratings", padded_total=bm.size)
-            blocks = (bu, bi, bv, bm)
-        assert (ub, nc * ibc) == (self.u_bound, self.i_bound)
-        self._blocks = tuple(self.mesh.shard_array(a, 0) for a in blocks)
+                    with telemetry.span("mfsgd.coverage"):
+                        eu, ei, ev, ou, oi = insert_coverage_entries(
+                            eu, ei, ev, ou, oi, ub, u_tile)
+                blocks = (eu, ei, ev, ou, oi)
+            else:
+                bu, bi, bv, bm, ub, ibc = partition_ratings(
+                    users, items, vals, self.n_users, self.n_items, n,
+                    self.cfg.chunk, n_slices=self._n_slices,
+                )
+                if telemetry.enabled():
+                    work = (bm > 0).reshape(n, -1).sum(1)
+                    skew.record_partition(
+                        "mfsgd.partition", work, unit="ratings",
+                        padded_total=bm.size)
+                blocks = (bu, bi, bv, bm)
+            if work is not None:
+                # the record that counts what runs: the same ratings over
+                # the slots of the arrays as staged, after the coverage
+                # entries and the widening to the kernel's chunk multiple.
+                # Through the ledger, not the module hook: the health
+                # monitor has judged this per-worker work once already,
+                # under "mfsgd.partition"
+                skew.ledger.record_partition(
+                    "mfsgd.kernel_slots", work, unit="ratings",
+                    padded_total=blocks[0].size)
+            assert (ub, nc * ibc) == (self.u_bound, self.i_bound)
+            self._blocks = tuple(self.mesh.shard_array(a, 0) for a in blocks)
         self._multi_fns.clear()  # compiled executables bind to block shapes
         self.nnz = len(np.asarray(vals))
 
@@ -678,8 +702,6 @@ class MFSGD:
         """One rotation epoch; returns training RMSE over visited ratings."""
         if self._blocks is None:
             raise RuntimeError("call set_ratings() before train_epoch()")
-        from harp_tpu.utils import telemetry
-
         with telemetry.span("mfsgd.epoch"), \
                 telemetry.ledger.run("mfsgd.epochs", steps=1):
             t0 = time.perf_counter()
@@ -708,8 +730,6 @@ class MFSGD:
             raise RuntimeError("call set_ratings() before compile_epochs()")
         fn = self._multi_fns.get(epochs)
         if fn is None:
-            from harp_tpu.utils import telemetry
-
             jitted = make_multi_epoch_fn(self.mesh, self.cfg, epochs)
             # steps=0: lowering traces the comm sites (attributed to the
             # same tag the executions count under) without executing them
@@ -726,8 +746,6 @@ class MFSGD:
         :func:`make_multi_epoch_fn`).  Use
         ``fit()`` instead when checkpointing between epochs.
         """
-        from harp_tpu.utils import telemetry
-
         fn = self.compile_epochs(epochs)
         # the scan body's traced comm sites execute once per epoch
         with telemetry.span("mfsgd.epochs", epochs=epochs), \

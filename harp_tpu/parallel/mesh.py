@@ -29,7 +29,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from harp_tpu.utils import flightrec
+from harp_tpu.utils import flightrec, telemetry
 
 WORKER_AXIS = "workers"
 
@@ -162,8 +162,12 @@ class WorkerMesh:
         # flight recorder: shard_array is THE bulk ingest entry point;
         # record_h2d also feeds the same bytes to the memory ledger
         # (memrec, PR 19) as a 'staged' buffer entering the live set
-        flightrec.record_h2d(_nbytes(x))
-        return jax.device_put(x, NamedSharding(self.mesh, spec))
+        nbytes = _nbytes(x)
+        flightrec.record_h2d(nbytes)
+        # the span is the call as the host sees it: device_put returns
+        # before the array has arrived
+        with telemetry.span("mesh.shard_array", bytes=nbytes):
+            return jax.device_put(x, NamedSharding(self.mesh, spec))
 
     def shard_array_local(self, x_local, global_rows: int | None = None):
         """Assemble a dim-0-sharded global array from PER-PROCESS slices.

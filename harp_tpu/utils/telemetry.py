@@ -36,7 +36,8 @@ the existing tools: each enabled span also enters
 timeline next to :func:`harp_tpu.utils.profiling.annotate` regions), and
 :meth:`SpanTracer.summary` returns the same ``{name: {mean_s, total_s, n}}``
 shape as :class:`harp_tpu.utils.timing.Timer.summary`, so report code can
-merge both.
+merge both; :meth:`SpanTracer.durations` is the query a reader uses
+(by name, ancestor and absolute ``perf_counter`` time).
 
 Everything is **zero-cost when disabled** (the default): ``record_comm``
 returns before touching the tree, ``span`` yields without bookkeeping, and
@@ -51,6 +52,7 @@ from __future__ import annotations
 
 import contextlib
 import json
+import math
 import os
 import sys
 import time
@@ -361,6 +363,17 @@ class SpanTracer:
             if attrs:
                 rec.update(attrs)
             self.records.append(rec)
+
+    def durations(self, name: str, under: str | None = None,
+                  t0: float = -math.inf, t1: float = math.inf) -> list[float]:
+        """Seconds of every recorded span ``name`` that lies inside
+        ``[t0, t1]`` (absolute ``time.perf_counter`` seconds) and, with
+        ``under``, has a span of that name among its ancestors."""
+        return [r["dur"] for r in self.records
+                if r["span"] == name
+                and (under is None or under in r["path"].split("/")[:-1])
+                and self._t0 + r["t0"] >= t0
+                and self._t0 + r["t0"] + r["dur"] <= t1]
 
     def summary(self) -> dict[str, dict[str, float]]:
         """Per-name aggregate in :meth:`Timer.summary`'s shape, so span and
