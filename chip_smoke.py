@@ -333,6 +333,7 @@ def _check_mfsgd(run):
     import jax.numpy as jnp
 
     from harp_tpu.models import mfsgd
+    from harp_tpu.ops.mfsgd_kernel import insert_coverage_entries
 
     R, UB, IB, NE, C, tile = 64, 2048, 13440, 8, 2048, 256
     rng = np.random.default_rng(1)
@@ -345,7 +346,12 @@ def _check_mfsgd(run):
     ou = (np.arange(NE) * tile).astype(np.int32)  # u-major, full coverage
     oi = (rng.integers(0, IB // tile, NE) * tile).astype(np.int32)
     block = tuple(jnp.asarray(a) for a in (eu, ei, ev, ou, oi))
-    Wt, Ht, se, cnt = run(jnp.asarray(W.T), jnp.asarray(H.T), *block)
+    # the kernel's layout of the same entries: four 512-wide chunks each
+    chunks = insert_coverage_entries(
+        *(a[None] for a in (eu, ei, ev, ou, oi)), UB, tile, tile)
+    assert chunks[0].shape == (1, 4 * NE, 512)
+    Wt, Ht, se, cnt = run(jnp.asarray(W.T), jnp.asarray(H.T),
+                          *(jnp.asarray(a[0]) for a in chunks))
     cfg = mfsgd.MFSGDConfig(rank=R, algo="dense", u_tile=tile, i_tile=tile,
                             lr=0.01, reg=0.05)
     W2, H2, se2, cnt2 = jax.jit(

@@ -92,8 +92,11 @@ class MFSGDConfig:
     # old algo's resolved value (review finding, round 5).
     u_tile: int | None = None
     i_tile: int | None = None
-    # max ratings per dense entry; overfull tiles split into several entries
-    # (keeps padding bounded under power-law item skew)
+    # max ratings per dense entry (one snapshot, one apply: the minibatch);
+    # overfull tiles split into several entries.  algo="dense" stages every
+    # entry this wide or as wide as the heaviest tile, whichever is less;
+    # the pallas kernel stages only the 512-wide chunks that hold ratings
+    # (ops/mfsgd_kernel.py), so there it caps the minibatch alone
     entry_cap: int = 2048
     # dense matmul operand dtype: bf16 is MXU-native (gather/scatter one-hots
     # are exact 0/1 either way; W/H operands round to bf16 — noise well under
@@ -490,13 +493,14 @@ def _tile_block_update(W, H, block, cfg: MFSGDConfig):
 
 def _pallas_tile_block_update(W, H, block, cfg: MFSGDConfig):
     """Fused-kernel twin of :func:`_tile_block_update` (same entries, same
-    order — see ops/mfsgd_kernel.py).  Factors transpose to rank-major at
-    the block boundary; ~0.3 ms/epoch of HBM traffic at ML-20M scale."""
+    order, staged as the list of chunks that hold their ratings — see
+    ops/mfsgd_kernel.py).  Factors transpose to rank-major at the block
+    boundary; ~0.3 ms/epoch of HBM traffic at ML-20M scale."""
     from harp_tpu.ops.mfsgd_kernel import sgd_tile_update
 
-    eu, ei, ev, ou, oi = block
+    cu, ci, cv, meta = block
     Wt, Ht, se, cnt = sgd_tile_update(
-        W.T, H.T, eu, ei, ev, ou, oi,
+        W.T, H.T, cu, ci, cv, meta,
         lr=cfg.lr, reg=cfg.reg, u_tile=tiles(cfg)[0], i_tile=tiles(cfg)[1],
         compute_dtype=cfg.compute_dtype,
         interpret=interpret_default())
@@ -556,7 +560,8 @@ def _epoch_device_fn(mesh: WorkerMesh, cfg: MFSGDConfig):
 
 
 def _n_block_args(cfg: MFSGDConfig) -> int:
-    return 5 if cfg.algo in _DENSE_ALGOS else 4
+    # dense: eu/ei/ev/ou/oi; pallas: cu/ci/cv/meta; scatter: u/i/v/mask
+    return 5 if cfg.algo == "dense" else 4
 
 
 def make_epoch_fn(mesh: WorkerMesh, cfg: MFSGDConfig):
@@ -669,9 +674,10 @@ class MFSGD:
                         insert_coverage_entries)
 
                     with telemetry.span("mfsgd.coverage"):
-                        eu, ei, ev, ou, oi = insert_coverage_entries(
-                            eu, ei, ev, ou, oi, ub, u_tile)
-                blocks = (eu, ei, ev, ou, oi)
+                        blocks = insert_coverage_entries(
+                            eu, ei, ev, ou, oi, ub, u_tile, i_tile)
+                else:
+                    blocks = (eu, ei, ev, ou, oi)
             else:
                 bu, bi, bv, bm, ub, ibc = partition_ratings(
                     users, items, vals, self.n_users, self.n_items, n,
@@ -685,8 +691,8 @@ class MFSGD:
                 blocks = (bu, bi, bv, bm)
             if work is not None:
                 # the record that counts what runs: the same ratings over
-                # the slots of the arrays as staged, after the coverage
-                # entries and the widening to the kernel's chunk multiple.
+                # the slots of the arrays as staged — for the kernel, the
+                # chunks that hold ratings plus its no-op chunks.
                 # Through the ledger, not the module hook: the health
                 # monitor has judged this per-worker work once already,
                 # under "mfsgd.partition"

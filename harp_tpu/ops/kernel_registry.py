@@ -149,14 +149,14 @@ def _lda_cgs():
 
 
 # mfsgd.sgd_tile_update at the 8-worker-sim smoke tiling (R=64,
-# UB=2048, IB=13440, NE=8, C=2048, tile=256): 6·R flops per rating over
-# NE·C rating slots; min bytes = W/H blocks in+out (f32) + entry
-# streams; vmem = the kernel's own budget algebra: TWO resident H
-# copies (h_in + h_out) + four [R, tile] scratch tiles + chunk streams.
+# UB=2048, IB=13440, NCH=32 chunks of 512, tile=256): 6·R flops per
+# rating over NCH·512 rating slots; min bytes = W/H blocks in+out (f32)
+# + chunk streams; vmem = the kernel's own budget algebra: TWO resident
+# H copies (h_in + h_out) + four [R, tile] scratch tiles + chunk streams.
 @register_kernel("mfsgd.sgd_tile_update",
-                 flops=6 * 64 * 8 * 2048,
+                 flops=6 * 64 * 32 * 512,
                  min_hbm_bytes=(2 * 4 * (64 * 2048 + 64 * 13440)
-                                + 3 * 4 * 8 * 2048),
+                                + 3 * 4 * 32 * 512),
                  vmem_bytes=2 * 13440 * 64 * 4 + 4 * 64 * 256 * 4
                  + 3 * 4 * 512)
 def _mfsgd_tile():
@@ -167,16 +167,15 @@ def _mfsgd_tile():
     from harp_tpu.ops.mfsgd_kernel import sgd_tile_update
 
     # the 8-worker-sim smoke tiling pinned in tests/test_mfsgd_kernel.py
-    R, UB, IB, NE, C, tile = 64, 2048, 13440, 8, 2048, 256
+    R, UB, IB, NCH, cc, tile = 64, 2048, 13440, 32, 512, 256
     fn = functools.partial(sgd_tile_update, lr=0.01, reg=0.05,
                            u_tile=tile, i_tile=tile, interpret=False)
     return fn, (jnp.zeros((R, UB), jnp.float32),
                 jnp.zeros((R, IB), jnp.float32),
-                jnp.zeros((NE, C), jnp.int32),
-                jnp.zeros((NE, C), jnp.int32),
-                jnp.zeros((NE, C), jnp.float32),
-                jnp.zeros(NE, jnp.int32),
-                jnp.zeros(NE, jnp.int32))
+                jnp.zeros((NCH, cc), jnp.int32),
+                jnp.zeros((NCH, cc), jnp.int32),
+                jnp.zeros((NCH, cc), jnp.float32),
+                jnp.zeros(NCH, jnp.int32))
 
 
 # flash_attention at (batch=2, T=256, d=128), causal: 4·T²·d flops per

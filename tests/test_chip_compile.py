@@ -43,6 +43,19 @@ def _compile_all() -> dict:
     def mosaic_calls(fn, sds):
         return fn.lower(*sds).compile().as_text().count(chip_smoke.MOSAIC_CALL)
 
+    def mfsgd_args(algo, n_dev, ns, u_bound, ibc, ne, c):
+        """W, H and a half-slice's block: ``ne`` entries ``c`` wide with
+        their offsets, or for the kernel ``ne`` chunks with their
+        metadata (cu / ci / cv / meta)."""
+        i32, f32 = jnp.int32, jnp.float32
+        rows = ns * n_dev
+        blocks = [((rows, ne, c), i32), ((rows, ne, c), i32),
+                  ((rows, ne, c), f32), ((rows, ne), i32)]
+        if algo == "dense":
+            blocks.append(((rows, ne), i32))
+        return [sds(s, dt) for s, dt in [
+            ((u_bound * n_dev, 64), f32), ((ibc * ns, 64), f32), *blocks]]
+
     for n_dev in (1, 4):
         mesh = WorkerMesh(devices[:n_dev])
         rows = mesh.sharding(mesh.spec(0, ndim=2))
@@ -79,15 +92,26 @@ def _compile_all() -> dict:
             # one entry per (u_tile × i_tile) sub-tile of a block: 20M
             # ratings over the grid average well under entry_cap per tile
             ne, c = (u_bound // ut) * (ibc // it), cfg.entry_cap
-            i32, f32 = jnp.int32, jnp.float32
-            shapes = [((u_bound * n_dev, 64), f32), ((ibc * ns, 64), f32),
-                      ((ns * n_dev, ne, c), i32), ((ns * n_dev, ne, c), i32),
-                      ((ns * n_dev, ne, c), f32), ((ns * n_dev, ne), i32),
-                      ((ns * n_dev, ne), i32)]
+            if algo == "pallas":  # every entry all its 512-wide chunks
+                ne, c = ne * (c // 512), 512
             progs[f"mfsgd.{algo}"] = mosaic_calls(
                 mfsgd.make_multi_epoch_fn(mesh, cfg, epochs=3),
-                [sds(s, dt) for s, dt in shapes])
+                mfsgd_args(algo, n_dev, ns, u_bound, ibc, ne, c))
         out[f"full_width_{n_dev}"] = progs
+
+    # the benchmark's cell mfsgd-epochs (perf/configs/mfsgd-ml20m-x4-r64):
+    # one chip, 553,972 users, blocks of 4 epochs, a chunk list longer
+    # than any of its seeds staged (133,611–136,739 chunks a half-slice).
+    # The kernel prefetches the list's metadata into SMEM whole.
+    mesh = WorkerMesh(devices[:1])
+    cfg = mfsgd.MFSGDConfig(rank=64, algo="pallas")
+    _, _, u_bound, ibc = mfsgd._dense_bounds(
+        553_972, 26_744, 1, 2, *mfsgd.tiles(cfg))
+    out["mfsgd_cell"] = {
+        "u_bound": u_bound,
+        "mosaic_calls": mosaic_calls(
+            mfsgd.make_multi_epoch_fn(mesh, cfg, epochs=4),
+            mfsgd_args("pallas", 1, 2, u_bound, ibc, 140_000, 512))}
 
     # every builder in the registry through the real Mosaic compiler
     one = jax.sharding.SingleDeviceSharding(devices[0])
@@ -124,6 +148,12 @@ def test_full_width_programs_compile_for_v5e(compiled, n_dev):
     # a Mosaic call in each Pallas program, none in its XLA twin
     for name, calls in progs.items():
         assert (calls > 0) == ("pallas" in name), (name, calls)
+
+
+def test_mfsgd_cell_epochs_compile_for_v5e(compiled):
+    """~134k chunks a half-slice, u_bound 553,984, rank 64: the
+    scalar-prefetch (SMEM) budget at the benchmark cell's real size."""
+    assert compiled["mfsgd_cell"] == {"u_bound": 553_984, "mosaic_calls": 1}
 
 
 def test_registered_kernels_compile_for_v5e(compiled):

@@ -489,12 +489,11 @@ def test_mfsgd_chunked_int8_pallas_epoch_lowers_for_tpu(mesh, monkeypatch):
     n, ns = 8, 4 * 8
     _, _, u_bound, ibc = MF._dense_bounds(2048, 8192, n, ns,
                                           *MF.tiles(cfg))
-    NE, Cw = 4, 256
+    NCH, Cw = 4, 256  # the kernel's chunk list: cu / ci / cv / meta
     i32, f32 = jnp.int32, jnp.float32
     shapes = [((u_bound * n, 8), f32), ((4 * ibc * n, 8), f32),
-              ((n * ns, NE, Cw), i32), ((n * ns, NE, Cw), i32),
-              ((n * ns, NE, Cw), f32), ((n * ns, NE), i32),
-              ((n * ns, NE), i32)]
+              ((n * ns, NCH, Cw), i32), ((n * ns, NCH, Cw), i32),
+              ((n * ns, NCH, Cw), f32), ((n * ns, NCH), i32)]
     sds = [jax.ShapeDtypeStruct(s, d, sharding=mesh.sharding(mesh.spec(0)))
            for s, d in shapes]
     fn = MF.make_multi_epoch_fn(mesh, cfg, epochs=2)
