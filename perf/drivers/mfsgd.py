@@ -110,5 +110,4 @@ class Driver:
         return out
 
     def extra(self) -> dict:
-        part = skew.ledger.summary().get("mfsgd.partition", {})
-        return {"padding_frac": part.get("padding_frac")}
+        return {}

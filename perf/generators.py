@@ -104,23 +104,34 @@ def skewed_ratings(data: dict, seed: int):
     item is drawn, independently, from the multiset that holds item ``i``
     ``deg_i`` times, so the item side is met in expectation (the heaviest
     item to about its square root) and a pair can repeat.  Ids are
-    permuted by the seed so that weight does not follow id.  Values are a
+    permuted so that weight does not follow id.  Values are a
     rank-``truth_rank`` ground truth plus ``noise`` x N(0,1), float32
     throughout.  Ratings come out user-major, as the source's files are.
     Made in ``BANDS`` bands of users, one child seed each, by a few
     threads.
+
+    What is the data set's and what the run's: WHICH ids are heavy (the
+    two id permutations) is a property of a data set — in ml-20m the same
+    movies are the popular ones in whatever sample is drawn — so a
+    ``data`` block may pin it with ``id_seed``; the ground truth, every
+    rating's item and every value always come from ``seed``.  Without
+    ``id_seed`` the permutations come from ``seed`` as well: each seed
+    then deals the heavy ids into other tiles, and the partition's tile
+    and chunk counts move with it by percents.
     """
     n_users, n_items, nnz = data["n_users"], data["n_items"], data["nnz"]
     ss = np.random.SeedSequence(seed)
     head, *children = ss.spawn(BANDS + 1)
     rng = np.random.default_rng(head)
+    ids = (np.random.default_rng(data["id_seed"]) if "id_seed" in data
+           else rng)
     du = lognormal_degrees(n_users, nnz, data["user_min"], data["user_max"],
                            data["user_median"])
     di = lognormal_degrees(n_items, nnz, data["item_min"], data["item_max"],
                            data["item_median"])
     du_by_id = np.empty(n_users, np.int64)
-    du_by_id[rng.permutation(n_users)] = du
-    iid = rng.permutation(n_items).astype(np.int32)
+    du_by_id[ids.permutation(n_users)] = du
+    iid = ids.permutation(n_items).astype(np.int32)
     wt, ht = _truth(n_users, n_items, data["truth_rank"], rng)
     noise = np.float32(data["noise"])
     users, off, edges = _repeat_ids(np.arange(n_users, dtype=np.int32),

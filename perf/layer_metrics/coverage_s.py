@@ -1,5 +1,6 @@
 """Seconds in set-up inside the program's ``mfsgd.coverage`` span:
-``insert_coverage_entries`` rebuilding the entry arrays for the kernel."""
+``insert_coverage_entries`` building the kernel's chunk list from the
+partition's entries."""
 
 from perf import program_telemetry
 
