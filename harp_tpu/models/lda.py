@@ -58,7 +58,7 @@ from harp_tpu.models.mfsgd import (
     partition_ratings_tiles,
     rotate_chunks_resolved,
 )
-from harp_tpu.utils import flightrec, prng, skew
+from harp_tpu.utils import flightrec, prng, skew, telemetry
 
 
 @dataclasses.dataclass
@@ -85,17 +85,13 @@ class LDAConfig:
     # acceptable.
     # Delta matmuls are EXACT in bf16 (operands are 0/±1; f32 accumulate),
     # so counts remain integers on all paths.
-    # FLIPPED to "pallas" 2026-08-01 (1× v5e, FLIP_DECISIONS.jsonl):
-    # fused kernel + exprace + rbg + carry_db measured 10.50M
-    # tok/s/chip vs 6.46M dense gumbel = 1.63× at equal likelihood
-    # (−12.0815 vs −12.0824, tol 0.05) at the 100k-doc × 1k-topic
-    # sweep shape; the plain kernel alone is 7.92M = 1.23×.
+    # "pallas" (default since 2026-08-01, FLIP_DECISIONS.jsonl) is the
+    # fused kernel of ops/lda_kernel.py: exprace draw over hardware
+    # random bits, exact count gathers, the doc-tile carry.
     algo: str = "pallas"
     d_tile: int = 512   # dense: doc-topic tile rows
     w_tile: int = 512   # dense: word-topic tile rows
-    entry_cap: int = 2048  # dense/pallas: max tokens per tile entry —
-    # 2048 measured best on the kernel+carry stack (2026-08-01, 1× v5e:
-    # 10.5M tok/s vs 10.17M @1024 / 10.30M @4096)
+    entry_cap: int = 2048  # dense/pallas: max tokens per tile entry
     chunk: int = 8192   # scatter/pushpull: tokens sampled per count-snapshot
     # pushpull: row-request slots per (worker, owner) pair and chunk.  The
     # default (= chunk) guarantees zero drops (a chunk can never request
@@ -135,13 +131,11 @@ class LDAConfig:
     # prototype was reverted there), so the sweep configs lda_carry /
     # lda_pallas_carry measure it and the flip gate decides (VERDICT r3
     # item 2's queued decision, now one flag).  FLIPPED ON 2026-08-01
-    # for the pallas stack: lda_pallas_carry measured 10.50M tok/s =
-    # 1.33× over the plain kernel (1.63× over dense) on 1× v5e — the
-    # trace shows the carry removing the dominant [K, d_tile] DUS
-    # write-back; chain bit-identical (silicon kernel_equiv_check) and
-    # no whole-table copies in the HLO.  The DENSE-stack arm
-    # (`lda_carry`, 1.13×) was VETOED by the conditional gate, so the
-    # auto default stays off there.
+    # for the pallas stack: the carry removes the dominant [K, d_tile]
+    # DUS write-back; chain bit-identical (silicon kernel_equiv_check)
+    # and no whole-table copies in the HLO.  The DENSE-stack arm
+    # (`lda_carry`) was VETOED by the conditional gate, so the auto
+    # default stays off there.
     # None = "auto per algo", STORED as None and resolved at READ time by
     # :func:`carry_db_resolved` (mirrors MFSGDConfig.tiles() /
     # KMeansConfig._use_pallas — a __post_init__ resolution froze the
@@ -172,10 +166,9 @@ class LDAConfig:
     # at rates p_k is k with probability p_k/Σp) with 1 log + 2 mul +
     # 1 div per element, ~5× fewer transcendentals on the VPU.  Same
     # chain statistics, different random stream.  FLIPPED 2026-08-01
-    # with the pallas algo (its required stack; the lda_fast A/B alone
-    # measured exprace+rbg 1.24× over gumbel+threefry at equal LL,
-    # while exprace+threefry was 0.98× — the noise TENSOR, not the
-    # transcendentals, was the wall).
+    # with the pallas algo (its required stack; exprace pays only
+    # together with rbg: the noise TENSOR, not the transcendentals, was
+    # the wall).
     sampler: str = "exprace"
     # Random-bit source for the per-[token, K] draws.  "threefry"
     # (default): JAX's counter-based PRNG — splittable, reproducible
@@ -185,8 +178,7 @@ class LDAConfig:
     # hardware generator, near-free, still deterministic per key but a
     # different (backend-dependent) stream.  Chain statistics unaffected
     # (any iid uniform source is a valid Gibbs draw).  FLIPPED
-    # 2026-08-01 with the pallas algo (see sampler above — rbg is where
-    # the lda_fast 1.24× comes from).
+    # 2026-08-01 with the pallas algo (see sampler above).
     rng_impl: str = "rbg"
     # Rotation pipeline knobs (rotation algos only — pushpull never
     # rotates).  Same contract as MFSGDConfig: rotate_chunks None = auto
@@ -661,6 +653,14 @@ def _device_epoch_fn(mesh: WorkerMesh, cfg: LDAConfig, vocab_size: int,
     return _epoch_device_fn(mesh, cfg, vocab_size, count_bounds)
 
 
+#: the chain's state (Ndk, Nwk, Nk, z_grid) is donated to the sweep
+#: programs: the driver installs the outputs in its place, and a second
+#: word-topic table (4 GB at a 1M-word vocabulary and 1k topics) beside
+#: the sweep's own temporaries does not fit a 16 GB chip (18.7 GB
+#: undonated, 14.6 GB donated: tests/test_chip_compile.py)
+_STATE_ARGS = (0, 1, 2, 3)
+
+
 def _n_token_args(cfg: LDAConfig) -> int:
     return 5 if cfg.algo in _TILED_ALGOS else 4  # (+ keys)
 
@@ -680,6 +680,10 @@ def make_epoch_fn(mesh: WorkerMesh, cfg: LDAConfig, vocab_size: int,
     ``count_bounds``: static (max doc-topic, max word-topic) count bounds
     the pallas kernel uses to pick its exact-gather plane counts — chain
     invariants derived by ``LDA._install_pack`` from the initial tables.
+
+    The first four arguments (``Ndk``, ``Nwk``, ``Nk``, ``z_grid``) are
+    DONATED (``_STATE_ARGS``): where the backend honours donation a
+    handle to them is deleted by the call; keep what the call returns.
     """
     return jax.jit(
         mesh.shard_map(
@@ -687,7 +691,8 @@ def make_epoch_fn(mesh: WorkerMesh, cfg: LDAConfig, vocab_size: int,
             in_specs=(mesh.spec(0), mesh.spec(0), P(), mesh.spec(0))
             + (mesh.spec(0),) * _n_token_args(cfg),
             out_specs=_epoch_out_specs(mesh, cfg),
-        )
+        ),
+        donate_argnums=_STATE_ARGS,
     )
 
 
@@ -699,7 +704,8 @@ def make_multi_epoch_fn(mesh: WorkerMesh, cfg: LDAConfig, vocab_size: int,
     dispatch and one readback per run, not per sweep).  Each sweep's
     RNG key is derived on device by folding the epoch index into the
     worker's base key, so the chain is identical to per-epoch dispatches
-    with the same derivation.
+    with the same derivation.  The first four arguments are donated, as
+    :func:`make_epoch_fn`'s are.
     """
     inner = _device_epoch_fn(mesh, cfg, vocab_size, count_bounds)
 
@@ -731,7 +737,8 @@ def make_multi_epoch_fn(mesh: WorkerMesh, cfg: LDAConfig, vocab_size: int,
             in_specs=(mesh.spec(0), mesh.spec(0), P(), mesh.spec(0))
             + (mesh.spec(0),) * _n_token_args(cfg),
             out_specs=_epoch_out_specs(mesh, cfg),
-        )
+        ),
+        donate_argnums=_STATE_ARGS,
     )
 
 
@@ -822,8 +829,17 @@ def epoch_arg_shapes(n_workers, n_docs, vocab_size, cfg: LDAConfig,
       (a corpus whose hot tiles fill their caps — enwiki's Zipf vocab
       does; the partitioner shrinks C below the cap only when every tile
       is small) and ``entries_per_row`` defaults to
-      ``ceil(tokens_per_grid_row / C)`` — tight packing.  Pass the real
-      partitioner's NE/C to model a specific corpus.
+      ``ceil(tokens_per_grid_row / C)`` — tight packing.  That default
+      is a LOWER BOUND, exact only where every occupied tile fills its
+      entries: an entry holds ONE (d_tile x w_tile) tile's tokens, so a
+      corpus stages at least one entry an occupied tile.  At the source's
+      1M-word vocabulary (1,954 word tiles) a 512-document tile row holds
+      ~76 tokens a tile and stages 977-989 entries a half-slice: 6,656
+      documents stage NE = 12,859 where tight packing says 472 (27x;
+      30-80x at 100-290 tokens a document, CPU counts, PRs 27 and 29),
+      and the token arrays are that much larger.  Pass the real
+      partitioner's NE/C to model a specific corpus
+      (tests/test_lda_scale.py does for a Zipf one).
     """
     n, K = n_workers, cfg.n_topics
     ns = rotate_chunks_resolved(cfg) * n  # chunk-slices (pushpull: unused)
@@ -952,6 +968,30 @@ class LDA:
         the move itself is chain-preserving."""
         n = self.mesh.num_workers
         K = self.cfg.n_topics
+        with telemetry.span("lda.pack_tokens", tokens=len(doc_ids)):
+            with telemetry.span("lda.pack.partition"):
+                tokens, z_grid = self._partition_tokens(doc_ids, word_ids,
+                                                        z0)
+            # initial count tables from the assignments (host, exact)
+            with telemetry.span("lda.pack.counts"):
+                Ndk = np.zeros((self.d_bound * n, K),
+                               np.dtype(self.cfg.ndk_dtype))
+                Nwk = np.zeros((self.w_bound * n, K), np.float32)
+                gd, gw, gm = self._global_token_ids(tokens)
+                gz = z_grid.reshape(-1)
+                np.add.at(Ndk, (gd[gm], gz[gm]), 1)  # int: Ndk may be int16
+                np.add.at(Nwk, (gw[gm], gz[gm]), 1.0)
+                Nk = Nwk.sum(0)
+        return {"tokens": tuple(tokens), "z_grid": z_grid, "Ndk": Ndk,
+                "Nwk": Nwk, "Nk": Nk, "n_tokens": int(gm.sum())}
+
+    def _partition_tokens(self, doc_ids, word_ids, z0):
+        """The layout half of :meth:`pack_tokens`: ``(tokens, z_grid)`` in
+        this config's device layout (the MF-SGD grid partitioners, whose
+        ``mfsgd.partition.sort`` / ``.pack`` spans nest below the
+        caller's ``lda.pack.partition``)."""
+        n = self.mesh.num_workers
+        K = self.cfg.n_topics
         if self.cfg.ndk_dtype == "int16":
             # a doc-topic count is bounded by the doc's token count; wrap
             # past int16 would corrupt counts SILENTLY (the posterior
@@ -1009,17 +1049,7 @@ class LDA:
             assert (db, nc * wbc) == (self.d_bound, self.w_bound)
             z_grid = bz.astype(np.int32)
             tokens = (bd, bw, bm)
-
-        # initial count tables from the assignments (host, exact)
-        Ndk = np.zeros((self.d_bound * n, K), np.dtype(self.cfg.ndk_dtype))
-        Nwk = np.zeros((self.w_bound * n, K), np.float32)
-        gd, gw, gm = self._global_token_ids(tokens)
-        gz = z_grid.reshape(-1)
-        np.add.at(Ndk, (gd[gm], gz[gm]), 1)  # int literal: Ndk may be int16
-        np.add.at(Nwk, (gw[gm], gz[gm]), 1.0)
-        Nk = Nwk.sum(0)
-        return {"tokens": tuple(tokens), "z_grid": z_grid, "Ndk": Ndk,
-                "Nwk": Nwk, "Nk": Nk, "n_tokens": int(gm.sum())}
+        return tokens, z_grid
 
     def _install_pack(self, pack: dict) -> None:
         """Device half of :meth:`set_tokens`: shard a
@@ -1042,8 +1072,6 @@ class LDA:
             self._epoch_fn = flightrec.track(
                 make_epoch_fn(self.mesh, self.cfg, self.vocab_size,
                               self._count_bounds), "lda.epoch")
-        from harp_tpu.utils import telemetry
-
         if telemetry.enabled():
             # ingest-side skew record (host arithmetic over the pack —
             # also fires for cached packs, which skip pack_tokens)
@@ -1051,11 +1079,23 @@ class LDA:
             per = gm.reshape(n, -1).sum(1)
             skew.record_partition("lda.partition", per, unit="tokens",
                                   padded_total=gm.size)
-        self.Ndk, self.Nwk = sh(pack["Ndk"], 0), sh(pack["Nwk"], 0)
-        self.Nk = jax.device_put(jnp.asarray(pack["Nk"]),
-                                 self.mesh.replicated())
-        self.z_grid = sh(np.asarray(pack["z_grid"], np.int32), 0)
-        self._tokens = tuple(sh(a, 0) for a in pack["tokens"])
+            # the record that counts what runs (the twin of
+            # ``mfsgd.kernel_slots``): the same tokens over the slots of
+            # the arrays AS STAGED — for the tiled algos NE x C a grid
+            # row, every one of which a sweep executes.  Through the
+            # ledger, not the module hook: the health monitor has judged
+            # this per-worker work once already, under "lda.partition"
+            skew.ledger.record_partition(
+                "lda.kernel_slots", per, unit="tokens",
+                padded_total=pack["tokens"][0].size)
+        placed = (pack["Ndk"], pack["Nwk"], pack["z_grid"], *pack["tokens"])
+        with telemetry.span("lda.install",
+                            bytes=sum(a.nbytes for a in placed)):
+            self.Ndk, self.Nwk = sh(pack["Ndk"], 0), sh(pack["Nwk"], 0)
+            self.Nk = jax.device_put(jnp.asarray(pack["Nk"]),
+                                     self.mesh.replicated())
+            self.z_grid = sh(np.asarray(pack["z_grid"], np.int32), 0)
+            self._tokens = tuple(sh(a, 0) for a in pack["tokens"])
         self._multi_fns.clear()  # compiled programs bind to token shapes
         self.n_tokens = int(pack["n_tokens"])
         # raw key bits (utils.prng): bit-identical to split(PRNGKey(seed))
@@ -1149,8 +1189,6 @@ class LDA:
             raise RuntimeError("call set_tokens() before compile_epochs()")
         fn = self._multi_fns.get(epochs)
         if fn is None:
-            from harp_tpu.utils import telemetry
-
             jitted = make_multi_epoch_fn(
                 self.mesh, self.cfg, self.vocab_size, epochs,
                 self._count_bounds)
@@ -1183,8 +1221,6 @@ class LDA:
         """Run ``epochs`` Gibbs sweeps as one device program (one dispatch,
         one sync) — see :func:`make_multi_epoch_fn`.  Use :meth:`fit` when
         checkpointing between sweeps."""
-        from harp_tpu.utils import telemetry
-
         fn = self.compile_epochs(epochs)
         keys = self.mesh.shard_array(self._keys, 0)
         # the scan body's traced comm sites execute once per Gibbs sweep
@@ -1203,8 +1239,6 @@ class LDA:
     def sample_epoch(self):
         if self._tokens is None:
             raise RuntimeError("call set_tokens() before sample_epoch()")
-        from harp_tpu.utils import telemetry
-
         keys = self.mesh.shard_array(self._keys, 0)
         with telemetry.span("lda.epoch"), \
                 telemetry.ledger.run("lda.epochs", steps=1):

@@ -113,6 +113,30 @@ def _compile_all() -> dict:
             mfsgd.make_multi_epoch_fn(mesh, cfg, epochs=4),
             mfsgd_args("pallas", 1, 2, u_bound, ibc, 140_000, 512))}
 
+    # the benchmark's cell lda-sweeps (perf/configs/lda-enwiki-v1m-k1k):
+    # one chip, 1k topics over a 1M-word vocabulary, 6,656 documents, one
+    # sweep a program, the entries every seed stages (12,859 a half-slice)
+    # and count bounds above its corpus' (a 6,411-token document, a word
+    # of 204,574 tokens: 2 and 3 gather planes).  What the chip must
+    # hold: the arguments and the program's temporaries, the state donated.
+    from harp_tpu.models import lda
+
+    cfg = lda.LDAConfig(n_topics=1000)
+    fn = lda.make_multi_epoch_fn(mesh, cfg, 1_000_000, 1, (7000, 210_000))
+    compiled = fn.lower(*[
+        jax.ShapeDtypeStruct(
+            shape, dt, sharding=(mesh.replicated() if i == 2 else
+                                 mesh.sharding(mesh.spec(0, ndim=len(shape)))))
+        for i, (shape, dt) in enumerate(lda.epoch_arg_shapes(
+            1, 6656, 1_000_000, cfg, entries_per_row=12_859,
+            entry_width=2048))]).compile()
+    mem = compiled.memory_analysis()
+    out["lda_cell"] = {
+        "mosaic_calls": compiled.as_text().count(chip_smoke.MOSAIC_CALL),
+        "aliased_gb": round(mem.alias_size_in_bytes / 1e9, 2),
+        "held_gb": round((mem.argument_size_in_bytes
+                          + mem.temp_size_in_bytes) / 1e9, 1)}
+
     # every builder in the registry through the real Mosaic compiler
     one = jax.sharding.SingleDeviceSharding(devices[0])
     out["registry"] = {
@@ -154,6 +178,18 @@ def test_mfsgd_cell_epochs_compile_for_v5e(compiled):
     """~134k chunks a half-slice, u_bound 553,984, rank 64: the
     scalar-prefetch (SMEM) budget at the benchmark cell's real size."""
     assert compiled["mfsgd_cell"] == {"u_bound": 553_984, "mosaic_calls": 1}
+
+
+def test_lda_cell_sweep_compiles_for_v5e_and_fits(compiled):
+    """The 4 GB word-topic table at the benchmark cell's real size: the
+    state is donated (the output takes the table's place), and arguments
+    plus temporaries stay under the chip's 16 GB: 14.8 GB.  Without the
+    donation the same program asks for 18.7 GB and the chip refuses it
+    at its first block (my chip runs, PRs 27 and 29)."""
+    cell = compiled["lda_cell"]
+    assert cell["mosaic_calls"] == 1
+    assert cell["aliased_gb"] >= 4.1  # the word-topic table, at least
+    assert cell["held_gb"] < 15.5
 
 
 def test_registered_kernels_compile_for_v5e(compiled):

@@ -91,6 +91,43 @@ def test_resume_rejects_mismatched_checkpoint_shapes(mesh, tmp_path):
         m2.fit(2, ckpt, ckpt_every=1)
 
 
+def test_donated_state_survives_restart_and_checkpoint(mesh, tmp_path):
+    """The sweep programs donate the chain's state (``_STATE_ARGS``): a
+    handle taken before a sweep is gone after it where the backend
+    honours donation, and ``fit``'s entry snapshot, its checkpoints and
+    a restart from them still sample the chain an undisturbed run does."""
+    from harp_tpu.utils.fault import FaultInjector
+
+    d, w = L.synthetic_corpus(32, 24, 2, tokens_per_doc=6, seed=0)
+    cfg = L.LDAConfig(n_topics=4, algo="dense", d_tile=8, w_tile=8,
+                      entry_cap=16)
+
+    def model():
+        m = L.LDA(32, 24, cfg, mesh, seed=0)
+        m.set_tokens(d, w)
+        return m
+
+    crashed, plain = model(), model()
+    held = crashed.Nwk
+    crashed.sample_epoch()
+    plain.sample_epoch()
+    if not held.is_deleted():
+        pytest.skip("this backend does not honour donation")
+    with pytest.raises(RuntimeError, match="deleted"):
+        np.asarray(held)
+    crashed.sample_epochs(2)
+    plain.sample_epochs(2)
+    # a crash before the first checkpoint (restart from the entry
+    # snapshot) and one after it (restart from the file)
+    crashed.fit(4, str(tmp_path / "lda"), ckpt_every=2,
+                fault=FaultInjector(fail_at=(1, 3)))
+    plain.fit(4)
+    assert float(crashed.Nwk.sum()) == crashed.n_tokens == len(d)
+    for name in ("Ndk", "Nwk", "Nk", "z_grid"):
+        np.testing.assert_array_equal(np.asarray(getattr(crashed, name)),
+                                      np.asarray(getattr(plain, name)))
+
+
 def test_sample_epochs_matches_convergence_contract(small_model):
     """Multi-epoch single-dispatch sampling keeps the count invariants and
     improves likelihood like per-epoch dispatches."""

@@ -140,6 +140,61 @@ def test_gather_planes_exact_above_256():
     assert approx[0, list(vals).index(257)] != 257.0
 
 
+def _exact_draws(DbT, WbT, nk, z, cd, cw, seed2, alpha, beta, vbeta):
+    """What one 256-token chunk must draw: the race over the uniforms the
+    interpret path streams in, on the counts as they stand, in float32
+    and in the kernel's order of operations."""
+    import jax
+    import jax.numpy as jnp
+
+    K, C = DbT.shape[0], len(z)
+    key = jax.random.wrap_key_data(jnp.asarray(seed2, jnp.uint32))
+    u = np.asarray(jax.random.uniform(key, (K, C), jnp.float32,
+                                      minval=2.0 ** -25, maxval=1.0))
+    own = (np.arange(K)[:, None] == z[None, :]).astype(np.float32)
+    a = np.maximum(DbT[:, cd] - own + np.float32(alpha), np.float32(1e-10))
+    b = np.maximum(WbT[:, cw] - own + np.float32(beta), np.float32(1e-10))
+    c = np.maximum(nk[:, None] - own + np.float32(vbeta), np.float32(1e-10))
+    return (-np.log(u) * c / (a * b)).argmin(0)
+
+
+def test_kernel_draws_the_exact_posterior_above_256():
+    """The guard the benchmark's ``correct`` cannot be (PERF.md section
+    7): on counts above 256 the kernel's every draw is the race's winner
+    on the EXACT counts, token for token, from the same uniforms; the
+    single-dot path, which sees 257 as 256 and 259 as 260, draws another
+    topic for some of them.  A kernel that drops exactness fails here."""
+    import jax.numpy as jnp
+
+    from harp_tpu.ops.lda_kernel import cgs_entry_update
+
+    K, DR, WR, C = 8, 8, 128, 256
+    rng = np.random.default_rng(3)
+    # word-topic counts that bfloat16 cannot hold: odd, in [257, 511]
+    # and [513, 1023]; document counts small (exact on both paths)
+    WbT = (2 * rng.integers(128, 512, (K, WR)) + 1).astype(np.float32)
+    DbT = rng.integers(20, 60, (K, DR)).astype(np.float32)
+    nk = WbT.sum(1) + 1000.0
+    assert (np.asarray(jnp.asarray(WbT).astype(jnp.bfloat16), np.float32)
+            != WbT).all()
+    kw = dict(alpha=0.1, beta=0.01, vbeta=10.0, interpret=True)
+    flipped = 0
+    for r in range(12):
+        z = rng.integers(0, K, C).astype(np.int32)
+        cd = rng.integers(0, DR, C).astype(np.int32)
+        cw = rng.integers(0, WR, C).astype(np.int32)
+        seed2 = np.array([5, 40 + r], np.int32)
+        want = _exact_draws(DbT, WbT, nk, z, cd, cw, seed2, 0.1, 0.01, 10.0)
+        args = [jnp.asarray(x) for x in (DbT, WbT, nk, z, cd, cw, seed2)]
+        got = np.asarray(cgs_entry_update(
+            *args, nwk_count_bound=1023, ndk_count_bound=60, **kw)[2])
+        np.testing.assert_array_equal(got, want)
+        rounded = np.asarray(cgs_entry_update(*args, exact_gathers=False,
+                                              **kw)[2])
+        flipped += int((rounded != want).sum())
+    assert flipped > 0  # read: 5 of the 3,072 draws (0.16%)
+
+
 def test_count_bounds_pick_fewer_planes_identically():
     """A static count bound lets the kernel gather with fewer digit
     planes (1 when every count ≤ 256 — the enwiki doc-length case);

@@ -83,6 +83,35 @@ def test_shape_model_matches_partitioner_dense(mesh):
     assert default_ne <= ne_real
 
 
+def test_shape_model_takes_the_real_partitioners_entries_on_a_zipf_corpus():
+    """A Zipf vocabulary over several word tiles (the benchmark's corpus
+    generator at a toy size, one worker, the pallas layout): the real
+    partitioner's NE and C fed through ``entries_per_row`` /
+    ``entry_width`` give ``pack_tokens``' shapes, and the tight-packing
+    default undercounts NE by what the light tiles leave empty: 28% at
+    these 16 word tiles, 30-80x at the 1,954 of a 1M-word vocabulary
+    (12,859 entries a half-slice against 472: PERF.md section 6, PR 29)."""
+    from harp_tpu.parallel.mesh import WorkerMesh
+    from perf import corpus
+
+    n_docs, vocab, n_tokens = 200, 2000, 20_000
+    doc, word = corpus.zipf_corpus(
+        {"n_docs": n_docs, "n_tokens": n_tokens, "vocab_size": vocab,
+         "zipf_exponent": 1.07, "doc_len_sigma": 0.9, "doc_len_min": 8,
+         "id_seed": 13}, 7, shard_docs=128)
+    cfg = L.LDAConfig(n_topics=16, d_tile=128, w_tile=128, entry_cap=256)
+    model = L.LDA(n_docs, vocab, cfg, WorkerMesh(jax.devices()[:1]))
+    model.set_tokens(doc, word)
+    _, ne_real, c_real = model._tokens[0].shape
+    _check_shapes(model, L.epoch_arg_shapes(
+        1, n_docs, vocab, cfg, n_tokens=n_tokens,
+        entries_per_row=ne_real, entry_width=c_real))
+    default_ne = L.epoch_arg_shapes(
+        1, n_docs, vocab, cfg, n_tokens=n_tokens)[4][0][1]
+    assert default_ne == 40 and ne_real > 1.2 * default_ne
+    assert c_real == cfg.entry_cap  # the hot tiles fill their cap
+
+
 def _sds(mesh, shapes):
     return [jax.ShapeDtypeStruct(
         shape, dt, sharding=(mesh.replicated() if i == 2
