@@ -110,7 +110,7 @@ class Driver:
         out = {"correct": True}
 
         def hold(name, value, limit):
-            out[name] = value
+            out[name], out[name + "_limit"] = value, limit
             if not value <= limit:  # a NaN fails too
                 out["correct"] = False
 

@@ -21,10 +21,12 @@ profiler's trace as ``perf:<name>`` so that device gaps can be labelled.
 from __future__ import annotations
 
 import contextlib
+import json
 import math
 import os
 import shutil
 import statistics
+import sys
 import time
 
 from perf import spec, trace_reduce, workmodels
@@ -317,6 +319,10 @@ def run_cell(root: str, workload: str, seed: int, seconds: float,
     telemetry.enable(telemetry_was)
 
     correct = bool(verdict["correct"]) and warm_ok and failed == 0
+    # every number the check held, beside its limit (plain numbers)
+    compared = json.loads(spec.dumps(
+        {k: {"value": v, "limit": verdict[k + "_limit"]}
+         for k, v in verdict.items() if k + "_limit" in verdict}))
     say("info " + spec.dumps({
         "cell": workload, "seed": seed, "mode": cell.traffic["mode"],
         "item": cell.config["item"], "items": run.items,
@@ -327,6 +333,7 @@ def run_cell(root: str, workload: str, seed: int, seconds: float,
         "setup_spans_s": {n: round(e - s, 3) for n, s, e in rec.spans
                           if e <= t0 and n != "setup"},
         "in_window": run.in_window, "cache_dir": cache_dir,
+        "check_s": round(rec.durations("check")[0], 3),
         "check": verdict}))
 
     device = {"platform": devices[0].platform, "kind": run.device_kind,
@@ -363,4 +370,8 @@ def run_cell(root: str, workload: str, seed: int, seconds: float,
             metrics[m["name"]] = {"value": value, "unit": m["unit"]}
     out["metrics"] = metrics
     out["device"] = device
+    out["compared"] = compared  # last on the line
+    for name, c in compared.items():  # and standard error's last lines
+        print(f"compared {name} {c['value']} limit {c['limit']}",
+              file=sys.stderr, flush=True)
     return out

@@ -32,8 +32,8 @@ def _with_parked(bench):
         bench["per_layer"] += p["per_layer"]
     return bench
 
-# the in-test override: the shape only (rows, widths and data scale),
-# and a block short enough for the CPU
+# the in-test override, by the configuration's driver: the shape only
+# (rows, widths and data scale), and a block short enough for the CPU
 TINY = {
     "kmeans": {"data": {"n_per_chip": 2048, "d": 16, "k": 8},
                "traffic": {"steps": 3, "trace_seconds": 0.2}},
@@ -45,11 +45,20 @@ TINY = {
               # order of visits matters far more than at 80M, so the toy
               # size gets a toy band (0.8% measured here)
               "reference": {"first_epoch_rtol": 0.03}},
+    # 20,000 tokens over 2 x 8 word tiles and 2 document tiles; the bands
+    # at this size are read in test_lda_check.py
+    "lda": {"data": {"n_docs": 200, "n_tokens": 20_000, "vocab_size": 2000,
+                     "n_topics": 16},
+            "knobs": {"d_tile": 128, "w_tile": 128, "entry_cap": 256},
+            "traffic": {"steps": 1, "trace_seconds": 0.05}},
 }
+_CELLS = {w["name"]: w for w in _with_parked(BENCH)["workloads"]}
+_CONFIGS = {c["name"]: c for c in BENCH["configs"]}
 
 
 def _tiny(cell_name):
-    return TINY["mfsgd" if cell_name.startswith("mfsgd") else "kmeans"]
+    config = _CONFIGS[_CELLS[cell_name]["config"]]
+    return TINY[spec.load_json(os.path.join(ROOT, config["file"]))["driver"]]
 
 
 @pytest.fixture()
@@ -73,8 +82,13 @@ def _run(root, cell, trace, override, lines=None):
 
 def _check_last_line(out, root, cell, trace):
     assert set(out) == {"correct", "attempted", "failed", "metrics",
-                        "device"} | ({"breakdown"} if trace else set())
+                        "device", "compared"} | (
+                            {"breakdown"} if trace else set())
     json.dumps(out)  # one JSON object, nothing numpy left in it
+    # every number the check held beside its limit, last on the line
+    assert list(out)[-1] == "compared" and out["compared"]
+    for c in out["compared"].values():
+        assert set(c) == {"value", "limit"} and c["value"] <= c["limit"]
     assert out["attempted"] >= 1 and out["failed"] == 0
     dev = out["device"]
     assert {"platform", "kind", "count", "memory_peak_bytes"} <= set(dev)
