@@ -215,7 +215,9 @@ def _configs(smoke):
                {"pack_cache": _BENCH_DATA})),
         # the DEFAULT LDAConfig stack since the 2026-08-01 flip (the
         # benchmark entry pins every knob explicitly so this row's
-        # identity survives any future default change)
+        # identity survives any future default change).  Since PR 32 the
+        # doc-tile carry is the kernel's own, so this and `lda_pallas`
+        # run one program; both names stay for the evidence rows
         "lda_pallas_carry": lambda: lda.benchmark(
             algo="pallas", carry_db=True,
             **({"n_docs": 256, "vocab_size": 128, "n_topics": 8,

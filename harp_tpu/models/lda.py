@@ -32,7 +32,6 @@ TPU-native design:
 from __future__ import annotations
 
 import dataclasses
-import functools
 import glob
 import os
 import re
@@ -91,7 +90,12 @@ class LDAConfig:
     algo: str = "pallas"
     d_tile: int = 512   # dense: doc-topic tile rows
     w_tile: int = 512   # dense: word-topic tile rows
-    entry_cap: int = 2048  # dense/pallas: max tokens per tile entry
+    # dense: max tokens per tile entry (an overfull tile splits into
+    # several).  pallas: the same entries are cut into the kernel's
+    # 128-slot chunks and only the chunks that hold tokens are staged
+    # (ops/lda_kernel.stage_chunk_list), so the cap no longer sets what a
+    # sweep executes; it bounds the host's intermediate entry arrays
+    entry_cap: int = 2048
     chunk: int = 8192   # scatter/pushpull: tokens sampled per count-snapshot
     # pushpull: row-request slots per (worker, owner) pair and chunk.  The
     # default (= chunk) guarantees zero drops (a chunk can never request
@@ -118,7 +122,7 @@ class LDAConfig:
     # deduped stream reaches ZERO drops at cap = m/4 — 4× smaller
     # exchange buffers at equal fidelity.
     dedup_pulls: bool = True
-    # Tiled algos (dense/pallas): carry the doc-topic tile across its
+    # algo="dense": carry the doc-topic tile across its
     # od-run instead of slice+DUS per entry.  Entries are od-major
     # (partition_ratings_tiles sorts tiles u-major), so one od's ~25
     # entries at enwiki shapes (512 docs x 100 tok / 2048-token entries)
@@ -143,6 +147,10 @@ class LDAConfig:
     # raised and ``replace(..., algo='dense')`` silently enabled the
     # VETOED dense-carry arm; ADVICE r5).  An explicit True on a
     # non-tiled algo still raises.
+    # algo="pallas" (PR 32): the carry is the kernel's — one call a
+    # document-tile run keeps the run's doc tile in VMEM and XLA slices
+    # nothing — so there is no slice-per-entry arm to choose: None and
+    # True mean that carry, an explicit False raises.
     carry_db: bool | None = None
     # algo="pallas" only: exact base-256-plane count gathers (ADVICE r3 —
     # single-dot bf16 gathers round counts > 256, perturbing the posterior
@@ -224,6 +232,11 @@ class LDAConfig:
         if self.carry_db and self.algo not in _TILED_ALGOS:
             raise ValueError("carry_db applies to the tiled algos "
                              f"{_TILED_ALGOS}, not algo={self.algo!r}")
+        if self.carry_db is False and self.algo == "pallas":
+            raise ValueError(
+                "carry_db=False (slice the doc tile per entry) exists for "
+                "algo='dense' only: the fused kernel keeps a run's doc "
+                "tile in VMEM (a silently-ignored flag wastes sweeps)")
         if self.rotate_chunks is not None and self.rotate_chunks < 1:
             raise ValueError(
                 f"rotate_chunks must be >= 1, got {self.rotate_chunks}")
@@ -418,50 +431,53 @@ def _sample_entry(Ndk, Nwk, Nk, z, entry, key, cfg: LDAConfig, vocab_size):
     return Ndk, Nwk, dNk, z_new
 
 
-def _sample_tiles_pallas(DbT, WbT, nk, z, cd, cw, key2, cfg: LDAConfig,
-                         vocab_size, count_bounds=(None, None)):
-    """Tile-level core of :func:`_sample_entry_pallas` (topic-major
-    blocks in/out) — the fused-kernel twin of
-    :func:`_sample_entry_tiles`, shared by the carry and slice-per-entry
-    epoch paths."""
-    from harp_tpu.ops.lda_kernel import cgs_entry_update
+def _sample_runs_pallas(NdkT, NwkT, Nk, z, cd, cw, meta, key, cfg: LDAConfig,
+                        vocab_size, count_bounds=(None, None)):
+    """One rotation step of the fused kernel (ops/lda_kernel.py) on
+    TOPIC-MAJOR tables: the resident half-slice's chunk list ``z/cd/cw
+    [NCH, cc]`` + ``meta [NCH]`` is one slab of chunks a document-tile
+    run, and each run is ONE kernel call that switches its word tiles
+    itself and keeps its doc tile, the chain's deltas and ``N_k``'s in
+    VMEM.  Both tables go through the calls in place (aliased), so no
+    tile is sliced out or written back by XLA.  Chunk-granular snapshots
+    (fresher than the XLA entry snapshot); exprace draw over hardware
+    bits by construction."""
+    from harp_tpu.ops.lda_kernel import cgs_run_update
 
-    DbT, WbT, z_new, dNk = cgs_entry_update(
-        DbT, WbT, nk, z, cd, cw, key2,
-        alpha=cfg.alpha, beta=cfg.beta, vbeta=vocab_size * cfg.beta,
-        interpret=interpret_default(),
-        exact_gathers=cfg.pallas_exact_gathers,
-        ndk_count_bound=count_bounds[0], nwk_count_bound=count_bounds[1])
-    return DbT, WbT, dNk, z_new
+    R = NdkT.shape[1] // cfg.d_tile
+    run_keys = lax.bitcast_convert_type(jax.random.split(key, R), jnp.int32)
+
+    def per_run(a):
+        return a.reshape((R, a.shape[0] // R) + a.shape[1:])
+
+    def run_body(st, inp):
+        NdkT, NwkT, dNk_acc = st
+        r, zc, cdc, cwc, mc, k = inp
+        NdkT, NwkT, z_new, dNk = cgs_run_update(
+            NdkT, NwkT, Nk + dNk_acc, zc, cdc, cwc, mc, r, k,
+            alpha=cfg.alpha, beta=cfg.beta, vbeta=vocab_size * cfg.beta,
+            d_tile=cfg.d_tile, w_tile=cfg.w_tile,
+            interpret=interpret_default(),
+            exact_gathers=cfg.pallas_exact_gathers,
+            ndk_count_bound=count_bounds[0],
+            nwk_count_bound=count_bounds[1])
+        return (NdkT, NwkT, dNk_acc + dNk), z_new
+
+    (NdkT, NwkT, dNk), z_new = lax.scan(
+        run_body, (NdkT, NwkT, jnp.zeros_like(Nk)),
+        (jnp.arange(R, dtype=jnp.int32), per_run(z), per_run(cd),
+         per_run(cw), per_run(meta), run_keys))
+    return NdkT, NwkT, dNk, z_new.reshape(z.shape)
 
 
-def _sample_entry_pallas(NdkT, NwkT, nk, z, entry, key2, cfg: LDAConfig,
-                         vocab_size, count_bounds=(None, None)):
-    """Fused-kernel twin of :func:`_sample_entry` on TOPIC-MAJOR tables
-    (ops/lda_kernel.py): tiles slice along lanes, the whole [C, K] chain
-    stays in VMEM.  Chunk-granular snapshots (fresher than the XLA
-    entry snapshot); exprace draw over hardware bits by construction."""
-    cd, cw, od, ow = entry
-    DR, WR = cfg.d_tile, cfg.w_tile
-    DbT = lax.dynamic_slice_in_dim(NdkT, od, DR, 1)
-    WbT = lax.dynamic_slice_in_dim(NwkT, ow, WR, 1)
-    DbT, WbT, dNk, z_new = _sample_tiles_pallas(DbT, WbT, nk, z, cd, cw,
-                                                key2, cfg, vocab_size,
-                                                count_bounds)
-    NdkT = lax.dynamic_update_slice_in_dim(NdkT, DbT, od, 1)
-    NwkT = lax.dynamic_update_slice_in_dim(NwkT, WbT, ow, 1)
-    return NdkT, NwkT, dNk, z_new
-
-
-#: algos that consume the dense (d_tile × w_tile) entry layout
+#: algos on the (d_tile × w_tile) tile grid: dense stages its entries as
+#: they are, pallas as the list of the chunks that hold their tokens
 _TILED_ALGOS = ("dense", "pallas")
-
-#: pallas prep: entry width must be a multiple of the kernel chunk
-_PALLAS_C = 256
 
 #: benchmark pack-cache format version — bump when pack_tokens/partitioner
 #: layout changes so stale cached packs can never be installed
-_PACK_VERSION = 1
+#: (2: algo="pallas" stages a chunk list, PR 32)
+_PACK_VERSION = 2
 
 
 def _epoch_device_fn(mesh: WorkerMesh, cfg: LDAConfig, vocab_size: int,
@@ -474,9 +490,11 @@ def _epoch_device_fn(mesh: WorkerMesh, cfg: LDAConfig, vocab_size: int,
     chunk while the previously-sampled one is in flight
     (:func:`rotate_pipeline`; the 2-chunk default is the former bespoke
     half-slice schedule, and ``cfg.rotate_wire`` narrows the ring
-    payload).  The per-step token pass dispatches on ``cfg.algo``: scan
-    over dense tile entries, or over fixed-size scatter chunks (see
-    :func:`_sample_entry` / :func:`_sample_chunk`).
+    payload).  The per-step token pass dispatches on ``cfg.algo``: one
+    fused-kernel call a document-tile run, a scan over dense tile
+    entries, or one over fixed-size scatter chunks (see
+    :func:`_sample_runs_pallas` / :func:`_sample_entry` /
+    :func:`_sample_chunk`).
     """
     nc = rotate_chunks_resolved(cfg)
     tiled = cfg.algo in _TILED_ALGOS
@@ -508,12 +526,14 @@ def _epoch_device_fn(mesh: WorkerMesh, cfg: LDAConfig, vocab_size: int,
             z_blk = z_grid[chunk_idx]
             key, sub = jax.random.split(key)
 
-            if tiled:
+            if pallas:
+                cd, cw, meta = blk  # [NCH, cc], [NCH]
+                Ndk, computing, dNk, z_new = _sample_runs_pallas(
+                    Ndk, computing, Nk, z_blk, cd, cw, meta, sub, cfg,
+                    vocab_size, count_bounds)
+            elif tiled:
                 ed, ew, od, ow = blk  # [NE, C], [NE]
                 entry_keys = jax.random.split(sub, ed.shape[0])
-                if pallas:
-                    entry_keys = lax.bitcast_convert_type(
-                        entry_keys, jnp.int32)
 
                 if carry_db:
                     # Carry the doc tile across its od-run (entries are
@@ -522,31 +542,27 @@ def _epoch_device_fn(mesh: WorkerMesh, cfg: LDAConfig, vocab_size: int,
                     # switch always flushes the old region before any
                     # region can be re-sliced, so this is exact under any
                     # entry order — pad entries jumping back to od 0
-                    # included.  Same tile cores as the non-carry path:
+                    # included.  Same tile core as the non-carry path:
                     # chains are bit-identical (tested).
-                    ax = 1 if pallas else 0
                     DR = cfg.d_tile
-                    core = (functools.partial(_sample_tiles_pallas,
-                                              count_bounds=count_bounds)
-                            if pallas else _sample_entry_tiles)
 
                     def entry_body(st, inp):
                         Ndk, Nwk, dNk_acc, db, cur_od = st
                         cd, cw, zc, eo, wo, k = inp
 
                         Ndk, db, cur_od = carry_tile_switch(
-                            Ndk, db, cur_od, eo, DR, ax)
+                            Ndk, db, cur_od, eo, DR, 0)
                         Wb = lax.dynamic_slice_in_dim(
-                            Nwk, wo, cfg.w_tile, ax)
-                        db, Wb, dNk, z_new = core(
+                            Nwk, wo, cfg.w_tile, 0)
+                        db, Wb, dNk, z_new = _sample_entry_tiles(
                             db, Wb, Nk + dNk_acc, zc, cd, cw, k,
                             cfg, vocab_size)
                         Nwk = lax.dynamic_update_slice_in_dim(
-                            Nwk, Wb, wo, ax)
+                            Nwk, Wb, wo, 0)
                         return (Ndk, Nwk, dNk_acc + dNk, db, cur_od), z_new
 
                     od0 = od[0]
-                    db0 = lax.dynamic_slice_in_dim(Ndk, od0, DR, ax)
+                    db0 = lax.dynamic_slice_in_dim(Ndk, od0, DR, 0)
                     (Ndk, computing, dNk, db_f, od_f), z_new = lax.scan(
                         entry_body,
                         (Ndk, computing, jnp.zeros_like(Nk), db0, od0),
@@ -554,16 +570,12 @@ def _epoch_device_fn(mesh: WorkerMesh, cfg: LDAConfig, vocab_size: int,
                     )
                     # final flush: the last run's tile is still in carry
                     Ndk = lax.dynamic_update_slice_in_dim(
-                        Ndk, db_f, od_f, ax)
+                        Ndk, db_f, od_f, 0)
                 else:
-                    sample = (functools.partial(_sample_entry_pallas,
-                                                count_bounds=count_bounds)
-                              if pallas else _sample_entry)
-
                     def entry_body(st, inp):
                         Ndk, Nwk, dNk_acc = st
                         cd, cw, zc, eo, wo, k = inp
-                        Ndk, Nwk, dNk, z_new = sample(
+                        Ndk, Nwk, dNk, z_new = _sample_entry(
                             Ndk, Nwk, Nk + dNk_acc, zc, (cd, cw, eo, wo),
                             k, cfg, vocab_size)
                         return (Ndk, Nwk, dNk_acc + dNk), z_new
@@ -662,7 +674,8 @@ _STATE_ARGS = (0, 1, 2, 3)
 
 
 def _n_token_args(cfg: LDAConfig) -> int:
-    return 5 if cfg.algo in _TILED_ALGOS else 4  # (+ keys)
+    # dense: ed/ew/od/ow; pallas: cd/cw/meta; scatter, pushpull: d/w/m
+    return 5 if cfg.algo == "dense" else 4  # (+ keys)
 
 
 def _epoch_out_specs(mesh, cfg):
@@ -832,13 +845,19 @@ def epoch_arg_shapes(n_workers, n_docs, vocab_size, cfg: LDAConfig,
       ``ceil(tokens_per_grid_row / C)`` — tight packing.  That default
       is a LOWER BOUND, exact only where every occupied tile fills its
       entries: an entry holds ONE (d_tile x w_tile) tile's tokens, so a
-      corpus stages at least one entry an occupied tile.  At the source's
-      1M-word vocabulary (1,954 word tiles) a 512-document tile row holds
-      ~76 tokens a tile and stages 977-989 entries a half-slice: 6,656
-      documents stage NE = 12,859 where tight packing says 472 (27x;
-      30-80x at 100-290 tokens a document, CPU counts, PRs 27 and 29),
-      and the token arrays are that much larger.  Pass the real
-      partitioner's NE/C to model a specific corpus
+      corpus stages at least one entry an occupied tile.
+    - pallas: a grid row is one slab of chunks a document-tile run
+      (``d_bound / d_tile`` runs, ops/lda_kernel.stage_chunk_list), the
+      chunk ``lda_kernel.CHUNK`` slots wide (``entry_width`` is not a
+      parameter there and raises); ``entries_per_row`` counts the CHUNKS
+      a row, a multiple of the runs, and defaults to tight packing —
+      again a LOWER BOUND: a run stages at least one chunk an occupied
+      tile, and every run is as long as the longest.  At the source's
+      1M-word vocabulary (1,954 word tiles, ~76 tokens a tile) 6,656
+      documents stage 13 runs of 1,381 chunks = 17,953 a half-slice
+      where tight packing says 13 x 581 = 7,553 (2.4x, 58% of the slots
+      padding; the fixed-width entries before PR 32 staged 27x, 96%).
+      Pass the real partitioner's count to model a specific corpus
       (tests/test_lda_scale.py does for a Zipf one).
     """
     n, K = n_workers, cfg.n_topics
@@ -859,16 +878,27 @@ def epoch_arg_shapes(n_workers, n_docs, vocab_size, cfg: LDAConfig,
     if cfg.algo in _TILED_ALGOS:
         d_own, w_own, d_bound, ib2 = _dense_bounds(
             n_docs, vocab_size, n, ns, cfg.d_tile, cfg.w_tile)
-        C = entry_width or cfg.entry_cap
-        # NE comes from the REAL entry capacity — pallas C-padding adds
-        # masked slots, not token capacity (set_tokens pads after packing)
-        NE = entries_per_row or max(1, _ceil_div(_ceil_div(n_tokens, n * ns),
-                                                 C))
+        tables = [((d_bound * n, K), ndk_dt), ((2 * ib2 * n, K), f32), nk]
+        row_tokens = _ceil_div(n_tokens, n * ns)
         if cfg.algo == "pallas":
-            C = _PALLAS_C * _ceil_div(C, _PALLAS_C)
+            from harp_tpu.ops.lda_kernel import CHUNK
+
+            if entry_width is not None:
+                raise ValueError("algo='pallas' stages lda_kernel.CHUNK-"
+                                 "slot chunks: entry_width is not a "
+                                 "parameter")
+            runs = d_bound // cfg.d_tile
+            NCH = entries_per_row or runs * max(
+                1, _ceil_div(_ceil_div(row_tokens, runs), CHUNK))
+            if NCH % runs:
+                raise ValueError(f"{NCH} chunks a row do not split into "
+                                 f"{runs} document-tile runs")
+            cc = ((n * ns, NCH, CHUNK), i32)
+            return tables + [cc, cc, cc, ((n * ns, NCH), i32), keys]
+        C = entry_width or cfg.entry_cap
+        NE = entries_per_row or max(1, _ceil_div(row_tokens, C))
         ec, eo = ((n * ns, NE, C), i32), ((n * ns, NE), i32)
-        return [((d_bound * n, K), ndk_dt), ((2 * ib2 * n, K), f32), nk,
-                ec, ec, ec, eo, eo, keys]
+        return tables + [ec, ec, ec, eo, eo, keys]
     # scatter: mirrors partition_ratings' B rule
     d_bound = _ceil_div(n_docs, n)
     wb2 = _ceil_div(vocab_size, ns)
@@ -1024,17 +1054,17 @@ class LDA:
             assert (do, wo, db, nc * wbc) == (
                 self.d_own, self.w_own, self.d_bound, self.w_bound)
             if self.cfg.algo == "pallas":
-                # kernel chunks C in _PALLAS_C slices: pad entry width up
-                # (pad slots: d id = tile width -> masked out in-kernel)
-                Cw = ed.shape[-1]
-                Cp = _PALLAS_C * _ceil_div(Cw, _PALLAS_C)
-                if Cp != Cw:
-                    pad = ((0, 0), (0, 0), (0, Cp - Cw))
-                    ed = np.pad(ed, pad, constant_values=self.cfg.d_tile)
-                    ew = np.pad(ew, pad, constant_values=self.cfg.w_tile)
-                    ez = np.pad(ez, pad, constant_values=0.0)
-            z_grid = ez.astype(np.int32)
-            tokens = (ed, ew, od, ow)
+                # the kernel's layout: the same entries, same order, as
+                # the list of the chunks that hold their tokens
+                from harp_tpu.ops.lda_kernel import stage_chunk_list
+
+                cd, cw, z_grid, meta = stage_chunk_list(
+                    ed, ew, ez, od, ow, db // self.cfg.d_tile,
+                    self.cfg.d_tile, self.cfg.w_tile)
+                tokens = (cd, cw, meta)
+            else:
+                z_grid = ez.astype(np.int32)
+                tokens = (ed, ew, od, ow)
         elif self.cfg.algo == "pushpull":
             pd, pw, pz, pm, db = partition_tokens_by_doc(
                 doc_ids, word_ids, z0, self.n_docs, n, self.cfg.chunk)
@@ -1081,13 +1111,25 @@ class LDA:
                                   padded_total=gm.size)
             # the record that counts what runs (the twin of
             # ``mfsgd.kernel_slots``): the same tokens over the slots of
-            # the arrays AS STAGED — for the tiled algos NE x C a grid
-            # row, every one of which a sweep executes.  Through the
-            # ledger, not the module hook: the health monitor has judged
-            # this per-worker work once already, under "lda.partition"
+            # the arrays AS STAGED — dense: NE x C a grid row, every one
+            # of which a sweep executes; pallas: the chunk list, a run's
+            # no-op chunks counted (a step each, its body skipped).
+            # Through the ledger, not the module hook: the health monitor
+            # has judged this per-worker work once already, under
+            # "lda.partition"
+            staged = pack["tokens"][0]
             skew.ledger.record_partition(
                 "lda.kernel_slots", per, unit="tokens",
-                padded_total=pack["tokens"][0].size)
+                padded_total=staged.size)
+            if self.cfg.algo == "pallas":
+                # what splits that padding: the chunks that hold tokens
+                # over the chunks staged.  The rest are the no-ops that
+                # end the shorter runs; the padding left after them is
+                # inside the chunks that run
+                held = gm.reshape(staged.shape).any(-1)
+                skew.ledger.record_partition(
+                    "lda.kernel_chunks", held.reshape(n, -1).sum(1),
+                    unit="chunks", padded_total=held.size)
         placed = (pack["Ndk"], pack["Nwk"], pack["z_grid"], *pack["tokens"])
         with telemetry.span("lda.install",
                             bytes=sum(a.nbytes for a in placed)):
@@ -1122,7 +1164,19 @@ class LDA:
         db, wbc = self.d_bound, self.w_bound // rotate_chunks_resolved(self.cfg)
         rows = np.arange(n * ns)
         if self.cfg.algo in _TILED_ALGOS:
-            ed, ew, od, ow = (np.asarray(a) for a in tokens)
+            if self.cfg.algo == "pallas":
+                # a chunk's tile offsets: its run's doc tile (one slab of
+                # chunks a run) and the word tile its metadata names
+                from harp_tpu.ops.lda_kernel import unpack_chunk_meta
+
+                ed, ew, meta = (np.asarray(a) for a in tokens)
+                nchr = ed.shape[1] // (db // self.cfg.d_tile)
+                od = np.broadcast_to(
+                    np.arange(ed.shape[1]) // nchr * self.cfg.d_tile,
+                    meta.shape)
+                ow = unpack_chunk_meta(meta)[0] * self.cfg.w_tile
+            else:
+                ed, ew, od, ow = (np.asarray(a) for a in tokens)
             gm = (ed < self.cfg.d_tile).reshape(-1)
             ld = np.minimum(ed, self.cfg.d_tile - 1) + od[:, :, None]
             lw = np.minimum(ew, self.cfg.w_tile - 1) + ow[:, :, None]
@@ -1355,9 +1409,10 @@ def _make_cfg(n_topics, algo="dense", chunk=None, d_tile=None, w_tile=None,
     # the user-facing LDAConfig default flipped ON (2026-08-01) — else
     # the flip would silently turn `lda`/`lda_pallas` sweep rows into
     # carry rows and the A/B would compare a config against itself
-    # (owning algos only — a pinned False would trip algo_kwargs's
-    # non-owning-knob check for scatter/pushpull)
-    if carry_db is None and algo in _TILED_ALGOS:
+    # (dense only: scatter/pushpull do not own the knob, and under
+    # algo="pallas" the carry is the kernel's since PR 32, so `lda_pallas`
+    # and `lda_pallas_carry` are one program and False would raise)
+    if carry_db is None and algo == "dense":
         carry_db = False
     return LDAConfig(n_topics=n_topics, ndk_dtype=ndk_dtype, sampler=sampler,
                      rng_impl=rng_impl,
@@ -1437,7 +1492,7 @@ def _pack_cache_path(pack_cache, cfg: LDAConfig, num_workers, n_docs,
                      vocab_size, n_topics, tokens_per_doc, seed) -> str:
     """Cache path for a :func:`benchmark` corpus pack — layout-relevant
     knobs ONLY, keyed by the EXACT algo: dense/pallas pack differently
-    (pallas pads C to _PALLAS_C), and scatter vs pushpull use different
+    (pallas stages a chunk list), and scatter vs pushpull use different
     partitioners entirely (partition_ratings grid vs
     partition_tokens_by_doc), so they must never share a pack.  Shared
     with scripts/prewarm_bench_cache.py so an offline prewarm writes the

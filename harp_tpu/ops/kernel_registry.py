@@ -117,15 +117,17 @@ def _kmeans_int8():
                 jnp.ones(256, jnp.float32))
 
 
-# lda.cgs_entry_update at (K=64, DR=WR=128, C=256): per token ~14K flops
-# (posterior + draw + delta matmuls) over C tokens; min bytes = both
-# table tiles in/out + token streams; vmem = the kernel's own est(cc)
-# budget model (lda_kernel.py) at cc=C=256 with exact-gather planes.
+# lda.cgs_entry_update at (K=64, DR=WR=128, C=256): the one-entry face of
+# cgs_run_update (one run, one word tile, 256-slot chunks).  Per token
+# ~14K flops (posterior + draw + delta matmuls) over C tokens; min bytes
+# = both table tiles in/out + token streams; vmem = the kernel's own
+# lda_kernel.vmem_bytes(64, 128, 128, 256, 4, 2, 3): both tiles in and
+# out, double-buffered, + live [K, cc] temporaries + gather planes.
 @register_kernel("lda.cgs_entry_update",
                  flops=14 * 64 * 256,
                  min_hbm_bytes=(2 * 4 * (64 * 128 + 64 * 128) + 4 * 64
                                 + 3 * 4 * 256),
-                 vmem_bytes=(4 + 4) * 64 * 128 + 8 * 64 * 128
+                 vmem_bytes=4 * 4 * 64 * 128 + 4 * 4 * 64 * 128
                  + 6 * 4 * 64 * 256 + 6 * 64 * 128)
 def _lda_cgs():
     import functools

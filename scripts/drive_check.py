@@ -438,9 +438,10 @@ print("DRIVE OK round-15")
 
 # 21. round 4 (this session): carry_db through the public LDA driver —
 # the od-run-carried doc tile must be BIT-identical to the
-# slice-per-entry chain on both tiled algos; the exact-gather kernel
-# default keeps integer tables; and the flip gate refuses a degraded
-# candidate.
+# slice-per-entry chain on the dense algo; under pallas the carry is the
+# kernel's (PR 32): None and True are one chain and False raises; the
+# exact-gather kernel default keeps integer tables; and the flip gate
+# refuses a degraded candidate.
 from harp_tpu.models.lda import LDA as _R4L
 from harp_tpu.models.lda import LDAConfig as _R4C
 from harp_tpu.models.lda import synthetic_corpus as _r4corpus
@@ -451,7 +452,8 @@ for _r4algo in ("dense", "pallas"):
     _r4extra = ({"sampler": "exprace", "rng_impl": "rbg"}
                 if _r4algo == "pallas" else {})
     _r4chains = {}
-    for _r4carry in (False, True):
+    _r4base = None if _r4algo == "pallas" else False
+    for _r4carry in (_r4base, True):
         _r4m = _R4L(48, 24, _R4C(n_topics=4, algo=_r4algo, d_tile=8,
                                  w_tile=8, entry_cap=32,
                                  carry_db=_r4carry, **_r4extra),
@@ -461,9 +463,15 @@ for _r4algo in ("dense", "pallas"):
             _r4m.sample_epoch()
         _r4chains[_r4carry] = (np.asarray(_r4m.Ndk), np.asarray(_r4m.Nwk),
                                np.asarray(_r4m.z_grid))
-    for _a, _b in zip(_r4chains[False], _r4chains[True]):
+    for _a, _b in zip(_r4chains[_r4base], _r4chains[True]):
         np.testing.assert_array_equal(_a, _b)
-    print(f"carry_db ≡ slice-per-entry ({_r4algo}, bit-identical)")
+    print(f"carry_db ≡ {_r4base} ({_r4algo}, bit-identical)")
+try:
+    _R4C(n_topics=4, algo="pallas", sampler="exprace", rng_impl="rbg",
+         carry_db=False)
+    raise AssertionError("pallas carry_db=False must raise")
+except ValueError:
+    print("pallas carry_db=False refused (the carry is the kernel's)")
 
 # exact plane gathers: a pallas chain at hot counts (tiny vocab) keeps
 # integer tables and tracks dense likelihood
@@ -1000,7 +1008,8 @@ with _SKT.scope(True):
     # (b) export -> checker invariant 5: real rows clean, forged row loud
     with _sk_tmp.NamedTemporaryFile("r+", suffix=".jsonl") as _sk_fh:
         _SKT.export(_sk_fh.name)
-        assert len(_SKT.load_rows(_sk_fh.name)["skew"]) == 2
+        # lda.partition, lda.kernel_slots (since PR 29), lda.epochs
+        assert len(_SKT.load_rows(_sk_fh.name)["skew"]) == 3
         assert _fr_cj.check_file(_sk_fh.name) == []
         _sk_fh.seek(0, 2)
         _sk_fh.write(_fr_json.dumps(

@@ -341,7 +341,9 @@ def run_all(smoke: bool, only, watchdog=None, skip=None):
                 "pack_cache": BENCH_DATA})),
         # round 4: fused kernel + carried doc tile — the two HBM levers
         # stacked (entry VMEM-residency from the kernel, od-run tile
-        # amortization from the carry)
+        # amortization from the carry).  Since PR 32 the carry is the
+        # kernel's own (one call a document-tile run): this and
+        # `lda_pallas` run one program, the names stay for the gates
         "lda_pallas_carry": lambda: lda.benchmark(
             algo="pallas", carry_db=True,
             **(SMOKE["lda_pallas"] if smoke else

@@ -27,8 +27,11 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 def _compile_all() -> dict:
     """Every check, in the child: {check name: {program: mosaic calls}}."""
+    import functools
+
     import jax
     import jax.numpy as jnp
+    import numpy as np
     from jax.experimental import topologies
 
     import chip_smoke
@@ -115,10 +118,12 @@ def _compile_all() -> dict:
 
     # the benchmark's cell lda-sweeps (perf/configs/lda-enwiki-v1m-k1k):
     # one chip, 1k topics over a 1M-word vocabulary, 6,656 documents, one
-    # sweep a program, the entries every seed stages (12,859 a half-slice)
-    # and count bounds above its corpus' (a 6,411-token document, a word
-    # of 204,574 tokens: 2 and 3 gather planes).  What the chip must
-    # hold: the arguments and the program's temporaries, the state donated.
+    # sweep a program, the chunk list every seed stages (13 runs of 1,381
+    # chunks of 128 slots a half-slice) and count bounds above its
+    # corpus' (a 6,411-token document, a word of 204,574 tokens: 2 and 3
+    # gather planes).  What the chip must hold: the arguments and the
+    # program's temporaries, the state donated, both tables going through
+    # the kernel in place.
     from harp_tpu.models import lda
 
     cfg = lda.LDAConfig(n_topics=1000)
@@ -128,17 +133,42 @@ def _compile_all() -> dict:
             shape, dt, sharding=(mesh.replicated() if i == 2 else
                                  mesh.sharding(mesh.spec(0, ndim=len(shape)))))
         for i, (shape, dt) in enumerate(lda.epoch_arg_shapes(
-            1, 6656, 1_000_000, cfg, entries_per_row=12_859,
-            entry_width=2048))]).compile()
+            1, 6656, 1_000_000, cfg, entries_per_row=13 * 1381))]).compile()
     mem = compiled.memory_analysis()
+    table = lda.epoch_arg_shapes(1, 6656, 1_000_000, cfg)[1]
     out["lda_cell"] = {
         "mosaic_calls": compiled.as_text().count(chip_smoke.MOSAIC_CALL),
-        "aliased_gb": round(mem.alias_size_in_bytes / 1e9, 2),
+        "table_bytes": int(np.prod(table[0])) * table[1].itemsize,
+        "aliased_bytes": mem.alias_size_in_bytes,
         "held_gb": round((mem.argument_size_in_bytes
                           + mem.temp_size_in_bytes) / 1e9, 1)}
 
-    # every builder in the registry through the real Mosaic compiler
+    # the kernel call that program makes 26 times a sweep, by itself: one
+    # document-tile run (1,381 chunks, their metadata prefetched into
+    # SMEM) against the whole doc table and one half-slice of the
+    # word-topic table, both aliased; two K = 1000 count tiles in and
+    # out, double-buffered, under the raised VMEM limit
+    from harp_tpu.ops import lda_kernel
+
+    i32, f32 = jnp.int32, jnp.float32
     one = jax.sharding.SingleDeviceSharding(devices[0])
+    run = jax.jit(functools.partial(
+        lda_kernel.cgs_run_update, alpha=0.1, beta=0.01, vbeta=1e4,
+        d_tile=512, w_tile=512, ndk_count_bound=7000,
+        nwk_count_bound=210_000), donate_argnums=(0, 1)).lower(*[
+            jax.ShapeDtypeStruct(shape, dt, sharding=one)
+            for shape, dt in (
+                ((1000, 6656), f32), ((1000, table[0][0] // 2), f32),
+                ((1000,), f32), *[((1381, lda_kernel.CHUNK), i32)] * 3,
+                ((1381,), i32), ((), i32), ((2,), i32))]).compile()
+    out["lda_run"] = {
+        "mosaic_calls": run.as_text().count(chip_smoke.MOSAIC_CALL),
+        "vmem_estimate_mb": round(lda_kernel.vmem_bytes(
+            1000, 512, 512, lda_kernel.CHUNK, 4, 2, 3) / 2 ** 20, 1),
+        "temp_mb": round(run.memory_analysis().temp_size_in_bytes
+                         / 2 ** 20, 1)}
+
+    # every builder in the registry through the real Mosaic compiler
     out["registry"] = {
         name: mosaic_calls(jax.jit(fn), [
             jax.ShapeDtypeStruct(jnp.shape(a), jnp.result_type(a),
@@ -182,14 +212,35 @@ def test_mfsgd_cell_epochs_compile_for_v5e(compiled):
 
 def test_lda_cell_sweep_compiles_for_v5e_and_fits(compiled):
     """The 4 GB word-topic table at the benchmark cell's real size: the
-    state is donated (the output takes the table's place), and arguments
-    plus temporaries stay under the chip's 16 GB: 14.8 GB.  Without the
-    donation the same program asks for 18.7 GB and the chip refuses it
-    at its first block (my chip runs, PRs 27 and 29)."""
+    state is donated (the output takes the table's place, so the aliased
+    bytes are the table's and the little beside it: the doc-topic table,
+    the chain), and arguments plus temporaries stay under the chip's
+    16 GB: 14.2 GB as this client counts them (14.8 GB with the
+    fixed-width entries before PR 32, whose chain and token arrays held
+    52.7M slots where the chunk list holds 4.6M).  Without the donation
+    the program asked for 18.7 GB and the chip refused it at its first
+    block (my chip runs, PRs 27 and 29)."""
     cell = compiled["lda_cell"]
     assert cell["mosaic_calls"] == 1
-    assert cell["aliased_gb"] >= 4.1  # the word-topic table, at least
-    assert cell["held_gb"] < 15.5
+    assert cell["table_bytes"] == 2 * 500_224 * 1000 * 4
+    assert cell["table_bytes"] <= cell["aliased_bytes"] \
+        < 1.02 * cell["table_bytes"]
+    assert cell["held_gb"] < 15.0
+
+
+def test_lda_run_kernel_compiles_for_v5e(compiled):
+    """``cgs_run_update`` by itself at the cell's shapes: 1,381 chunks of
+    128 slots a document-tile run, K = 1000, 512-wide tiles, 2 and 3
+    gather planes.  Its VMEM estimate passes the default 16 MiB scoped
+    limit (which is why the call raises it) and stays under the budget
+    the kernel holds itself to; both tables go through in place, so the
+    call holds no second table."""
+    from harp_tpu.ops import lda_kernel
+
+    run = compiled["lda_run"]
+    assert run["mosaic_calls"] == 1
+    assert 16 < run["vmem_estimate_mb"] < lda_kernel._VMEM_BUDGET / 2 ** 20
+    assert run["temp_mb"] < 64  # no copy of a table beside the aliased one
 
 
 def test_registered_kernels_compile_for_v5e(compiled):

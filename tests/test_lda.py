@@ -317,20 +317,29 @@ def test_int16_ndk_bit_identical_to_f32(mesh, algo):
 
 @pytest.mark.parametrize("algo", ["dense", "pallas"])
 def test_carry_db_bit_identical_chain(mesh, algo):
-    """carry_db=True (VERDICT r3 item 2's Db-carry) shares the tile cores
-    with the slice-per-entry path, so the sampled chain — same corpus,
-    same seed — must be BIT-identical: same z trajectory, same tables.
-    The corpus has more docs than one d_tile so real od changes exercise
-    the flush/load cond, and pad entries jump od back to 0 (the re-slice
-    case the switch-ordering argument covers)."""
+    """dense: carry_db=True (VERDICT r3 item 2's Db-carry) shares the
+    tile core with the slice-per-entry path, so the sampled chain — same
+    corpus, same seed — must be BIT-identical: same z trajectory, same
+    tables.  The corpus has more docs than one d_tile so real od changes
+    exercise the flush/load cond, and pad entries jump od back to 0 (the
+    re-slice case the switch-ordering argument covers).
+
+    pallas: the carry is the kernel's (one call a document-tile run keeps
+    the doc tile in VMEM), there is no slice-per-entry arm to compare
+    with, and asking for one raises; None and True are one program.  The
+    bit-identity this case held is
+    tests/test_lda_kernel.py::test_chunk_list_chain_equals_the_padded_entry_chain."""
     extra = ({"sampler": "exprace", "rng_impl": "rbg"}
              if algo == "pallas" else {})
     d, w = L.synthetic_corpus(n_docs=96, vocab_size=48, n_topics_true=4,
                               tokens_per_doc=30, seed=6)
     kw = dict(n_topics=8, algo=algo, d_tile=16, w_tile=16, entry_cap=64,
               **extra)
+    if algo == "pallas":
+        with pytest.raises(ValueError, match="carry_db=False"):
+            L.LDAConfig(carry_db=False, **kw)
     models = []
-    for carry in (False, True):
+    for carry in ((None, True) if algo == "pallas" else (False, True)):
         m = L.LDA(96, 48, L.LDAConfig(carry_db=carry, **kw), mesh, seed=5)
         m.set_tokens(d, w)
         m.sample_epochs(3)

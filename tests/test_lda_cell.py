@@ -1,10 +1,17 @@
 """The benchmark cell ``lda-sweeps`` rehearsed end to end on the CPU at
-a cut-down data block, untraced and traced, as
-``perf/tests/test_harness.py`` rehearses the accepted cells (that file
-hands KMeans' toy override to every cell not named ``mfsgd*``, so this
-cell's override lives here); its six per-layer readers on runs that
-lack what they read, its entries in ``BENCHMARK.json``, its knobs against
-the program's defaults, its work model.  No number printed here is a
+a cut-down data block, untraced and traced, through the default path of
+the program: the fused sampler over its chunk list
+(``ops/lda_kernel.stage_chunk_list``), in interpret mode.  The override
+is ``perf/tests/test_harness.py``'s own ``TINY["lda"]`` (since PR 31 that
+file holds one toy override a driver and rehearses this cell too; here
+the cell's checks are read more closely).  ``correct`` is decided as the
+configuration's ``reference`` says: (a) and (b) on the state the window
+left, (c) on the chain as it stood after ``chain_sweeps`` = 4 sweeps, a
+window that ended sooner at its last, inside 0.6 of the plain sampler's
+step there; ``perf/tests/test_lda_check.py`` plants the faults.  Beside
+the rehearsals: the cell's six per-layer readers on runs that lack what
+they read, its entries in ``BENCHMARK.json``, its knobs against the
+program's defaults, its work model.  No number printed here is a
 speed."""
 
 import json
@@ -31,11 +38,8 @@ SHARED = ["compiles_in_window", "dispatches_per_block", "collective_share",
           "collective_bytes_per_item", "xla_share", "step_roofline",
           "kernel_share"]
 # the shape only: 20,000 tokens over 2 x 8 word tiles and 2 document
-# tiles; the bands at this size are read in tests/test_lda_reference.py
-TINY = {"data": {"n_docs": 200, "n_tokens": 20_000, "vocab_size": 2000,
-                 "n_topics": 16},
-        "knobs": {"d_tile": 128, "w_tile": 128, "entry_cap": 256},
-        "traffic": {"steps": 1, "trace_seconds": 0.05}}
+# tiles; the bands at this size are read in perf/tests/test_lda_check.py
+TINY = _H.TINY["lda"]
 
 
 def _run(root, trace, lines=None):
@@ -129,8 +133,13 @@ def test_cell_rehearses_traced(checkout):  # noqa: F811
     got = out["metrics"]
     assert got["compiles_in_window"]["value"] == 0
     assert got["dispatches_per_block"]["value"] == 2.0
-    # a count: these toy tiles are mostly full
-    assert 0.0 < got["lda_executed_pad_share"]["value"] < 50.0
+    # a count, of the chunk list as staged: 20,000 tokens in 2 rows of
+    # 2 runs of 128-slot chunks, the no-ops that end the shorter run
+    # counted (a toy tile holds 600 tokens: mostly whole chunks)
+    share = got["lda_executed_pad_share"]["value"]
+    chunks = 20_000 / (1 - share / 100) / 128
+    assert 0.0 < share < 50.0 and chunks == pytest.approx(round(chunks))
+    assert round(chunks) % 4 == 0
     # the program's spans were read; a CPU's seconds are not printed
     for name in ("lda_pack_s", "lda_partition_sort_s",
                  "lda_partition_pack_s"):

@@ -384,3 +384,368 @@ def test_kernel_lowers_for_tpu(ndk_dtype, shape, bounds):
         jnp.zeros(C, jnp.int32),
         jnp.zeros(2, jnp.int32)).lower(lowering_platforms=("tpu",))
     assert "tpu_custom_call" in lowered.as_text()
+
+
+# ---------------------------------------------------------------------------
+# The chunk list (PR 32): what the host step stages, and that the kernel
+# run over it samples the chain the fully padded entry list samples.
+# ---------------------------------------------------------------------------
+
+def _entries(doc, word, n_docs, vocab, d_tile, w_tile, entry_cap, n_workers=1,
+             seed=0):
+    """``partition_ratings_tiles``' entries for a corpus, topics drawn
+    from ``seed`` riding as the values, and the worker's runs a row."""
+    from harp_tpu.models.mfsgd import partition_ratings_tiles
+
+    z0 = np.random.default_rng(seed).integers(0, 8, len(doc)).astype(
+        np.float32)
+    ed, ew, ez, od, ow, _, _, d_bound, _ = partition_ratings_tiles(
+        doc, word, z0, n_docs, vocab, n_workers, d_tile, w_tile, entry_cap,
+        n_slices=2 * n_workers)
+    return (ed, ew, ez, od, ow), d_bound // d_tile
+
+
+def _loop_chunk_list(entries, n_runs, d_tile, w_tile, cc, keep_padding):
+    """The layout by plain loops, the reference ``stage_chunk_list`` is
+    held to: an entry's chunks that hold tokens — or, ``keep_padding``,
+    every chunk of the entry's full width, the all-padding ones run as
+    real chunks: the fully padded entry list.  Returns the four arrays
+    and, a row, the ``(run, entry, chunk of the entry)`` of every staged
+    chunk that is not a tail no-op."""
+    from harp_tpu.ops.lda_kernel import pack_chunk_meta
+
+    ed, ew, ez, od, ow = entries
+    ws, _, c = ed.shape
+    full = -(-c // cc)
+    per_row = []
+    for w in range(ws):
+        runs = [[] for _ in range(n_runs)]
+        for e in range(ed.shape[1]):
+            count = int((ed[w, e] < d_tile).sum())
+            if count:
+                k = full if keep_padding else -(-count // cc)
+                runs[od[w, e] // d_tile] += [(e, j) for j in range(k)]
+        per_row.append(runs)
+    nchr = max(1, max(len(r) for runs in per_row for r in runs))
+    shape = (ws, n_runs * nchr, cc)
+    cd = np.full(shape, d_tile, np.int32)
+    cw = np.full(shape, w_tile, np.int32)
+    z = np.zeros(shape, np.int32)
+    meta = np.zeros(shape[:2], np.int32)
+    where = []
+    for w, runs in enumerate(per_row):
+        src = {}
+        for r, chunks in enumerate(runs):
+            wt = 0
+            for i in range(nchr):
+                pos = r * nchr + i
+                if i < len(chunks):
+                    e, j = chunks[i]
+                    sl = slice(j * cc, min((j + 1) * cc, c))
+                    n = sl.stop - sl.start
+                    cd[w, pos, :n] = ed[w, e, sl]
+                    cw[w, pos, :n] = ew[w, e, sl]
+                    z[w, pos, :n] = ez[w, e, sl]
+                    wt = ow[w, e] // w_tile
+                    src[pos] = (r, e, j)
+                meta[w, pos] = pack_chunk_meta(wt, i >= len(chunks))
+        where.append(src)
+    return (cd, cw, z, meta), where
+
+
+def _check_chunk_list(entries, staged, n_runs, d_tile, w_tile):
+    """The host step's contract, whatever the case."""
+    from harp_tpu.ops.lda_kernel import pack_chunk_meta, unpack_chunk_meta
+
+    ed, ew, ez, od, ow = entries
+    cd, cw, z, meta = staged
+    ws, nch, cc = cd.shape
+    assert cw.shape == z.shape == cd.shape and meta.shape == (ws, nch)
+    assert z.dtype == np.int32 and meta.dtype == np.int32
+    assert nch % n_runs == 0
+    nchr = nch // n_runs
+    wt, noop = unpack_chunk_meta(meta)
+    # the metadata round-trips
+    np.testing.assert_array_equal(pack_chunk_meta(wt, noop), meta)
+    for w in range(ws):
+        # each token once and in the parent's order: the valid slots of
+        # the entries, entry by entry, are the valid slots of the chunks,
+        # chunk by chunk, as (doc row, word row, topic)
+        ve, vc = ed[w] < d_tile, cd[w] < d_tile
+        run_of = np.arange(nch) // nchr
+        want = np.stack([(ed[w] + od[w][:, None])[ve],
+                         (ew[w] + ow[w][:, None])[ve], ez[w][ve]])
+        got = np.stack([(cd[w] + (run_of * d_tile)[:, None])[vc],
+                        (cw[w] + (wt[w] * w_tile)[:, None])[vc], z[w][vc]])
+        np.testing.assert_array_equal(got, want)
+        # valid slots lead a chunk; a no-op holds none
+        np.testing.assert_array_equal(
+            vc, np.arange(cc)[None, :] < vc.sum(1)[:, None])
+        assert not vc[noop[w]].any()
+        for r in range(n_runs):
+            sl = slice(r * nchr, (r + 1) * nchr)
+            real = ~noop[w, sl]
+            n_real = int(real.sum())
+            # tail no-ops: the real chunks are a prefix of the run's
+            # slab, every one holds a token, the no-ops stay at the last
+            # real chunk's word tile (tile 0 in an empty run)
+            assert real[:n_real].all()
+            assert vc[sl][:n_real].any(1).all()
+            tiles = wt[w, sl]
+            assert (tiles[n_real:] == (tiles[n_real - 1] if n_real else 0)
+                    ).all()
+            # a tile's chunks adjacent, each word tile one contiguous
+            # group inside the run: the groups' tiles strictly increase
+            changes = np.flatnonzero(np.diff(tiles[:n_real]))
+            assert (np.diff(tiles[:n_real])[changes] > 0).all()
+
+
+def _layout_cases():
+    """name → (doc ids, word ids, n_docs, vocab, entry_cap, workers): tiles
+    of 8 documents x 8 words, chunks of 8 slots."""
+    rng = np.random.default_rng(11)
+
+    def tile(dt, wt, n):
+        return (dt * 8 + rng.integers(0, 8, n), wt * 8 + rng.integers(0, 8, n))
+
+    def corpus(*tiles):
+        d, w = (np.concatenate(x) for x in zip(*tiles))
+        order = rng.permutation(len(d))
+        return d[order].astype(np.int32), w[order].astype(np.int32)
+
+    # 32 words = two half-slices of two word tiles; 24 documents = 3 runs
+    return {
+        # tiles of 1, 2 and many chunks, one over the entry cap (two entries)
+        "one_two_many_chunks": (*corpus(
+            tile(0, 0, 5), tile(0, 1, 13), tile(1, 0, 41), tile(1, 1, 8),
+            tile(2, 0, 70), tile(2, 1, 1), tile(0, 2, 9), tile(1, 3, 17),
+            tile(2, 2, 3)), 24, 32, 64, 1),
+        # word tile 1 of the first half-slice holds nothing anywhere
+        "empty_word_tile": (*corpus(
+            tile(0, 0, 12), tile(1, 0, 3), tile(2, 0, 20), tile(0, 2, 4),
+            tile(0, 3, 30), tile(2, 3, 6)), 24, 32, 64, 1),
+        # document tile 1 holds nothing: a run of no-ops in every row
+        "empty_doc_tile_run": (*corpus(
+            tile(0, 0, 12), tile(0, 1, 9), tile(2, 0, 25), tile(2, 1, 2),
+            tile(0, 2, 7), tile(2, 3, 11)), 24, 32, 64, 1),
+        # rows of unequal length: two workers, four half-slices, one heavy
+        "unequal_rows": (*corpus(
+            tile(0, 0, 60), tile(0, 1, 33), tile(1, 0, 18), tile(2, 1, 2),
+            tile(3, 2, 5), tile(4, 3, 1), tile(5, 0, 9), tile(1, 2, 4)),
+            48, 32, 32, 2),
+        # a tile of exactly one chunk, of one token more, of exactly two
+        # and of two and one: ceil(count / chunk) at its edges
+        "exactly_a_chunk_and_one_over": (*corpus(
+            tile(0, 0, 8), tile(0, 1, 9), tile(1, 0, 16), tile(2, 1, 17),
+            tile(1, 2, 7)), 24, 32, 64, 1),
+        # an entry cap that is no multiple of the chunk: full entries end
+        # in a partly filled chunk
+        "cap_not_a_chunk_multiple": (*corpus(
+            tile(0, 0, 50), tile(1, 1, 21), tile(2, 0, 12), tile(0, 3, 31)),
+            24, 32, 20, 1),
+    }
+
+
+_CASES = _layout_cases()
+
+
+@pytest.mark.parametrize("case", sorted(_CASES))
+def test_stage_chunk_list_keeps_the_contract(case):
+    """(ii): every token once and in the parent's order, a tile's chunks
+    adjacent, each word tile one contiguous group inside a run, tail
+    no-ops, the metadata round trip — and the arrays are the plain-loop
+    layout's, byte for byte."""
+    from harp_tpu.ops.lda_kernel import stage_chunk_list
+
+    doc, word, n_docs, vocab, cap, nw = _CASES[case]
+    entries, n_runs = _entries(doc, word, n_docs, vocab, 8, 8, cap, nw)
+    staged = stage_chunk_list(*entries, n_runs, 8, 8, cc=8)
+    _check_chunk_list(entries, staged, n_runs, 8, 8)
+    by_loops, _ = _loop_chunk_list(entries, n_runs, 8, 8, 8, False)
+    for a, b in zip(staged, by_loops):
+        np.testing.assert_array_equal(a, b)
+    # the fully padded entry list is a chunk list too, and keeps the
+    # same contract but for its all-padding chunks
+    assert int((staged[0] < 8).sum()) == len(doc)
+
+
+@pytest.mark.parametrize("count", [1, 127, 128, 129, 256, 257, 700])
+def test_a_tile_stages_the_chunks_that_hold_its_tokens(count):
+    """At the kernel's own width (``CHUNK`` = 128 slots, 128-wide tiles,
+    the program's ``entry_cap``): a tile of ``count`` tokens beside a
+    one-token tile in a second run stages ``ceil(count / 128)`` chunks,
+    the other run one and no-ops up to the same length; the fixed-width
+    entries before it staged ``ceil(count / 256) * 256`` slots an entry
+    whatever they held."""
+    from harp_tpu.ops.lda_kernel import (CHUNK, stage_chunk_list,
+                                         unpack_chunk_meta)
+
+    rng = np.random.default_rng(count)
+    doc = np.r_[rng.integers(0, 128, count), 128 + 5].astype(np.int32)
+    word = np.r_[rng.integers(128, 256, count), 3].astype(np.int32)
+    entries, n_runs = _entries(doc, word, 256, 512, 128, 128, 2048)
+    assert n_runs == 2
+    cd, cw, z, meta = stage_chunk_list(*entries, n_runs, 128, 128)
+    _check_chunk_list(entries, (cd, cw, z, meta), n_runs, 128, 128)
+    want = -(-count // CHUNK)
+    assert cd.shape == (2, 2 * want, CHUNK)
+    wt, noop = unpack_chunk_meta(meta)
+    # half-slice 0 holds both tiles (words 0..255): run 0 the heavy one
+    # at word tile 1, run 1 the single token at word tile 0
+    np.testing.assert_array_equal(noop[0], np.r_[[False] * want, False,
+                                                 [True] * (want - 1)])
+    np.testing.assert_array_equal(wt[0], np.r_[[1] * want, [0] * want])
+    np.testing.assert_array_equal((cd[0] < 128).sum(1)[:want],
+                                  np.r_[[CHUNK] * (want - 1),
+                                        count - (want - 1) * CHUNK])
+    assert noop[1].all() and not (cd[1] < 128).any()  # an empty row
+
+
+def test_stage_chunk_list_refuses_what_the_kernel_cannot_run():
+    """Entries out of document-tile-major order, a word tile index over
+    the metadata's bits, a run longer than SMEM holds: a ValueError each,
+    before anything is staged or dispatched."""
+    import jax
+    import jax.numpy as jnp
+
+    from harp_tpu.ops import lda_kernel as LK
+
+    doc, word, n_docs, vocab, cap, nw = _CASES["one_two_many_chunks"]
+    (ed, ew, ez, od, ow), n_runs = _entries(doc, word, n_docs, vocab, 8, 8,
+                                            cap, nw)
+    nreal = int(((ed[0] < 8).sum(-1) > 0).sum())
+    swap = np.r_[nreal - 1, np.arange(1, nreal - 1), 0,
+                 np.arange(nreal, ed.shape[1])]
+    with pytest.raises(ValueError, match="document-tile-major"):
+        LK.stage_chunk_list(ed[:, swap], ew[:, swap], ez[:, swap],
+                            od[:, swap], ow[:, swap], n_runs, 8, 8, cc=8)
+    with pytest.raises(ValueError, match="document-tile-major"):
+        LK.stage_chunk_list(ed, ew, ez, od, ow, n_runs - 1, 8, 8, cc=8)
+    with pytest.raises(ValueError, match="lead each entry"):
+        LK.stage_chunk_list(ed[..., ::-1], ew[..., ::-1], ez[..., ::-1],
+                            od, ow, n_runs, 8, 8, cc=8)
+    with pytest.raises(ValueError, match="30 bits"):
+        LK.pack_chunk_meta(np.array([0, 1 << 30]), False)
+    with pytest.raises(ValueError, match="30 bits"):
+        LK.pack_chunk_meta(np.array([-1]), False)
+    nch = LK._MAX_CHUNKS + 1
+    i32 = jnp.int32
+    with pytest.raises(ValueError, match="SMEM"):
+        jax.eval_shape(
+            lambda *a: LK.cgs_run_update(
+                *a, alpha=0.1, beta=0.1, vbeta=1.0, d_tile=8, w_tile=8,
+                interpret=True),
+            *(jax.ShapeDtypeStruct(s, d) for s, d in (
+                ((8, 8), jnp.float32), ((8, 16), jnp.float32),
+                ((8,), jnp.float32), ((nch, 8), i32), ((nch, 8), i32),
+                ((nch, 8), i32), ((nch,), i32), ((), i32), ((2,), i32))))
+
+
+def _run_chunk_list(staged, row, K, n_runs, tables, nk, uniforms):
+    """One rotation step of ``lda._sample_runs_pallas`` by hand: the
+    row's runs in order through ``cgs_run_update``, ``uniforms[r]``
+    [K, chunks a run, cc] feeding run ``r``."""
+    import jax.numpy as jnp
+
+    from harp_tpu.ops.lda_kernel import cgs_run_update
+
+    cd, cw, z, meta = (jnp.asarray(a[row]) for a in staged)
+    nchr = cd.shape[0] // n_runs
+    NdkT, NwkT = (jnp.asarray(t) for t in tables)
+    dnk = jnp.zeros(K, jnp.float32)
+    z_out = []
+    for r in range(n_runs):
+        sl = slice(r * nchr, (r + 1) * nchr)
+        NdkT, NwkT, z_new, d = cgs_run_update(
+            NdkT, NwkT, jnp.asarray(nk) + dnk, z[sl], cd[sl], cw[sl],
+            meta[sl], r, jnp.zeros(2, jnp.int32), alpha=0.5, beta=0.1,
+            vbeta=3.2, d_tile=8, w_tile=8, interpret=True,
+            nwk_count_bound=600, ndk_count_bound=600,
+            uniforms=jnp.asarray(uniforms[r].reshape(K, -1)))
+        dnk = dnk + d
+        z_out.append(np.asarray(z_new))
+    return (np.asarray(NdkT), np.asarray(NwkT), np.asarray(dnk),
+            np.concatenate(z_out))
+
+
+@pytest.mark.parametrize("case", sorted(_CASES))
+def test_chunk_list_chain_equals_the_padded_entry_chain(case):
+    """(i) layout equivalence, interpret mode: the chunk list against the
+    fully padded entry list (every chunk of every entry's full width,
+    the all-padding ones executed) through the same kernel body, the
+    same uniforms chunk for chunk: every token's new topic, both count
+    tables and ``dN_k`` bit-identical.  The chunks dropped were masked
+    slots that changed no count and no topic."""
+    K = 8
+    doc, word, n_docs, vocab, cap, nw = _CASES[case]
+    entries, n_runs = _entries(doc, word, n_docs, vocab, 8, 8, cap, nw)
+    compact, c_src = _loop_chunk_list(entries, n_runs, 8, 8, 8, False)
+    padded, p_src = _loop_chunk_list(entries, n_runs, 8, 8, 8, True)
+    from harp_tpu.ops.lda_kernel import stage_chunk_list
+
+    for a, b in zip(stage_chunk_list(*entries, n_runs, 8, 8, cc=8), compact):
+        np.testing.assert_array_equal(a, b)
+    assert sum(map(len, p_src)) > sum(map(len, c_src))  # chunks that run
+    rng = np.random.default_rng(5)
+    d_rows, w_rows, moved = n_runs * 8, 16, 0
+    for row in range(compact[0].shape[0]):
+        # tables with counts on both sides of 256, as the chain could
+        # hold them (the draws read them; their sums are not the corpus')
+        NdkT = rng.integers(0, 40, (K, d_rows)).astype(np.float32)
+        NwkT = rng.integers(0, 600, (K, w_rows)).astype(np.float32)
+        nk = NwkT.sum(1) + 50.0
+        c_nchr = compact[0].shape[1] // n_runs
+        p_nchr = padded[0].shape[1] // n_runs
+        u_c = rng.uniform(2.0 ** -25, 1.0, (n_runs, K, c_nchr, 8)).astype(
+            np.float32)
+        # the padded layout's uniforms: each real chunk its twin's, the
+        # padding's whatever
+        u_p = rng.uniform(2.0 ** -25, 1.0, (n_runs, K, p_nchr, 8)).astype(
+            np.float32)
+        at = {src: pos for pos, src in p_src[row].items()}
+        for pos, src in c_src[row].items():
+            r = src[0]
+            u_p[r, :, at[src] - r * p_nchr] = u_c[r, :, pos - r * c_nchr]
+        got = _run_chunk_list(compact, row, K, n_runs, (NdkT, NwkT), nk, u_c)
+        want = _run_chunk_list(padded, row, K, n_runs, (NdkT, NwkT), nk, u_p)
+        for a, b in zip(got[:3], want[:3]):
+            np.testing.assert_array_equal(a, b)
+        for pos, src in c_src[row].items():
+            np.testing.assert_array_equal(got[3][pos], want[3][at[src]])
+        assert got[0].sum() == NdkT.sum() and got[1].sum() == NwkT.sum()
+        moved += int((got[3] != compact[2][row]).sum())
+    assert moved > len(doc) // 2  # and the chain was sampled
+
+
+def test_unvisited_tiles_keep_their_counts():
+    """Both tables alias the kernel's outputs: a run visits one doc tile
+    and the word tiles its chunks name, and every other block comes back
+    as it went in — no coverage chunk needed for an empty tile."""
+    import jax.numpy as jnp
+
+    from harp_tpu.ops.lda_kernel import cgs_run_update, pack_chunk_meta
+
+    K, rng = 8, np.random.default_rng(2)
+    NdkT = rng.integers(1, 30, (K, 24)).astype(np.float32)
+    NwkT = rng.integers(1, 30, (K, 32)).astype(np.float32)
+    # run 1 (doc tile 1): two chunks at word tile 2, a no-op behind them
+    cd = np.full((3, 8), 8, np.int32)
+    cw = np.full((3, 8), 8, np.int32)
+    cd[0], cw[0] = rng.integers(0, 8, 8), rng.integers(0, 8, 8)
+    cd[1, :3], cw[1, :3] = rng.integers(0, 8, 3), rng.integers(0, 8, 3)
+    z = rng.integers(0, K, (3, 8)).astype(np.int32)
+    meta = pack_chunk_meta([2, 2, 2], [False, False, True])
+    Ndk2, Nwk2, z_new, dnk = (np.asarray(a) for a in cgs_run_update(
+        jnp.asarray(NdkT), jnp.asarray(NwkT), jnp.asarray(NwkT.sum(1)),
+        jnp.asarray(z), jnp.asarray(cd), jnp.asarray(cw), jnp.asarray(meta),
+        1, jnp.array([4, 2], jnp.int32), alpha=0.5, beta=0.1, vbeta=3.2,
+        d_tile=8, w_tile=8, interpret=True))
+    doc_tile, word_tile = slice(8, 16), slice(16, 24)
+    for new, old, sl in ((Ndk2, NdkT, doc_tile), (Nwk2, NwkT, word_tile)):
+        keep = np.ones(old.shape[1], bool)
+        keep[sl] = False
+        np.testing.assert_array_equal(new[:, keep], old[:, keep])
+        np.testing.assert_array_equal(new[:, sl].sum(1) - old[:, sl].sum(1),
+                                      dnk)
+    assert (z_new[:2][cd[:2] < 8] != z[:2][cd[:2] < 8]).any()
+    np.testing.assert_array_equal(z_new[cd == 8], z[cd == 8])  # pads, no-op

@@ -2,12 +2,18 @@
 lda.py``) at toy sizes, through the three checks the cell ``lda-sweeps``
 decides ``correct`` by, as its driver runs them: (a) the tables are a
 recount of the chain, (b) the program's likelihood is the reference's
-likelihood of the program's tables, (c) the chain's likelihood lies in a
-band around the plain sampler's.  A planted fault each for (a) and (c)
-and the precision below the configuration's (what no statistic of the
-chain can show, exact count gathers, is held token for token in
-``tests/test_lda_kernel.py``); the spans and the ``lda.kernel_slots``
-record ``set_tokens`` leaves."""
+likelihood of the program's tables, both on the state the window left;
+(c) the likelihood of the chain as it stood after the configuration's
+``reference.chain_sweeps`` = 4 sweeps (a window that ended sooner, as
+every one here does: at its last sweep) lies within 0.6 of the plain
+sampler's step there of the mean of the plain sampler's four keys.  A
+planted fault each for (a) and (c) and the precision below the
+configuration's (what no statistic of the chain can show, exact count
+gathers, is held token for token in ``tests/test_lda_kernel.py``); the
+spans and the ``lda.kernel_slots`` / ``lda.kernel_chunks`` records
+``set_tokens`` leaves.  That (c) stays at sweep 4 however long the run
+goes on, and every fault of ``perf/tests/lda_faults.py``, is
+``perf/tests/test_lda_check.py``'s."""
 
 import copy
 import os
@@ -28,9 +34,10 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # size over four seeds and both arms, 2 and 4 sweeps (CPU, PR 29): the
 # program stands 0.001-0.083 of a plain sweep's step from the mean of
 # the plain sampler's four keys, whose range is 0.04-0.10 of a step; the
-# band is 0.6.  (At 20 sweeps this small chain has flattened and 20,000
-# tokens are noisy: up to 0.29, the keys' range up to 0.72.  The cell's
-# 1.9M tokens run 4-6 sweeps; the tests keep to 2 or 3.)
+# band is 0.6.  The tests keep to 2 or 3 sweeps, so (c) is taken at the
+# window's last; at sweep 4 of a longer run the same size reads
+# 0.011-0.027 against a band of 0.058-0.061 (CPU, PR 31;
+# perf/tests/test_lda_check.py).
 TOY = {"n_docs": 200, "n_tokens": 20_000, "vocab_size": 2000,
        "n_topics": 16}
 TILES = {"d_tile": 128, "w_tile": 128, "entry_cap": 256}
@@ -249,13 +256,29 @@ def test_kernel_slots_is_a_count_of_the_staged_arrays(algo, one_worker,
     with telemetry.scope():
         model = _set_tokens(one_worker, toy_corpus, algo=algo)
         rec = skew.ledger.summary()["lda.kernel_slots"]
-    ed = np.asarray(model._tokens[0])  # [2, NE, C] as staged
+        chunks = skew.ledger.summary().get("lda.kernel_chunks")
+    # dense: [2, NE, C] entries; pallas: [2, NCH, 128], the chunk list
+    ed = np.asarray(model._tokens[0])
     valid = int((ed < TILES["d_tile"]).sum())
     assert valid == TOY["n_tokens"] == rec["total"]
     # the summary rounds the share to six places
     assert rec["padding_frac"] == pytest.approx(1 - valid / ed.size,
                                                 abs=1e-6)
     assert ed.shape[0] == 2  # one worker, two half-slices
+    if algo == "dense":
+        assert chunks is None  # the kernel's record, not the layout's
+        return
+    # the chunks that hold tokens over the chunks staged: the rest are
+    # the no-ops that end the shorter runs, and what padding is left is
+    # inside the chunks that run
+    from harp_tpu.ops.lda_kernel import CHUNK, unpack_chunk_meta
+
+    noop = unpack_chunk_meta(np.asarray(model._tokens[2]))[1]
+    assert ed.shape[2] == CHUNK and noop.shape == ed.shape[:2]
+    assert chunks["total"] == int((~noop).sum()) > 0
+    assert chunks["padding_frac"] == pytest.approx(noop.mean(), abs=1e-6)
+    assert not (ed[noop] < TILES["d_tile"]).any()
+    assert chunks["padding_frac"] < rec["padding_frac"]
 
 
 def test_set_tokens_spans_nest_and_cost_nothing_when_off(one_worker,
@@ -279,6 +302,7 @@ def test_set_tokens_spans_nest_and_cost_nothing_when_off(one_worker,
     quiet = _set_tokens(one_worker, toy_corpus)
     assert telemetry.tracer.records == []
     assert "lda.kernel_slots" not in skew.ledger.summary()
+    assert "lda.kernel_chunks" not in skew.ledger.summary()
     with telemetry.scope():
         loud = _set_tokens(one_worker, toy_corpus)
     for a, b in zip(quiet._tokens, loud._tokens):

@@ -86,11 +86,14 @@ def test_shape_model_matches_partitioner_dense(mesh):
 def test_shape_model_takes_the_real_partitioners_entries_on_a_zipf_corpus():
     """A Zipf vocabulary over several word tiles (the benchmark's corpus
     generator at a toy size, one worker, the pallas layout): the real
-    partitioner's NE and C fed through ``entries_per_row`` /
-    ``entry_width`` give ``pack_tokens``' shapes, and the tight-packing
-    default undercounts NE by what the light tiles leave empty: 28% at
-    these 16 word tiles, 30-80x at the 1,954 of a 1M-word vocabulary
-    (12,859 entries a half-slice against 472: PERF.md section 6, PR 29)."""
+    ``stage_chunk_list`` count fed through ``entries_per_row`` gives
+    ``pack_tokens``' shapes (a grid row is runs x chunks of
+    ``lda_kernel.CHUNK`` slots; ``entry_width`` is no parameter there and
+    raises), and the tight-packing default undercounts the chunks by
+    what the light tiles and the shorter runs leave empty: 2.4x at the
+    1,954 word tiles of a 1M-word vocabulary (13 x 1,381 = 17,953 chunks
+    a half-slice against 13 x 581 = 7,553: PERF.md section 6, PR 32)."""
+    from harp_tpu.ops.lda_kernel import CHUNK
     from harp_tpu.parallel.mesh import WorkerMesh
     from perf import corpus
 
@@ -102,14 +105,20 @@ def test_shape_model_takes_the_real_partitioners_entries_on_a_zipf_corpus():
     cfg = L.LDAConfig(n_topics=16, d_tile=128, w_tile=128, entry_cap=256)
     model = L.LDA(n_docs, vocab, cfg, WorkerMesh(jax.devices()[:1]))
     model.set_tokens(doc, word)
-    _, ne_real, c_real = model._tokens[0].shape
+    _, nch_real, c_real = model._tokens[0].shape
+    runs = model.d_bound // cfg.d_tile
+    assert c_real == CHUNK and nch_real % runs == 0
     _check_shapes(model, L.epoch_arg_shapes(
-        1, n_docs, vocab, cfg, n_tokens=n_tokens,
-        entries_per_row=ne_real, entry_width=c_real))
-    default_ne = L.epoch_arg_shapes(
+        1, n_docs, vocab, cfg, n_tokens=n_tokens, entries_per_row=nch_real))
+    default_nch = L.epoch_arg_shapes(
         1, n_docs, vocab, cfg, n_tokens=n_tokens)[4][0][1]
-    assert default_ne == 40 and ne_real > 1.2 * default_ne
-    assert c_real == cfg.entry_cap  # the hot tiles fill their cap
+    assert default_nch == runs * 40 and nch_real > 1.2 * default_nch
+    with pytest.raises(ValueError, match="entry_width"):
+        L.epoch_arg_shapes(1, n_docs, vocab, cfg, n_tokens=n_tokens,
+                           entry_width=256)
+    with pytest.raises(ValueError, match="document-tile runs"):
+        L.epoch_arg_shapes(1, n_docs, vocab, cfg, n_tokens=n_tokens,
+                           entries_per_row=nch_real + 1)
 
 
 def _sds(mesh, shapes):
@@ -154,12 +163,18 @@ def test_enwiki_1m_pallas_program_lowers(mesh, monkeypatch, carry_db):
     """The fused-kernel epoch at the TRUE graded shapes, MOSAIC-compiled:
     HARP_PALLAS_FORCE_MOSAIC routes the kernel through the real Pallas→
     Mosaic lowering (not interpret), and the whole program — topic-major
-    transposes, entry scan, scalar-prefetch grids, the kernel itself,
-    and (round 4) the carry_db flush/load cond — lowers for TPU on this
-    CPU host."""
+    transposes, the scan over document-tile runs, the scalar-prefetched
+    chunk metadata, the kernel itself with both tables aliased — lowers
+    for TPU on this CPU host.  The doc-tile carry is the kernel's:
+    ``carry_db=False`` has no program to lower and raises."""
     monkeypatch.setenv("HARP_PALLAS_FORCE_MOSAIC", "1")
-    cfg = L.LDAConfig(n_topics=K, algo="pallas", ndk_dtype="int16",
-                      sampler="exprace", rng_impl="rbg", carry_db=carry_db)
+    kw = dict(n_topics=K, algo="pallas", ndk_dtype="int16",
+              sampler="exprace", rng_impl="rbg", carry_db=carry_db)
+    if not carry_db:
+        with pytest.raises(ValueError, match="carry_db=False"):
+            L.LDAConfig(**kw)
+        return
+    cfg = L.LDAConfig(**kw)
     shapes = L.epoch_arg_shapes(8, N_DOCS, VOCAB, cfg, n_tokens=N_TOK)
     fn = L.make_multi_epoch_fn(mesh, cfg, VOCAB, epochs=2)
     lowered = fn.trace(*_sds(mesh, shapes)).lower(
