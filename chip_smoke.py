@@ -2,7 +2,7 @@
 """chip_smoke.py — the quickest proof that the system still starts on the chip.
 
 Drives the north-star pair of BASELINE.json once, at full width, through
-the functions the launcher and bench.py call (``models/kmeans.benchmark``
+the functions the launcher calls (``models/kmeans.benchmark``
 and ``.fit``, ``models/mfsgd.benchmark``), then compiles and executes every
 Pallas kernel in ``ops/kernel_registry.KERNELS`` against the reference its
 own test file uses, then (on more than one device) checks each base verb of
@@ -132,7 +132,7 @@ def _finite(name: str, value) -> None:
 
 def phase_kmeans(mesh, meter, *, n, d, k, iters, on_tpu) -> dict:
     """f32 XLA arm + int8 fused Pallas arm through ``kmeans.benchmark``
-    (bench.py's two kmeans cells), and the XLA int8 arm as the fused
+    (the launcher's two kmeans arms), and the XLA int8 arm as the fused
     kernel's reference: equal inertia after ``iters`` Lloyd iterations."""
     from harp_tpu.models import kmeans
 

@@ -207,7 +207,7 @@ def lint_source(relpath: str, text: str) -> list[Violation]:
 # default scan set: library + drivers + tooling; tests are reference/golden
 # code (PRNGKey as the equivalence oracle etc.) and lint their own fixtures
 DEFAULT_ROOTS = ("harp_tpu", "scripts", "examples",
-                 "bench.py", "chip_smoke.py", "__graft_entry__.py")
+                 "chip_smoke.py", "__graft_entry__.py")
 
 
 def iter_python_files(repo: str, roots=DEFAULT_ROOTS):

@@ -128,11 +128,7 @@ PLANES: tuple[PlaneSpec, ...] = (
     PlaneSpec("schedule", ("harp_tpu/schedule.py",), ("main",)),
     PlaneSpec("timing", ("harp_tpu/utils/timing.py",), ("main",)),
     PlaneSpec("fault", ("harp_tpu/utils/fault.py",), ("main",)),
-    # bench-config-worker RUNS each config thunk (bench.py `_run_boxed`
-    # pattern: main only joins with a timeout), so it is the bench
-    # plane's jax thread by design
-    PlaneSpec("bench", ("bench.py", "harp_tpu/serve/bench.py"),
-              ("main", "thread:run")),
+    PlaneSpec("bench", ("harp_tpu/serve/bench.py",), ("main",)),
 )
 
 

@@ -5,7 +5,7 @@ This package import stays light (vocabularies + the sentinel; no jax,
 no perfmodel): the skew/flightrec hooks import it lazily on their hot
 paths.  The evidence-regression grader (:mod:`harp_tpu.health.grade`)
 pulls the perfmodel import cascade, so it is NOT imported here — the
-CLI and the measure_all pruning gate import it directly.
+CLI imports it directly.
 """
 
 from harp_tpu.health.sentinel import (  # noqa: F401

@@ -121,7 +121,7 @@ def main(argv=None) -> int:
         print(json.dumps(_stamped(row)), flush=True)
         if not ok:
             print("health: perfmodel INVALIDATED by committed evidence "
-                  "— measure_all --predicted-top will refuse until the "
+                  "— do not rank by it until the "
                   "model is re-calibrated (python -m harp_tpu predict "
                   "--grade for the term breakdowns)", file=sys.stderr)
             return 1

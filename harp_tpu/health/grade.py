@@ -1,27 +1,23 @@
-"""Evidence regression — grade fresh measurements, gate the pruning.
+"""Evidence regression — grade fresh measurements, gate the model.
 
 The fourth detector family (see :mod:`harp_tpu.health.sentinel`): a
 freshly measured bench row is judged against two baselines —
 
 1. the **committed incumbent** (the latest full-shape TPU row for the
-   same config in BENCH_local.jsonl, the same filter as
-   ``flip_decision.latest_rows``): relative-tolerance verdict per
-   metric family — ``regressed`` / ``improved`` outside the ±10% dead
-   band (:data:`REL_TOL`, the flip rule's own margin), ``confirmed``
-   inside it;
+   same config in BENCH_local.jsonl, smoke, error and CPU rows
+   filtered out): relative-tolerance verdict per metric family —
+   ``regressed`` / ``improved`` outside the ±10% dead band
+   (:data:`REL_TOL`), ``confirmed`` inside it;
 2. the **perfmodel's prediction** (:mod:`harp_tpu.perfmodel`): the
    magnitude band (``grade.MAGNITUDE_TOL``) and — for flip candidates
    with a measured incumbent — the ranking direction.  Either failing
    yields ``model_invalidated``: the model mis-priced real silicon.
 
-``model_invalidated`` is the verdict ROADMAP autotuning item (3) wants
-blocking the next sprint pruning: :func:`model_gate` re-runs the
-perfmodel's full self-grade against ALL committed evidence and
-``measure_all.py --predicted-top`` REFUSES (fail closed) when it fails
-— a model invalidated by fresh silicon evidence cannot prune the run
-that would re-measure it.  Run ``python -m harp_tpu health
---grade-model`` right after a measurement run lands new rows, so the
-verdict is committed evidence, not a scrolled warning.
+``model_invalidated`` says the model may not rank anything:
+:func:`model_gate` re-runs the perfmodel's full self-grade against ALL
+committed evidence, and ``python -m harp_tpu health --grade-model``
+exits 1 when it fails.  Run it right after a measurement run lands new
+rows, so the verdict is committed evidence, not a scrolled warning.
 """
 
 from __future__ import annotations
@@ -31,10 +27,10 @@ import os
 from harp_tpu.health import sentinel
 
 #: |ratio - 1| at or below this is "confirmed" — the same 10% margin the
-#: flip rule and the perfmodel's ranking dead band use.
+#: perfmodel's ranking dead band uses.
 REL_TOL = 0.10
 
-#: headline metric resolution order (bench.py UNITS keys + serve qps) —
+#: headline metric resolution order (the apps' rate keys + serve qps) —
 #: the first key present in a row is its metric family.
 METRIC_KEYS = ("iters_per_sec", "updates_per_sec_per_chip",
                "tokens_per_sec_per_chip", "samples_per_sec",
@@ -57,11 +53,10 @@ def grade_bench_row(row: dict, repo: str, *, bench: dict | None = None,
                     topo=None) -> dict | None:
     """Judge one freshly measured bench row; register and return the
     ``evidence_regression`` finding, or None when there is nothing to
-    grade against (no incumbent AND no model — fail-closed rows are the
-    flip gate's job, not the grader's).
+    grade against (no incumbent AND no model).
 
-    Smoke / error / CPU-sim rows are never graded (the same
-    CPU-inversion filter as ``flip_decision.latest_rows``).
+    Smoke / error / CPU-sim rows are never graded (CPU-sim speeds
+    invert the chip's rankings).
     """
     from harp_tpu.perfmodel import grade as G
     from harp_tpu.perfmodel import model as M
@@ -214,17 +209,16 @@ def grade_profile_row(row: dict, repo: str, *,
 
 
 def model_gate(repo: str) -> tuple[bool, dict]:
-    """ROADMAP autotuning item (3), closed: re-run the perfmodel's full
-    self-grade (``perfmodel.grade.grade`` — flip-pair directions, sweep
-    rank correlation, magnitude band, all against the COMMITTED
-    evidence files, which include any rows a sprint just landed) and
-    turn the outcome into an ``evidence_regression`` health finding.
+    """Re-run the perfmodel's full self-grade (``perfmodel.grade.grade``
+    — candidate-pair directions, sweep rank correlation, magnitude band,
+    all against the COMMITTED evidence files) and turn the outcome into
+    an ``evidence_regression`` health finding.
 
-    Returns ``(ok, finding)``.  ``measure_all.py --predicted-top``
-    calls this as its preflight and REFUSES to prune when ``ok`` is
-    False — the gate re-runs the grade every time, so the refusal lifts
-    exactly when the model has been re-calibrated against the evidence
-    that invalidated it (no manual ack file to go stale).
+    Returns ``(ok, finding)``; ``health --grade-model`` prints the
+    finding and exits 1 when ``ok`` is False.  The gate re-runs the
+    grade every time, so the refusal lifts exactly when the model has
+    been re-calibrated against the evidence that invalidated it (no
+    manual ack file to go stale).
     """
     from harp_tpu.perfmodel import grade as G
 

@@ -40,9 +40,8 @@ Four detector families, each grounded in a landed mechanism:
   leaves committed evidence.
 - **evidence regression** (:mod:`harp_tpu.health.grade`) — fresh bench
   rows judged against the committed incumbent and the perfmodel's
-  prediction; ``model_invalidated`` is the verdict that fails the next
-  ``measure_all --predicted-top`` pruning closed (ROADMAP autotuning
-  item 3).
+  prediction; ``model_invalidated`` is the verdict that fails
+  ``health --grade-model`` closed.
 
 Zero-cost when disabled (the PR-3 contract): every observe entry point
 returns before touching state unless telemetry is enabled
@@ -140,7 +139,7 @@ class HealthMonitor:
     reconciles exactly with the invariant-9/11 ledgers (the acceptance
     pin in tests/test_health.py).  ``mark()``/``since()`` let a bench
     delimit "findings new to this run" without resetting the monitor
-    (bench.py's monotone-counter contract).
+    (the counters stay monotone across runs).
     """
 
     def __init__(self):

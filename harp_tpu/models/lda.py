@@ -32,9 +32,6 @@ TPU-native design:
 from __future__ import annotations
 
 import dataclasses
-import glob
-import os
-import re
 import time
 
 import jax
@@ -103,7 +100,7 @@ class LDAConfig:
     # buffers ([nw·cap, K] each way) at the cost of counted drops —
     # dropped tokens simply keep their topic that sweep (still a valid
     # Gibbs chain: skipping a site preserves the stationary distribution).
-    # SIZING (VERDICT r2 item 5): with dedup_pulls the exact zero-drop cap
+    # SIZING: with dedup_pulls the exact zero-drop cap
     # is the max count of DISTINCT word rows per (chunk, owner) —
     # :func:`suggest_pull_cap` computes it from the loaded corpus (Zipf
     # corpora: far below chunk, because every repeat of a hot word shares
@@ -126,42 +123,38 @@ class LDAConfig:
     # od-run instead of slice+DUS per entry.  Entries are od-major
     # (partition_ratings_tiles sorts tiles u-major), so one od's ~25
     # entries at enwiki shapes (512 docs x 100 tok / 2048-token entries)
-    # currently pay 25x the [K, d_tile] in+out HBM traffic; the carry
+    # pay 25x the [K, d_tile] in+out HBM traffic without it; the carry
     # pays it once per run (a lax.cond flushes/loads ONLY on od change —
     # correct under any entry order: the switch always flushes before a
-    # region can be re-sliced).  Default OFF until TPU-measured: the
-    # cond+DUS-on-carry interaction is exactly the CLAUDE.md
-    # whole-table-copy trap's neighborhood (a round-3 regrouping
-    # prototype was reverted there), so the sweep configs lda_carry /
-    # lda_pallas_carry measure it and the flip gate decides (VERDICT r3
-    # item 2's queued decision, now one flag).  FLIPPED ON 2026-08-01
-    # for the pallas stack: the carry removes the dominant [K, d_tile]
-    # DUS write-back; chain bit-identical (silicon kernel_equiv_check)
-    # and no whole-table copies in the HLO.  The DENSE-stack arm
-    # (`lda_carry`) was VETOED by the conditional gate, so the auto
-    # default stays off there.
+    # region can be re-sliced).  The cond+DUS-on-carry interaction is the
+    # CLAUDE.md whole-table-copy trap's neighborhood (a regrouping
+    # prototype was reverted there); the HLO holds no whole-table copies
+    # and the chain is bit-identical (silicon kernel_equiv_check).
+    # Measured 2026-08-01 (1x v5e, FLIP_DECISIONS.jsonl): 1.33x on the
+    # pallas stack, where it removes the dominant [K, d_tile] DUS
+    # write-back, so it is on there; 1.13x on the dense stack, which by
+    # then was no longer the default, so the auto default stays off there.
     # None = "auto per algo", STORED as None and resolved at READ time by
     # :func:`carry_db_resolved` (mirrors MFSGDConfig.tiles() /
     # KMeansConfig._use_pallas — a __post_init__ resolution froze the
     # auto value, so ``dataclasses.replace(LDAConfig(), algo='scatter')``
     # raised and ``replace(..., algo='dense')`` silently enabled the
-    # VETOED dense-carry arm; ADVICE r5).  An explicit True on a
-    # non-tiled algo still raises.
+    # dense carry).  An explicit True on a non-tiled algo still raises.
     # algo="pallas" (PR 32): the carry is the kernel's — one call a
     # document-tile run keeps the run's doc tile in VMEM and XLA slices
     # nothing — so there is no slice-per-entry arm to choose: None and
     # True mean that carry, an explicit False raises.
     carry_db: bool | None = None
-    # algo="pallas" only: exact base-256-plane count gathers (ADVICE r3 —
-    # single-dot bf16 gathers round counts > 256, perturbing the posterior
-    # ~0.4% at enwiki hot-word counts).  Default ON: correctness first.
-    # False = single-dot gathers (+0/-2 MXU dots per tile); the
-    # lda_pallas_approx sweep config measures whether approx buys ≥10% at
-    # equal chain likelihood (flip_decision gate) before this may flip.
+    # algo="pallas" only: exact base-256-plane count gathers (single-dot
+    # bf16 gathers round counts > 256, perturbing the posterior ~0.4% at
+    # enwiki hot-word counts).  Default ON: correctness first.
+    # False = single-dot gathers (+0/-2 MXU dots per tile); measured
+    # 2026-08-01 (1x v5e, FLIP_DECISIONS.jsonl) 1.08x at the graded shape
+    # and 1.05x at hot counts, under the 10% a default has to buy.
     pallas_exact_gathers: bool = True
     # Doc-topic table dtype.  "int16" halves the Ndk HBM footprint — the
     # graded enwiki-1M × 1k-topics config needs 4 GB in f32 vs 2 GB in
-    # int16 (VERDICT r1 item 5) — and is EXACT: a doc-topic count is
+    # int16 — and is EXACT: a doc-topic count is
     # bounded by the doc's token count (≪ 32767), and every delta is ±1.
     # Sampling is bit-identical to f32 (tests pin this).  Nwk stays f32:
     # corpus-frequent words exceed the int16 range.
@@ -173,10 +166,10 @@ class LDAConfig:
     # from the IDENTICAL distribution (the winner of an exponential race
     # at rates p_k is k with probability p_k/Σp) with 1 log + 2 mul +
     # 1 div per element, ~5× fewer transcendentals on the VPU.  Same
-    # chain statistics, different random stream.  FLIPPED 2026-08-01
-    # with the pallas algo (its required stack; exprace pays only
-    # together with rbg: the noise TENSOR, not the transcendentals, was
-    # the wall).
+    # chain statistics, different random stream.  Default since
+    # 2026-08-01 with the pallas algo (its required stack; exprace pays
+    # only together with rbg, 1.24x against 0.98x alone on 1x v5e: the
+    # noise TENSOR, not the transcendentals, was the wall).
     sampler: str = "exprace"
     # Random-bit source for the per-[token, K] draws.  "threefry"
     # (default): JAX's counter-based PRNG — splittable, reproducible
@@ -185,7 +178,7 @@ class LDAConfig:
     # share of the epoch.  "rbg": XLA's RngBitGenerator — the TPU
     # hardware generator, near-free, still deterministic per key but a
     # different (backend-dependent) stream.  Chain statistics unaffected
-    # (any iid uniform source is a valid Gibbs draw).  FLIPPED
+    # (any iid uniform source is a valid Gibbs draw).  Default since
     # 2026-08-01 with the pallas algo (see sampler above).
     rng_impl: str = "rbg"
     # Rotation pipeline knobs (rotation algos only — pushpull never
@@ -195,9 +188,8 @@ class LDAConfig:
     # "int8" picks the in-flight chunk's ring payload.  The int8 wire
     # dequantizes counts lossily, so the chain samples against slightly
     # perturbed word-topic counts — a valid approximate-CGS trade (the
-    # whole parallel sampler is approximate), gated by the
-    # `lda_rotate_int8` log-likelihood flip candidate before it may
-    # become a default.
+    # whole parallel sampler is approximate), not yet measured on a chip:
+    # it may become a default only at equal chain likelihood there.
     rotate_chunks: int | None = None
     rotate_wire: str = "exact"
 
@@ -226,9 +218,8 @@ class LDAConfig:
         if self.pull_cap is not None and self.algo != "pushpull":
             raise ValueError("pull_cap only applies to algo='pushpull'")
         # carry_db=None stays None here — :func:`carry_db_resolved` reads
-        # it as "on for the pallas stack only" (exactly the 2026-08-01
-        # verdict: `lda_pallas_carry` FLIPPED, the dense arm `lda_carry`
-        # was VETOED); only an EXPLICIT True is validated
+        # it as "on for the pallas stack only" (what 2026-08-01 measured,
+        # see the field); only an EXPLICIT True is validated
         if self.carry_db and self.algo not in _TILED_ALGOS:
             raise ValueError("carry_db applies to the tiled algos "
                              f"{_TILED_ALGOS}, not algo={self.algo!r}")
@@ -258,12 +249,12 @@ class LDAConfig:
 
 def carry_db_resolved(cfg: LDAConfig) -> bool:
     """Resolved doc-tile carry — ``None`` means "on for the pallas stack
-    only" (the 2026-08-01 verdict: `lda_pallas_carry` FLIPPED at 1.33×,
-    the dense arm `lda_carry` was VETOED by the conditional gate, so only
-    the kernel stack may default the carry on).  Read-time resolution
+    only" (1x v5e, 2026-08-01: 1.33× on the pallas stack; the dense
+    stack's 1.13× came on a stack that is no longer the default, so only
+    the kernel stack defaults the carry on).  Read-time resolution
     (mirroring :func:`harp_tpu.models.mfsgd.tiles`) keeps
     ``dataclasses.replace(cfg, algo=...)`` tracking the new algo instead
-    of freezing the old algo's resolved value (ADVICE r5)."""
+    of freezing the old algo's resolved value."""
     return cfg.carry_db if cfg.carry_db is not None else cfg.algo == "pallas"
 
 
@@ -473,12 +464,6 @@ def _sample_runs_pallas(NdkT, NwkT, Nk, z, cd, cw, meta, key, cfg: LDAConfig,
 #: algos on the (d_tile × w_tile) tile grid: dense stages its entries as
 #: they are, pallas as the list of the chunks that hold their tokens
 _TILED_ALGOS = ("dense", "pallas")
-
-#: benchmark pack-cache format version — bump when pack_tokens/partitioner
-#: layout changes so stale cached packs can never be installed
-#: (2: algo="pallas" stages a chunk list, PR 32)
-_PACK_VERSION = 2
-
 
 def _epoch_device_fn(mesh: WorkerMesh, cfg: LDAConfig, vocab_size: int,
                      count_bounds=(None, None)):
@@ -986,10 +971,8 @@ class LDA:
     def pack_tokens(self, doc_ids, word_ids, z0=None) -> dict:
         """Host-side half of :meth:`set_tokens`: partition the corpus into
         this config's device layout and build the initial count tables —
-        a plain dict of numpy arrays, so callers can CACHE it
-        (``lda.benchmark``'s ``pack_cache``: the enwiki-1M pack costs
-        ~675 s on a 1-core host and is identical across sweep variants
-        that share a tiling).  ``_install_pack`` ships it to devices.
+        a plain dict of numpy arrays (the enwiki-1M pack cost ~675 s on
+        a 1-core host, 2026-08-01).  ``_install_pack`` ships it to devices.
 
         ``z0`` (PR 15): explicit per-token topic assignments instead of
         the seeded random init — the elastic repartition extracts the
@@ -1404,14 +1387,11 @@ def _make_cfg(n_topics, algo="dense", chunk=None, d_tile=None, w_tile=None,
         sampler = "exprace" if algo == "pallas" else "gumbel"
     if rng_impl is None:
         rng_impl = "rbg" if algo == "pallas" else "threefry"
-    # benchmark/sweep identity is per-NAME: the `_carry` configs own the
-    # carry knob, so an unstated carry_db pins to OFF here even though
-    # the user-facing LDAConfig default flipped ON (2026-08-01) — else
-    # the flip would silently turn `lda`/`lda_pallas` sweep rows into
-    # carry rows and the A/B would compare a config against itself
-    # (dense only: scatter/pushpull do not own the knob, and under
-    # algo="pallas" the carry is the kernel's since PR 32, so `lda_pallas`
-    # and `lda_pallas_carry` are one program and False would raise)
+    # a benchmark or CLI run has the carry only when it asks for it: an
+    # unstated carry_db pins to OFF here, so an A/B of the dense carry
+    # never compares a run against itself (dense only: scatter/pushpull
+    # do not own the knob, and under algo="pallas" the carry is the
+    # kernel's since PR 32, where False would raise)
     if carry_db is None and algo == "dense":
         carry_db = False
     return LDAConfig(n_topics=n_topics, ndk_dtype=ndk_dtype, sampler=sampler,
@@ -1429,58 +1409,9 @@ def _make_cfg(n_topics, algo="dense", chunk=None, d_tile=None, w_tile=None,
     }))
 
 
-def _load_pack(path: str) -> dict:
-    """Read a cached :meth:`LDA.pack_tokens` npz back into a pack dict."""
-    with np.load(path) as z:
-        nt = len([k for k in z.files if k.startswith("tok")])
-        return {"tokens": tuple(z[f"tok{i}"] for i in range(nt)),
-                "z_grid": z["z_grid"], "Ndk": z["Ndk"],
-                "Nwk": z["Nwk"], "Nk": z["Nk"],
-                "n_tokens": int(z["n_tokens"])}
-
-
-def _save_pack(path: str, pack: dict) -> None:
-    """Write a pack dict as npz — temp + atomic rename, because a
-    measurement run can be killed mid-config (timeouts, watchdogs) and a
-    truncated npz at the final path would poison every later cache hit.
-    The tmp name is per-process so a manual prewarm racing a benchmark
-    run can't interleave writes into one tmp file (ADVICE r4); stale
-    tmp siblings from killed writers are swept first so watchdog kills
-    don't accumulate orphaned multi-hundred-MB partials."""
-    # legacy constant-name orphans (pre-ADVICE-r4 writers) have no owner
-    # pid: always stale, sweep unconditionally
-    for stale in (path + ".tmp", path + ".tmp.npz"):
-        try:
-            os.unlink(stale)
-        except OSError:
-            pass
-    for stale in glob.glob(glob.escape(path) + ".*.tmp*"):
-        m = re.search(r"\.(\d+)\.tmp", stale)
-        try:
-            if m and int(m.group(1)) != os.getpid():
-                os.kill(int(m.group(1)), 0)  # raises if writer is dead
-        except ProcessLookupError:
-            try:
-                os.unlink(stale)
-            except OSError:
-                pass
-        except OSError:
-            pass  # can't signal (perms): assume live, leave it
-    tmp_path = f"{path}.{os.getpid()}.tmp"
-    np.savez(tmp_path, z_grid=pack["z_grid"], Ndk=pack["Ndk"],
-             Nwk=pack["Nwk"], Nk=pack["Nk"], n_tokens=pack["n_tokens"],
-             **{f"tok{i}": a for i, a in enumerate(pack["tokens"])})
-    # np.savez appends .npz to names without it
-    os.replace(tmp_path if os.path.exists(tmp_path) else tmp_path + ".npz",
-               path)
-
-
 def benchmark_corpus(n_docs, vocab_size, tokens_per_doc, seed):
     """The deterministic i.i.d. synthetic corpus :func:`benchmark` times
-    (structure irrelevant to cost).  ONE definition, shared with
-    scripts/prewarm_bench_cache.py — the pack-cache key assumes both
-    build identical corpora, so a second construction would let them
-    drift apart silently (same key, different bytes)."""
+    (structure irrelevant to cost)."""
     rng = np.random.default_rng(seed)
     n_tok = n_docs * tokens_per_doc
     d_ids = np.repeat(np.arange(n_docs, dtype=np.int32), tokens_per_doc)
@@ -1488,50 +1419,16 @@ def benchmark_corpus(n_docs, vocab_size, tokens_per_doc, seed):
     return d_ids, w_ids
 
 
-def _pack_cache_path(pack_cache, cfg: LDAConfig, num_workers, n_docs,
-                     vocab_size, n_topics, tokens_per_doc, seed) -> str:
-    """Cache path for a :func:`benchmark` corpus pack — layout-relevant
-    knobs ONLY, keyed by the EXACT algo: dense/pallas pack differently
-    (pallas stages a chunk list), and scatter vs pushpull use different
-    partitioners entirely (partition_ratings grid vs
-    partition_tokens_by_doc), so they must never share a pack.  Shared
-    with scripts/prewarm_bench_cache.py so an offline prewarm writes the
-    same keys the sprint reads."""
-    import hashlib
-
-    layout = (cfg.algo, cfg.algo == "pallas", cfg.d_tile, cfg.w_tile,
-              cfg.entry_cap, cfg.chunk, cfg.ndk_dtype)
-    # rotate_chunks changes n_slices and therefore the whole pack layout;
-    # appended only when non-incumbent so every existing 2-chunk cache
-    # key (675 s enwiki packs) stays valid
-    if rotate_chunks_resolved(cfg) != 2:
-        layout += (rotate_chunks_resolved(cfg),)
-    sig = repr((_PACK_VERSION, n_docs, vocab_size, n_topics,
-                tokens_per_doc, seed, num_workers, layout))
-    key = hashlib.sha1(sig.encode()).hexdigest()[:16]
-    os.makedirs(pack_cache, exist_ok=True)
-    return os.path.join(pack_cache, f"lda_pack_{key}.npz")
-
-
 def benchmark(n_docs=100_000, vocab_size=50_000, n_topics=1000,
               tokens_per_doc=100, epochs=2, mesh=None, chunk=None, seed=0,
               algo="dense", d_tile=None, w_tile=None, entry_cap=None,
               pull_cap=None, ndk_dtype="float32", dedup_pulls=None,
               sampler=None, rng_impl=None, pallas_exact_gathers=None,
-              carry_db=None, rotate_chunks=None, rotate_wire=None,
-              pack_cache=None):
+              carry_db=None, rotate_chunks=None, rotate_wire=None):
     """Tokens/sec/chip on an enwiki-1M-scaled config (graded config #3).
 
     (Full enwiki-1M docs needs a multi-chip pod for the 1M×1k doc-topic
     table; this keeps per-chip load representative.)
-
-    ``pack_cache``: directory for cached :meth:`LDA.pack_tokens` results.
-    The corpus here is deterministic in the arguments, and the pack is
-    identical across sweep variants sharing a tiling (sampler/rng/carry
-    knobs don't touch the layout), so the sprint pays the host packing —
-    675 s at enwiki-1M on this 1-core host — once per tiling instead of
-    once per config.  The key hashes every layout-relevant argument plus
-    ``_PACK_VERSION`` (bump it when packing code changes).
     """
     mesh = mesh or current_mesh()
     cfg = _make_cfg(n_topics, algo, chunk, d_tile, w_tile, entry_cap,
@@ -1542,16 +1439,7 @@ def benchmark(n_docs=100_000, vocab_size=50_000, n_topics=1000,
     n_tok = n_docs * tokens_per_doc
     d_ids, w_ids = benchmark_corpus(n_docs, vocab_size, tokens_per_doc, seed)
     t0 = time.perf_counter()
-    pack_path = (None if pack_cache is None else _pack_cache_path(
-        pack_cache, cfg, mesh.num_workers, n_docs, vocab_size, n_topics,
-        tokens_per_doc, seed))
-    if pack_path is not None and os.path.exists(pack_path):
-        model._install_pack(_load_pack(pack_path))
-    else:
-        pack = model.pack_tokens(d_ids, w_ids)
-        model._install_pack(pack)
-        if pack_path is not None:
-            _save_pack(pack_path, pack)
+    model.set_tokens(d_ids, w_ids)
     prep = time.perf_counter() - t0
 
     model.sample_epoch()         # warmup + single-epoch compile
@@ -1565,12 +1453,12 @@ def benchmark(n_docs=100_000, vocab_size=50_000, n_topics=1000,
         "n_tokens": n_tok, "n_topics": n_topics,
         "prep_sec": prep, "num_workers": mesh.num_workers,
     }
-    # Quality field for the flip gate (VERDICT r3 item 6): sampler/kernel
-    # candidates must show equal chain quality before becoming defaults.
+    # Quality field: a sampler or kernel arm must show equal chain
+    # quality before it may become a default.
     # Host-side (numpy over all tokens + the full Ndk pull), so skipped at
     # ladder scale — 100M tokens would add minutes of host time and a
-    # multi-GB device→host pull to a timing run; the candidate configs that need
-    # the gate all run at the 10M-token default shape.
+    # multi-GB device→host pull to a timing run; the arms that need
+    # it all run at the 10M-token default shape.
     if n_tok <= 20_000_000:
         out["log_likelihood"] = model.log_likelihood()
     if algo == "pushpull":

@@ -71,7 +71,7 @@ class MFSGDConfig:
     # "pallas" fuses the dense entry update into one VMEM-resident kernel
     # (ops/mfsgd_kernel.py) — same data layout and update order as "dense",
     # minus the HBM round trips between XLA fusions; needs 128-multiple
-    # tiles and rank % 8 == 0 on TPU.  FLIPPED to "pallas" 2026-08-01
+    # tiles and rank % 8 == 0 on TPU.  Default since 2026-08-01
     # (1× v5e, FLIP_DECISIONS.jsonl): 246.5M ups/s/chip at the swept
     # 256×256 auto-tiles vs 83.1M dense = 2.97× at identical rmse_final
     # (0.366, silicon-equivalence-gated; 188.1M = 2.26× pre-sweep at
@@ -89,7 +89,7 @@ class MFSGDConfig:
     # None = auto, resolved at READ time by :func:`tiles` — not baked in
     # at construction, so ``dataclasses.replace(cfg, algo=...)`` keeps
     # the auto default tracking the new algo instead of freezing the
-    # old algo's resolved value (review finding, round 5).
+    # old algo's resolved value.
     u_tile: int | None = None
     i_tile: int | None = None
     # max ratings per dense entry (one snapshot, one apply: the minibatch);
@@ -114,8 +114,8 @@ class MFSGDConfig:
     # per entry).  The pallas kernel already keeps W resident across its
     # block runs, so this applies to the XLA path alone.  MEASURED
     # 2026-08-01 (1× v5e): 1.01× vs dense — no win (the analytic 20%
-    # byte saving is hidden behind other traffic) — and the kernel flip
-    # supersedes the slot anyway; stays OFF.
+    # byte saving is hidden behind other traffic) — and the kernel
+    # default supersedes it anyway; stays OFF.
     carry_w: bool = False
     # Rotation pipeline knobs (the chunked double-buffered rotator,
     # parallel/rotate.py).  rotate_chunks: H sub-slices per worker that
@@ -123,9 +123,9 @@ class MFSGDConfig:
     # two-halves schedule; the generic pipeline at 2 chunks is
     # equivalence-pinned against it by tests/test_rotate_chunked.py).
     # More chunks shrink each ring transfer and expose finer overlap at
-    # the cost of more scan steps — flip candidate `mfsgd_chunked_rotate`
-    # measures 4 on the chip; default stays 2 until flip_decision says
-    # FLIP.  None = auto, resolved at READ time by
+    # the cost of more scan steps — 4 chunks are not measured on a chip
+    # yet, and the default stays 2 until they win there at equal rmse.
+    # None = auto, resolved at READ time by
     # :func:`rotate_chunks_resolved` (same contract as :func:`tiles`).
     rotate_chunks: int | None = None
     # Ring payload for the in-flight chunk: "exact" (default — bit-exact

@@ -726,9 +726,9 @@ def benchmark(n=60_000, batch=8192, steps=50, mesh=None, cfg=None, warmup=5):
         "steps_per_sec": usable * epochs / batch / dt_res,
         "loss": hist[-1][0],
         "acc": hist[-1][1],
-        # the quantized-gradient-wire flip gate's quality field (PR 8:
-        # mlp_grad_bf16/int8 candidates in measure_all + flip_decision —
-        # a degraded wire must refuse on train_acc, not win on speed)
+        # the quantized gradient wire's quality field (PR 8: a bf16 or
+        # int8 wire is judged on train_acc before speed — a degraded
+        # wire must not win on speed)
         "train_acc": hist[-1][1],
         "grad_wire": cfg.grad_wire,
         "batch": batch,

@@ -40,9 +40,8 @@ class SVMConfig:
     # exchange rides collective.reshard blocked(0)→replicated, so
     # "bf16"/"int8" halve/quarter the [nw*k, d] SV rows per round at
     # ONE rounding per exchange (labels/masks ride exact — reshard
-    # narrows float leaves only).  Flip candidates svm_sv_bf16/_int8
-    # gate on train_acc (flip_decision.py); default stays exact until
-    # a chip run measures them.
+    # narrows float leaves only).  The narrow wires are judged on
+    # train_acc; default stays exact until a chip run measures them.
     sv_wire: str = "exact"
     # dtype the [n, d] feature matrix is STAGED in (PR 16: the profile
     # pass found the committed svm_cli wall (2026-08-01) bound by that

@@ -46,9 +46,9 @@ class MDSConfig:
     # the [N, dim] payload per iteration at one rounding per hop.
     # UNWEIGHTED path only: the weighted CG solve applies V through its
     # exchanges, and a quantized operator inside CG breaks the residual
-    # recurrence — that path stays exact by design.  Flip candidates
-    # wdamds_coord_bf16/_int8 gate on final_stress (flip_decision.py);
-    # default stays exact until a chip run measures them.
+    # recurrence — that path stays exact by design.  The narrow wires
+    # are judged on final_stress; default stays exact until a chip run
+    # measures them.
     coord_wire: str = "exact"
     # dtype the n² dissimilarity matrix is STAGED in (PR 16: the profile
     # pass found the committed wdamds_cli wall (2026-08-01) bound by

@@ -4,7 +4,7 @@ An offline cost model over the landed evidence planes — CommGraph byte
 sheets (PR 9), topology link rates (PR 11), roofline work models,
 kernel-registry shapes, and calibrated flight-recorder deltas — that
 prices every config, grades itself against the committed bench rows,
-and prunes measurement runs (``measure_all.py --predicted-top``).  See
+and ranks candidates (``python -m harp_tpu predict --top``).  See
 :mod:`harp_tpu.perfmodel.model` for the model and its additive-roofline
 rationale, :mod:`harp_tpu.perfmodel.grade` for the self-grading
 contract (``grade.grade()`` — the function keeps its module's name, so

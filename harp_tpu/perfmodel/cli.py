@@ -9,9 +9,8 @@ exactly like the lint and plan CLIs):
   compute/memory/wire/overhead breakdown at the graded shape) —
   ``scripts/check_jsonl.py`` invariant 12 validates every row.
 - ``--top N``: the flip-candidate ranking (predicted speedup over each
-  candidate's incumbent) that ``measure_all.py --predicted-top`` maps
-  onto ``--only``; unpriceable candidates are listed loudly, never
-  silently dropped.
+  candidate's incumbent); unpriceable candidates are listed loudly,
+  never silently dropped.
 - ``--grade``: replay the model against ALL committed BENCH_local /
   FLIP_DECISIONS / SWEEP_pallas evidence it can price; exit 1 with the
   term breakdowns on any disagreement (the honesty gate — see

@@ -44,7 +44,7 @@ import os
 from harp_tpu.perfmodel import model as M
 
 #: |measured speedup - 1| at or below this is "the evidence calls it a
-#: tie" — the same 10% margin the flip rule itself uses.
+#: tie" — the 10% a default change had to buy (FLIP_DECISIONS.jsonl).
 DEAD_BAND = 0.10
 
 #: predicted rate must land within this factor of the measured rate.
@@ -53,10 +53,9 @@ MAGNITUDE_TOL = 50.0
 #: minimum Spearman rho per committed sweep.
 RANK_FLOOR = 0.9
 
-#: candidate -> (incumbent, metric, metric_fallback|None): the subset of
-#: scripts/flip_decision.py's CANDIDATES the model can price
-#: (tests/test_perfmodel.py pins each entry against that table — the
-#: two must never tell different stories about who competes with whom).
+#: candidate -> (incumbent, metric, metric_fallback|None): who competes
+#: with whom, and on which metric, for every pair the model can price
+#: (the incumbents are those of FLIP_DECISIONS.jsonl's rows).
 FAMILY_PAIRS = {
     "mfsgd_pallas": ("mfsgd", "updates_per_sec_per_chip", None),
     "mfsgd_carry": ("mfsgd", "updates_per_sec_per_chip", None),
@@ -124,9 +123,9 @@ SWEEPS = {
 
 
 def latest_tpu_rows(path: str) -> dict:
-    """config -> last full-shape non-error TPU row (the same filter as
-    flip_decision.latest_rows: CPU-sim speeds are explicitly
-    non-predictive of TPU here and must not grade the model either)."""
+    """config -> last full-shape non-error TPU row (CPU-sim speeds are
+    explicitly non-predictive of TPU here and must not grade the
+    model)."""
     rows: dict = {}
     try:
         with open(path) as f:

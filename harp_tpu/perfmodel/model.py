@@ -92,14 +92,14 @@ RBG_FLOPS_PER_WORD = 4.0
 
 #: per-topic VPU flops of the two samplers (roofline's 10K gumbel
 #: estimate; exprace measured "~5× fewer VPU transcendentals",
-#: measure_all.py comment).
+#: LDAConfig.sampler's comment).
 GUMBEL_VPU_FLOPS_PER_TOPIC = 10.0
 EXPRACE_VPU_FLOPS_PER_TOPIC = 2.0
 
 #: HBM round trips of the XLA [n, k] intermediates the dense kmeans
 #: formulation materializes per iteration (score write/read, one-hot
 #: write, two matmul operand reads) — "the XLA int8 path's wall is the
-#: ~2 GB/iter [n, k] intermediates" (measure_all.py; at the graded
+#: ~2 GB/iter [n, k] intermediates" (at the graded
 #: 1M×100 shape 5 × 4nk = 2.0 GB exactly).  The fused Pallas kernels
 #: never write them (single HBM pass, ops/kmeans_kernel.py).
 KMEANS_XLA_NK_PASSES = 5
@@ -620,8 +620,8 @@ CONFIG_MODELS = {
     "rf": _r(),
     "rf_dense_hist": _r(),                    # the hist_algo A/B, dense arm
     "rf_scatter_hist": _r(hist="scatter"),
-    # PR 17: the kernelized arms (presize-predicted, unmeasured — flip
-    # candidates in SPRINT_ORDER; silicon verdicts pending)
+    # PR 17: the kernelized arms (presize-predicted, unmeasured on a
+    # chip)
     "rf_hist_pallas": _r(hist="pallas"),
     "svm": _s(),
     "svm_sv_bf16": _s(wire="bf16"),
@@ -648,11 +648,11 @@ CONFIG_MODELS = {
 }
 
 #: committed BENCH_local rows whose config name is a CLI metrics tag,
-#: not a sprint config (svm_cli/wdamds_cli landed 2026-08-01 via the
-#: app CLIs) — the magnitude band grades them through the incumbent's
-#: model.  CONFIG_MODELS itself stays ⊆ measure_all.SPRINT_ORDER
-#: (tests/test_perfmodel.py): a predict row must never name a config
-#: the sprint cannot run.
+#: not a config of the price list (svm_cli/wdamds_cli landed 2026-08-01
+#: via the app CLIs) — the magnitude band grades them through the
+#: incumbent's model.  CONFIG_MODELS itself stays ⊆
+#: check_jsonl.KNOWN_MODEL_CONFIGS (tests/test_perfmodel.py): a predict
+#: row must never name a config the checker refuses.
 CLI_ROW_ALIASES = {"svm_cli": "svm", "wdamds_cli": "wdamds"}
 
 _FAMILY_FNS = {"kmeans": _price_kmeans, "mfsgd": _price_mfsgd,
@@ -662,7 +662,7 @@ _FAMILY_FNS = {"kmeans": _price_kmeans, "mfsgd": _price_mfsgd,
                "serve": _price_serve}
 
 #: full-shape overrides for configs whose graded shape differs from the
-#: family benchmark defaults (mirrors measure_all.py's full kwargs);
+#: family benchmark defaults (the shapes BENCH_local.jsonl's rows ran);
 #: everything else prices at the family defaults baked into the
 #: ``_price_*`` row.get defaults.
 FULL_SHAPES = {
@@ -710,9 +710,9 @@ def price(config: str, row: dict | None = None, topo=None) -> Price:
 # kind:"model" rows
 # ---------------------------------------------------------------------------
 
-#: byte-sheet program -> the SPRINT_ORDER configs that execute it
-#: (tests pin every value against measure_all.SPRINT_ORDER — invariant
-#: 12 refuses a model row referencing a config the sprint cannot run).
+#: byte-sheet program -> the configs that execute it (tests pin every
+#: value against check_jsonl.KNOWN_MODEL_CONFIGS — invariant 12 refuses
+#: a model row referencing a config outside that list).
 PROGRAM_CONFIGS = {
     "kmeans.fit": ("kmeans", "kmeans_int8", "kmeans_int8_fused"),
     "kmeans.fit_hier": ("kmeans_hier_psum",),
@@ -781,12 +781,12 @@ def model_row(p: Price, topo, *, program: str | None = None,
 
 
 # ---------------------------------------------------------------------------
-# Candidate ranking (the sprint-pruning input)
+# Candidate ranking (``predict --top``)
 # ---------------------------------------------------------------------------
 
 def rank_candidates(pairs: dict, topo, rows: dict | None = None) -> dict:
     """Predicted speedup per flip candidate: ``pairs`` maps candidate →
-    incumbent config (the flip_decision CANDIDATES surface); returns
+    incumbent config (``grade.FAMILY_PAIRS``); returns
     {candidate: speedup} for every pair the model can price, pricing
     both sides at the SAME shape (the incumbent's committed row when
     ``rows`` has one, else the graded full shape).  Unpriceable

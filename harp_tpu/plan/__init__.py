@@ -6,7 +6,7 @@ collective sites per link class, and the planner
 (:mod:`harp_tpu.plan.planner`) emits an explicit, serializable
 :class:`~harp_tpu.plan.planner.Plan` whose every choice FAILS CLOSED —
 the chosen schedule is today's exact lowering, and cheaper-priced
-alternatives name their ``measure_all.py`` flip candidate instead of
+alternatives name their flip candidate (a config name) instead of
 flipping anything themselves.  ``python -m harp_tpu plan`` is the front
 door; ``scripts/check_jsonl.py`` invariant 10 validates the rows.
 """

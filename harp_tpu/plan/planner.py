@@ -21,9 +21,8 @@ alternatives the codebase can actually execute —
 — and emits a serializable :class:`Plan`.  **Every choice fails
 closed**: the chosen ``schedule`` is always ``"keep"`` (bit-identical
 to today's lowering); a cheaper-priced alternative only *names its
-flip candidate* (the ``measure_all.py`` config that measures it), per
-the repo's rule that no default changes without a chip-measured
-``flip_decision`` verdict.  ``Plan.row()`` is the ``kind: "plan"``
+flip candidate* (the name of the config that would measure it), per
+the repo's rule that no default changes without a chip measurement.  ``Plan.row()`` is the ``kind: "plan"``
 JSONL record ``scripts/check_jsonl.py`` invariant 10 validates —
 provenance-stamped, topology tag and schedules from frozen
 vocabularies, and per-site predicted bytes equal to the program's byte
@@ -76,7 +75,7 @@ _VERB_ALTERNATIVES = {
     "barrier": (),
 }
 
-#: (program, verb, schedule) → the measure_all.py config that measures
+#: (program, verb, schedule) → the name of the config that measures
 #: the alternative on silicon.  Only mapped sites can ever carry a
 #: flip_candidate — an alternative with no measurement path stays a
 #: priced row, never a recommendation (fail closed all the way down).
@@ -116,7 +115,7 @@ class SiteDecision:
     predicted_bytes: int = 0
     cost_s: float = 0.0     # topology price of the chosen schedule
     alternatives: dict = dataclasses.field(default_factory=dict)
-    #: schedule -> measure_all config, one entry per alternative that
+    #: schedule -> config name, one entry per alternative that
     #: both prices under the margin AND has a measurement path
     candidates: dict = dataclasses.field(default_factory=dict)
     flip_candidate: str | None = None   # the cheapest of `candidates`
@@ -188,7 +187,7 @@ def decide_site(program: str, entry: dict, topo: Topology) -> SiteDecision:
     alternatives only attach their flip candidate, and only when
     a) the verb can legally lower to them, b) the site's wire is still
     exact (a quantized site already took its trade), and c) a
-    measure_all config exists to measure them.
+    config is named that would measure them.
     """
     sheet_bytes = int(entry["per_shard_bytes"]) * max(
         int(entry.get("amplification") or 1), 1)

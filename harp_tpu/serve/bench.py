@@ -1,9 +1,8 @@
 """Serving benchmark — qps + latency percentiles as ``kind:"serve"`` rows.
 
 Self-contained (synthetic state + synthetic requests), so it runs
-without a checkpoint on disk — the ``serve_kmeans`` /
-``serve_mfsgd_topk`` configs in scripts/measure_all.py and the
-``python -m harp_tpu serve <app> --bench`` CLI both route here.  The
+without a checkpoint on disk — the
+``python -m harp_tpu serve <app> --bench`` CLI routes here.  The
 emitted row is validated by scripts/check_jsonl.py invariant 7: latency
 percentiles monotone (p50 ≤ p95 ≤ p99), qps > 0, and — the serving
 loop's whole point — ``steady_compiles == 0`` (the CompileWatch delta
@@ -89,8 +88,8 @@ def benchmark(app: str = "kmeans", n_requests: int = 256,
     srv = Server(app, state=state, mesh=mesh, ladder=ladder,
                  cache_dir=cache_dir, budget_action="warn",
                  engine_opts=engine_opts)
-    # telemetry ON (without resetting ambient collectors: bench.py /
-    # measure_all deltas over the same counters must stay monotone)
+    # telemetry ON (without resetting ambient collectors: a caller's
+    # deltas over the same counters must stay monotone)
     # so CompileWatch evidence backs the steady_compiles claim
     with telemetry.scope(True, reset=False):
         t0 = time.perf_counter()

@@ -28,8 +28,7 @@ Keys bind the artifact to everything that could invalidate it:
   engine's model module — a changed step function must miss, never
   silently serve stale code).
 
-Entries are atomic-rename pickle files (the _save_pack discipline from
-models/lda.py: the sprint environment routinely kills processes
+Entries are atomic-rename pickle files (a process can be killed
 mid-write, and a truncated entry must never poison later restarts).
 A corrupt or stale entry falls back to a fresh compile — the cache can
 lose, never lie.

@@ -432,8 +432,7 @@ _BUDGET_KEYS = ("compiles", "compile_s", "h2d_bytes", "h2d_calls",
 
 
 def snapshot() -> dict:
-    """Current cumulative counters (the budget guard's baseline; bench.py
-    uses deltas between snapshots for its per-config flight block)."""
+    """Current cumulative counters (the budget guard's baseline)."""
     return {"compiles": compile_watch.count,
             "compile_s": round(compile_watch.total_s, 6),
             "h2d_bytes": transfers.h2d_bytes,

@@ -57,8 +57,8 @@ _PROVENANCE: dict | None = None
 def _provenance() -> dict:
     """backend/date/jax/commit stamp, computed once per process.
 
-    Round 5 (review finding): `flip_decision.latest_rows` excludes
-    CPU-sim evidence via ``backend == "cpu"`` —
+    The readers of BENCH_local.jsonl (``perfmodel.grade.latest_tpu_rows``)
+    exclude CPU-sim evidence via ``backend == "cpu"`` —
     a config-keyed CLI row WITHOUT the field (e.g. the teed
     `kmeans_stream_cli` 1B record) would pass as TPU evidence, exactly
     the CPU-inversion failure those filters exist for.  Stamping here
@@ -98,7 +98,7 @@ def benchmark_json(config: str, result: dict) -> str:
     output gets teed into BENCH_local.jsonl, and a Python dict repr
     there is an unparseable line every JSONL reader must skip.
     numpy scalars coerce to plain Python so json never chokes.  Rows
-    carry the same provenance fields measure_all stamps (round 5), so
+    carry the provenance fields (backend, date, commit), so
     downstream TPU-evidence filters can classify them.
     """
     def _plain(v: Any):

@@ -78,8 +78,8 @@ def ensure_dataset(fmt: str, rows: int, cols: int, disk_dtype: str,
     """Generate (or reuse) the benchmark file → (path, generated_now).
 
     ``generated_now`` lets run() clean up only files THIS invocation
-    created — a cached file another run kept (bench.py/measure_all's
-    reusable 12 GB dataset) must survive a no-``--keep`` run that merely
+    created — a cached file another run kept (a reusable 12 GB
+    dataset) must survive a no-``--keep`` run that merely
     reused it."""
     name = (f"pts_{rows}x{cols}_{disk_dtype}.npy" if fmt == "npy"
             else f"pts_{rows}x{cols}.csv")
@@ -201,22 +201,9 @@ def run_ab(fmt="npy", rows=200_000, cols=64, disk_dtype="float32",
 
 
 def run_smoke(quantize=None) -> dict:
-    """The ONE smoke preset shared by bench.py and measure_all — tiny
-    npy, CPU-safe, regenerated per run."""
+    """The smoke preset — tiny npy, CPU-safe, regenerated per run."""
     return run("npy", 20_000, 32, "float32", k=16, iters=2,
                chunk_points=4096, verbose=False, quantize=quantize)
-
-
-def run_full(compare_synthetic: bool = False, quantize=None) -> dict:
-    """The ONE full preset shared by bench.py and measure_all: 20M×300
-    float16 (12 GB), kept in .bench_data/ for reuse across runs.
-    ``compare_synthetic`` adds the device-regenerated compute twin (a
-    second full-scale compile + timed run) — measure_all opts in; the
-    driver's bench.py skips it to stay well inside its per-config
-    watchdog."""
-    return run("npy", 20_000_000, 300, "float16", k=1000, iters=2,
-               chunk_points=262_144, keep=True,
-               compare_synthetic=compare_synthetic, quantize=quantize)
 
 
 def main(argv=None):

@@ -12,11 +12,11 @@ runnable standalone (``python scripts/check_jsonl.py [--repo DIR]``):
    ``commit`` — the fields :func:`harp_tpu.utils.metrics._provenance`
    writes).  This is the CPU-inversion guard from metrics.py: a
    config-keyed row WITHOUT ``backend`` can pass downstream TPU-evidence
-   filters (``flip_decision.latest_rows`` excludes only
+   filters (``perfmodel.grade.latest_tpu_rows`` excludes only
    ``backend == "cpu"``), so an unstamped CPU record reads
    as silicon evidence.  Rows committed before the stamp existed are
-   grandfathered BY LINE INDEX (the history is append-only; reannotate.py
-   rewrites rows in place), so every row appended after this check landed
+   grandfathered BY LINE INDEX (the history is append-only), so every
+   row appended after this check landed
    must comply — "my row has no date, so I look legacy" is not a loophole.
 
 PROFILE_local.jsonl and FLIP_DECISIONS.jsonl rows are trace/decision rows,
@@ -144,9 +144,8 @@ not bench evidence: they get the parse check only — plus invariants 3/4:
     :mod:`harp_tpu.perfmodel`) must carry the provenance stamp (a
     prediction is about a specific commit's byte sheets and work
     models), name a registered program (``KNOWN_LINT_PROGRAMS``)
-    and/or a config from the sprint surface (``KNOWN_MODEL_CONFIGS``
-    — frozen against ``measure_all.SPRINT_ORDER``: a model row
-    referencing a config the sprint cannot run prunes nothing), stamp
+    and/or a config from the frozen list ``KNOWN_MODEL_CONFIGS`` (the
+    config names BENCH_local.jsonl's rows and the price list use), stamp
     ``rates_source`` and ``bound`` from the frozen vocabularies
     (``KNOWN_MODEL_RATES_SOURCES`` / ``KNOWN_MODEL_BOUNDS`` —
     sync-pinned against ``harp_tpu.perfmodel`` by
@@ -168,7 +167,7 @@ not bench evidence: they get the parse check only — plus invariants 3/4:
     counts and non-negative burn/ratio numbers, and — per detector —
     an ``evidence_regression`` row MUST carry a ``verdict`` from
     ``KNOWN_HEALTH_VERDICTS`` (``model_invalidated`` is the one that
-    fails ``measure_all --predicted-top`` closed), while a
+    fails ``health --grade-model`` closed), while a
     ``skew_trigger`` row MUST carry a structurally valid inline
     rebalance plan (``schedule.apply_rebalance``'s input shape:
     ``phase``, ``moves`` with non-negative worker ids and work, numeric
@@ -774,8 +773,8 @@ def _finish_trace_checks(name: str, state: dict,
 
 # the model-row vocabularies (invariant 12), FROZEN standalone like the
 # plan vocabularies and sync-pinned by tests/test_perfmodel.py against
-# harp_tpu.perfmodel (BOUNDS / RATES_SOURCES) and scripts/measure_all.py
-# (SPRINT_ORDER)
+# harp_tpu.perfmodel (BOUNDS / RATES_SOURCES; CONFIG_MODELS and
+# PROGRAM_CONFIGS name configs of this list only)
 KNOWN_MODEL_BOUNDS = ("compute", "memory", "wire", "overhead")
 KNOWN_MODEL_RATES_SOURCES = ("declared", "probed")
 KNOWN_MODEL_CONFIGS = (
@@ -822,8 +821,7 @@ def _check_model_row(name: str, i: int, row: dict) -> list[str]:
         if c not in KNOWN_MODEL_CONFIGS:
             errs.append(
                 f"{name}:{i}: model row references config {c!r} not in "
-                "the sprint surface (KNOWN_MODEL_CONFIGS — update in "
-                "the same commit as measure_all.SPRINT_ORDER)")
+                "the frozen list KNOWN_MODEL_CONFIGS")
     rs = row.get("rates_source")
     if rs not in KNOWN_MODEL_RATES_SOURCES:
         errs.append(f"{name}:{i}: model row rates_source={rs!r} not in "

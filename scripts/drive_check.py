@@ -473,28 +473,8 @@ try:
 except ValueError:
     print("pallas carry_db=False refused (the carry is the kernel's)")
 
-# exact plane gathers: a pallas chain at hot counts (tiny vocab) keeps
-# integer tables and tracks dense likelihood
-import importlib.util as _r4ilu
 import os as _r4os
 
-_r4spec = _r4ilu.spec_from_file_location(
-    "flip_decision", _r4os.path.join(
-        _r4os.path.dirname(_r4os.path.abspath(__file__)),
-        "flip_decision.py"))
-_r4fd = _r4ilu.module_from_spec(_r4spec)
-_r4spec.loader.exec_module(_r4fd)
-_r4v = _r4fd.decide(
-    {"tokens_per_sec_per_chip": 9e6, "log_likelihood": -9.5},
-    {"tokens_per_sec_per_chip": 6e6, "log_likelihood": -9.1},
-    _r4fd.CANDIDATES["lda_pallas"])
-assert not _r4v["flip"] and _r4v["quality_ok"] is False  # degraded → refused
-_r4v2 = _r4fd.decide(
-    {"tokens_per_sec_per_chip": 9e6, "log_likelihood": -9.11},
-    {"tokens_per_sec_per_chip": 6e6, "log_likelihood": -9.1},
-    _r4fd.CANDIDATES["lda_pallas"])
-assert _r4v2["flip"]  # 1.5x at equal quality → flips
-print("flip gate: degraded refused, equal-quality 1.5x flips")
 print("DRIVE OK round-16")
 
 # 22. round 5 (this session): ADVICE r4 fixes through the public surface.
@@ -523,51 +503,6 @@ for _a, _b in zip(_r5out[False], _r5out[True]):
     np.testing.assert_array_equal(np.asarray(_a), np.asarray(_b))
 print("carry_tile_switch exact for overlapping offsets (bit-identical)")
 
-# (b) the flip gate refuses a MIXED metric basis (ex-gen vs end-to-end);
-_r5spec = _r4fd.CANDIDATES["kmeans_stream_int8"]
-_r5v = _r4fd.decide(
-    {"iters_per_sec": 0.9, "iters_per_sec_ex_gen": 2.2, "inertia": 1e10},
-    {"iters_per_sec": 0.53, "inertia": 1e10}, _r5spec)
-assert not _r5v["flip"] and _r5v["speedup"] is None
-assert "mixed" in _r5v["reason"]
-print("flip gate: mixed metric basis refused")
-
-# (c) _save_pack sweeps dead writers' tmp orphans, survives a racing
-# live-pid tmp, and round-trips the pack;
-import subprocess as _r5sp
-import tempfile as _r5tf
-
-from harp_tpu.models.lda import _load_pack as _r5load
-from harp_tpu.models.lda import _save_pack as _r5save
-
-with _r5tf.TemporaryDirectory() as _r5d:
-    _r5p = _r4os.path.join(_r5d, "pack.npz")
-    # a guaranteed-dead pid: a reaped child (999999 could be live under
-    # a large kernel.pid_max)
-    _r5dead = _r5sp.Popen(["true"])
-    _r5dead.wait()
-    open(f"{_r5p}.{_r5dead.pid}.tmp.npz", "w").close()  # dead pid: swept
-    open(_r5p + ".tmp.npz", "w").close()              # legacy name: swept
-    # a LIVE foreign writer (sleeping child): its tmp must survive
-    _r5alive = _r5sp.Popen(["sleep", "30"])
-    _r5live = f"{_r5p}.{_r5alive.pid}.tmp.npz"
-    open(_r5live, "w").close()
-    _r5pack = {"tokens": (np.arange(6, dtype=np.int32),),
-               "z_grid": np.zeros((2, 3), np.int32),
-               "Ndk": np.ones((2, 2), np.int32),
-               "Nwk": np.ones((2, 2), np.int32),
-               "Nk": np.ones((2,), np.int32), "n_tokens": 6}
-    _r5save(_r5p, _r5pack)
-    assert not _r4os.path.exists(f"{_r5p}.{_r5dead.pid}.tmp.npz")
-    assert not _r4os.path.exists(_r5p + ".tmp.npz")
-    assert _r4os.path.exists(_r5live)                 # live writer kept
-    _r5alive.kill()
-    _r5alive.wait()
-    _r5back = _r5load(_r5p)
-    assert _r5back["n_tokens"] == 6
-    np.testing.assert_array_equal(_r5back["tokens"][0], _r5pack["tokens"][0])
-print("_save_pack: dead-writer tmp swept, pack round-trips")
-
 # (d) the mlp fit CLI emits one parseable JSON line (ADVICE r4 #5).
 import contextlib as _r5ctx
 import io as _r5io
@@ -584,28 +519,6 @@ assert any(r.get("config") == "mlp_fit_cli" and "train_acc" in r
            for r in _r5rows)
 print("mlp --train CLI emits parseable mlp_fit_cli JSON")
 print("DRIVE OK round-17")
-
-# 23. round 5 (this session): scaling-evidence CLIs drive end to end.
-# project_scaling emits a complete dated (app x N) grid whose BASELINE.md
-# table derives from it; every row cites a measured rate date and the
-# rotation rows show the double-buffered ring hiding under compute.
-import subprocess as _r5sp2
-
-_r5proj = _r5sp2.run([sys.executable, "scripts/project_scaling.py"],
-                     capture_output=True, text=True, timeout=300,
-                     cwd=_r4os.path.dirname(_r4os.path.dirname(
-                         _r4os.path.abspath(__file__))))
-assert _r5proj.returncode == 0, _r5proj.stderr[-500:]
-_r5rows = [_r5json.loads(ln) for ln in _r5proj.stdout.splitlines()
-           if ln.strip()]
-assert {r["app"] for r in _r5rows} == {
-    "kmeans", "kmeans_stream_1b", "mfsgd", "lda", "mlp", "subgraph", "rf"}
-assert all(0.0 < r["efficiency"] <= 1.0 and r["measured_date"]
-           for r in _r5rows)
-assert all(r["efficiency"] == 1.0 for r in _r5rows
-           if r["pattern"] == "rotate")
-print(f"project_scaling: {len(_r5rows)}-row grid, rotation comm hidden")
-print("DRIVE OK round-18")
 
 # 24. round 5 session 2: the two-word prng_seed invariant.  The real TPU
 # compiler rejects pltpu.prng_seed with >2 seed words ("Setting seed
@@ -850,17 +763,6 @@ def _p2rot_bytes(wire):
 assert _p2rot_bytes("exact") == 4 * _p2rot_bytes("int8") > 0
 print("lda rotate_chunks=4 invariants; ledger: int8 rotate = 1/4 f32 bytes")
 
-# (e) the new flip candidates fail closed without rows and flip at
-# equal quality >= 1.10x
-for _p2name in ("mfsgd_chunked_rotate", "lda_rotate_int8"):
-    _p2spec = _r4fd.CANDIDATES[_p2name]
-    assert not _r4fd.decide(None, None, _p2spec)["flip"]
-_p2v = _r4fd.decide(
-    {"updates_per_sec_per_chip": 12e6, "rmse_final": 0.366},
-    {"updates_per_sec_per_chip": 10e6, "rmse_final": 0.366},
-    _r4fd.CANDIDATES["mfsgd_chunked_rotate"])
-assert _p2v["flip"]
-print("flip gate: chunked-rotate candidates fail closed / flip at 1.2x")
 print("DRIVE OK round-21")
 
 # --- round 22: execution flight recorder ----------------------------------
@@ -1962,14 +1864,12 @@ print("svm/wdamds wires: exact arm trains/embeds, bf16 within bounds, "
 print("DRIVE OK round-32")
 
 # --- round 33: the predictive performance observatory (PR 13) --------------
-# Byte sheets -> model rows -> --predicted-top --only list ->
-# flip_decision gates respected, end-to-end through the CLI subprocess,
+# Byte sheets -> model rows, end-to-end through the CLI subprocess,
 # CPU-only: (a) the predict CLI prices every byte-sheeted program AND
 # every modeled config as invariant-12-clean rows; (b) self-grading
-# against the committed evidence exits 0; (c) measure_all's pruned
-# selection is gate-closed and flip_decision accepts it without a
-# bypassed gate; (d) the shared wire oracle prices the planner's sites
-# identically; (e) the pre-sizer reproduces the OOM-calibrated tiles.
+# against the committed evidence exits 0; (d) the shared wire oracle
+# prices the planner's sites identically; (e) the pre-sizer reproduces
+# the OOM-calibrated tiles.
 import json as _pm_json
 import subprocess as _pm_sp
 import tempfile as _pm_tmp
@@ -2016,34 +1916,6 @@ assert _pm_grow["ok"] is True
 assert sum(1 for e in _pm_grow["pairs"]
            if e["status"] == "agrees") >= 5
 
-# (c) pruning through the CLI subprocess: the --predicted-top list is
-# gate-closed, and flip_decision evaluates it without a bypassed gate
-# (exit 0/1 only — 2 would be an argparse rejection of the list)
-_pm_ma = _pm_sp.run(
-    [sys.executable, os.path.join(_pm_root, "scripts", "measure_all.py"),
-     "--predicted-top", "3", "--dry-run", "--topology", "v4_32"],
-    capture_output=True, text=True, timeout=300, env=_pm_env,
-    cwd=_pm_root)
-assert _pm_ma.returncode == 0, _pm_ma.stderr[-800:]
-_pm_sel = _pm_json.loads(_pm_ma.stdout.strip().splitlines()[-1])
-_pm_meta = _pm_json.loads(_pm_ma.stderr.strip().splitlines()[-1])
-assert _pm_sel["would_run"] == _pm_meta["only"]
-import flip_decision as _pm_fd
-for _pm_group in _pm_fd.JOINT_GATES + _pm_fd.EXCLUSIVE_GATES:
-    if set(_pm_sel["would_run"]) & set(_pm_group):
-        assert set(_pm_group) <= set(_pm_sel["would_run"]), _pm_group
-_pm_fd_rc = _pm_sp.run(
-    [sys.executable, os.path.join(_pm_root, "scripts",
-                                  "flip_decision.py"),
-     "--only"] + [c for c in _pm_sel["would_run"]
-                  if c in _pm_fd.CANDIDATES],
-    capture_output=True, text=True, timeout=300, env=_pm_env,
-    cwd=_pm_root)
-assert _pm_fd_rc.returncode in (0, 1), _pm_fd_rc.stderr[-500:]
-for _pm_ln in _pm_fd_rc.stdout.strip().splitlines():
-    _pm_v = _pm_json.loads(_pm_ln)
-    assert "flip" in _pm_v  # every selected candidate got a verdict row
-
 # (d) one wire oracle: planner site costs == model wire term, and the
 # Plan rows still fail closed after the re-point
 from harp_tpu.plan import planner as _pm_plan
@@ -2078,8 +1950,7 @@ finally:
 
 print(f"perfmodel: {len(_pm_rows)} model rows invariant-12-clean, "
       f"grade OK ({sum(1 for e in _pm_grow['pairs'] if e['status'] == 'agrees')}"
-      f" agreements), predicted-top {_pm_sel['would_run']} gate-closed, "
-      "wire oracle shared, pre-sizer == hand-calibrated tiles")
+      " agreements), wire oracle shared, pre-sizer == hand-calibrated tiles")
 print("DRIVE OK round-33")
 
 # --- round 34: the health sentinel (PR 14) ---------------------------------
@@ -2194,21 +2065,10 @@ _hl_row = _hl_json.loads(_hl_gm.stdout.strip().splitlines()[-1])
 assert _hl_row["verdict"] == "confirmed"
 assert _hl_cj._check_health_row("t", 1, _hl_row) == []
 
-# (d) the gate is OPEN at HEAD: pruning still selects (round 33 already
-# proved the selection machinery; this proves PR 14 did not close it)
-_hl_ma = _hl_sp.run(
-    [sys.executable, os.path.join(_hl_root, "scripts", "measure_all.py"),
-     "--predicted-top", "2", "--dry-run"],
-    capture_output=True, text=True, timeout=600, env=_hl_env,
-    cwd=_hl_root)
-assert _hl_ma.returncode == 0, _hl_ma.stderr[-800:]
-assert _hl_json.loads(_hl_ma.stdout.strip().splitlines()[-1])["would_run"]
-
 print(f"health: chaos run {_hl_res['served_requests']}/"
       f"{_hl_res['shed_requests']}/{_hl_res['failed_requests']} "
       "reconciled across ledger+trace+sentinel, control clean, "
-      f"skew plan applied (loads {_hl_loads}), grade-model confirmed, "
-      "pruning gate open")
+      f"skew plan applied (loads {_hl_loads}), grade-model confirmed")
 print("DRIVE OK round-34")
 
 # ---------------------------------------------------------------------------
@@ -2446,106 +2306,11 @@ print(f"profile: {len(_pf_expect)} span labels classified, attribute() "
 print("DRIVE OK round-36")
 
 # --------------------------------------------------------------- round 37
-# PR 17: the kernelized half — drive all three Pallas arms end to end.
-# (a) CLI knob -> bench row: the three flip candidates run through the
-#     REAL measurement harness (scripts/measure_all.py --smoke on the
-#     forced-CPU 8-device sim) and emit non-error rows with a finite
-#     metric + quality field and the pallas knob recorded on the row;
-# (b) the gates fail closed IN CODE: a forged 2x-faster-but-degraded
-#     candidate is refused with the QUALITY DEGRADED reason (never the
-#     literal "FLIP:" marker an operator greps for), and a winning
-#     rf_hist_pallas whose anchor chain is incomplete (rf_dense_hist
-#     measured but ITS incumbent rf_scatter_hist missing) exits 1 with
-#     the conditional-gate UNMEASURED veto;
-# (c) attribution re-capture: the rf/svm/wdamds profile rows still
-#     reconcile (dispatch count, zero in-window compiles, CommLedger
-#     match) with the new kernels registered.
-import contextlib as _k17_ctx
-import io as _k17_io
-import json as _k17_json
-import subprocess as _k17_sp
-import tempfile as _k17_tf
-
-import flip_decision as _k17_fd
+# PR 17: the kernelized half.  Attribution re-capture: the rf/svm/wdamds
+# profile rows still reconcile (dispatch count, zero in-window compiles,
+# CommLedger match) with the new kernels registered.
 from harp_tpu.profile import attribution as _k17_attr
 
-_k17_root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-_k17_cands = ("svm_kernel_pallas", "wdamds_dist_pallas", "rf_hist_pallas")
-
-# (a) the measurement harness itself, in a subprocess (fresh jax with 8
-# forced host devices — the parent's backend choice must not leak in)
-_k17_env = dict(os.environ)
-_k17_env["XLA_FLAGS"] = (_k17_env.get("XLA_FLAGS", "")
-                         + " --xla_force_host_platform_device_count=8")
-_k17_proc = _k17_sp.run(
-    [sys.executable, os.path.join(_k17_root, "scripts", "measure_all.py"),
-     "--smoke", "--only", *_k17_cands],
-    env=_k17_env, capture_output=True, text=True, timeout=1800)
-assert _k17_proc.returncode == 0, _k17_proc.stderr[-2000:]
-_k17_rows = {}
-for _k17_line in _k17_proc.stdout.splitlines():
-    _k17_line = _k17_line.strip()
-    if not _k17_line.startswith("{"):
-        continue
-    try:
-        _k17_row = _k17_json.loads(_k17_line)
-    except ValueError:
-        continue
-    if _k17_row.get("config") in _k17_cands:
-        _k17_rows[_k17_row["config"]] = _k17_row
-assert set(_k17_rows) == set(_k17_cands), sorted(_k17_rows)
-for _k17_name, _k17_metric, _k17_qual in (
-        ("svm_kernel_pallas", "samples_per_sec", "train_acc"),
-        ("wdamds_dist_pallas", "iters_per_sec", "final_stress"),
-        ("rf_hist_pallas", "trees_per_sec", "train_acc")):
-    _k17_row = _k17_rows[_k17_name]
-    assert "error" not in _k17_row, _k17_row
-    assert _k17_row.get(_k17_metric, 0) > 0 and np.isfinite(
-        _k17_row[_k17_metric]), _k17_row
-    assert np.isfinite(_k17_row[_k17_qual]), _k17_row
-assert _k17_rows["svm_kernel_pallas"]["algo"] == "pallas"
-assert _k17_rows["wdamds_dist_pallas"]["algo"] == "pallas"
-assert _k17_rows["rf_hist_pallas"]["hist_algo"] == "pallas"
-
-# (b1) quality gate: 2x speed never outruns a degraded quality field
-_k17_spec = _k17_fd.CANDIDATES["rf_hist_pallas"]
-_k17_bad = _k17_fd.decide(
-    {"config": "rf_hist_pallas", "trees_per_sec": 200.0, "train_acc": 0.80},
-    {"config": "rf_dense_hist", "trees_per_sec": 100.0, "train_acc": 0.99},
-    _k17_spec)
-assert _k17_bad["flip"] is False and _k17_bad["quality_ok"] is False
-assert "QUALITY DEGRADED" in _k17_bad["reason"], _k17_bad
-assert "FLIP:" not in _k17_bad["reason"], _k17_bad
-
-# (b2) conditional gate: a winning pallas row with rf_dense_hist
-# measured but the anchor's OWN incumbent (rf_scatter_hist) missing is
-# not a verdict — main() must veto AND signal exit 1 (rerun the benches)
-with _k17_tf.NamedTemporaryFile(
-        "w", suffix=".jsonl", delete=False) as _k17_f:
-    for _k17_forged in (
-            {"config": "rf_hist_pallas", "backend": "tpu",
-             "trees_per_sec": 200.0, "train_acc": 0.99},
-            {"config": "rf_dense_hist", "backend": "tpu",
-             "trees_per_sec": 100.0, "train_acc": 0.99}):
-        _k17_f.write(_k17_json.dumps(_k17_forged) + "\n")
-    _k17_bench = _k17_f.name
-_k17_out = _k17_io.StringIO()
-with _k17_ctx.redirect_stdout(_k17_out):
-    _k17_rc = _k17_fd.main(
-        ["--bench", _k17_bench, "--only", "rf_hist_pallas"])
-os.unlink(_k17_bench)
-assert _k17_rc == 1, _k17_out.getvalue()
-_k17_verdicts = [_k17_json.loads(ln)
-                 for ln in _k17_out.getvalue().splitlines() if ln.strip()]
-assert len(_k17_verdicts) == 1, _k17_verdicts
-_k17_v = _k17_verdicts[0]
-assert _k17_v["flip_decision"] == "rf_hist_pallas"
-assert _k17_v["flip"] is False
-assert "VETOED by conditional gate" in _k17_v["reason"], _k17_v
-assert "UNMEASURED" in _k17_v["reason"], _k17_v
-assert "FLIP:" not in _k17_v["reason"], _k17_v
-
-# (c) the newly priced apps still reconcile with the kernels registered
 for _k17_app in ("rf", "svm", "wdamds"):
     _k17_prow = _k17_attr.capture(_k17_app, reps=2)
     assert _k17_prow["reconciled"] is True, (
@@ -2553,11 +2318,8 @@ for _k17_app in ("rf", "svm", "wdamds"):
     _k17_errs = _pf_cj._check_profile_row("drive", 0, _k17_prow)
     assert _k17_errs == [], (_k17_app, _k17_errs)
 
-print("kernels: 3 pallas flip candidates measured through the real "
-      "harness (svm_kernel_pallas/wdamds_dist_pallas/rf_hist_pallas, "
-      "finite metric+quality, knob on the row), quality veto says "
-      "QUALITY DEGRADED not FLIP:, conditional gate exits 1 on the "
-      "unmeasured anchor chain, rf/svm/wdamds captures reconciled")
+print("kernels: rf/svm/wdamds captures reconciled with the kernels "
+      "registered")
 print("DRIVE OK round-37")
 
 # ---------------------------------------------------------------------------

@@ -206,10 +206,9 @@ def main():
     ref = hot_lls[("dense", None)]
     assert abs(hot_lls[("pallas", True)] - ref) / abs(ref) < 0.25, hot_lls
     # the approx variant gets only a GARBAGE bound (2x the exact
-    # tolerance): its fine-grained quality question is exactly what the
-    # sprint's LL A/B measures and flip_decision judges — but a gather
-    # path that zeroes (not rounds) the high plane must not burn the
-    # window recording junk rows
+    # tolerance): its fine-grained quality question is a likelihood A/B's
+    # to answer — but a gather path that zeroes (not rounds) the high
+    # plane must be caught here
     assert abs(hot_lls[("pallas", False)] - ref) / abs(ref) < 0.5, hot_lls
     print(f"lda pallas hot-count (>256) exact gathers == dense ({hot_lls})")
 

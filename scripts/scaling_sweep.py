@@ -9,9 +9,9 @@ from an XLA trace (`utils.profiling.op_breakdown` self-times, classified
 by op name).  One JSON row per (app, mode, n_workers) → SCALING_local.jsonl.
 Each row also carries per-worker SKEW columns (skew_work / skew_max_mean /
 skew_wasted_frac, from the utils/skew.py ledger the instrumented drivers
-feed during the telemetry-enabled warmup run), so
-`scripts/project_scaling.py` can attribute efficiency loss to load
-imbalance separately from collective overhead.
+feed during the telemetry-enabled warmup run), so a reader can
+attribute efficiency loss to load imbalance separately from collective
+overhead.
 
 The device count is baked into XLA at backend init, so the parent spawns
 one child subprocess per worker count (`--child`), each with its own
@@ -23,9 +23,7 @@ rates are non-predictive of TPU (BASELINE.md's onehot 7.8× CPU
 inversion).  What transfers is (a) the SHAPE of the weak/strong curves —
 how collective overhead grows with worker count under a fixed-bandwidth
 memory system — and (b) the measured collective-op share, which bounds
-the comm-byte models `scripts/project_scaling.py` feeds with measured
-TPU compute rates + ICI bandwidth to produce the v4-32 projection
-(BASELINE.md scaling section).
+any comm-byte model of a larger slice (BASELINE.md scaling section).
 
 Usage:
   python scripts/scaling_sweep.py [--out SCALING_local.jsonl]
@@ -50,7 +48,7 @@ APPS = ("kmeans", "mfsgd", "lda", "mlp", "subgraph", "rf")
 COMM_MARKERS = ("all-reduce", "all-gather", "all-to-all",
                 "collective-permute", "reduce-scatter", "collective")
 
-#: headline rate key per app (mirrors bench.py UNITS); *_per_chip keys
+#: headline rate key per app; *_per_chip keys
 #: are multiplied by N for the total-rate scaling curves
 RATE_KEYS = {
     "kmeans": "iters_per_sec",

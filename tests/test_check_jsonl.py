@@ -791,9 +791,9 @@ def test_model_row_needs_a_subject():
 def test_model_row_rejects_unknown_program_and_config():
     assert any("unregistered program" in e
                for e in _model_errs(_model_row(program="made.up")))
-    assert any("not in the sprint surface" in e
+    assert any("not in the frozen list" in e
                for e in _model_errs(_model_row(config="warp_drive")))
-    assert any("not in the sprint surface" in e
+    assert any("not in the frozen list" in e
                for e in _model_errs(_model_row(configs=["kmeans", "nope"])))
 
 

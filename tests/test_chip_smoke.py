@@ -160,7 +160,7 @@ def test_no_other_code_sets_a_compile_cache():
             offenders += [os.path.join(dirpath, f) for f in files
                           if f.endswith(".py")]
     offenders += [os.path.join(ROOT, f) for f in
-                  ("bench.py", "chip_smoke.py", "__graft_entry__.py")]
+                  ("chip_smoke.py", "__graft_entry__.py")]
 
     def sets_cache(path):
         with open(path) as f:

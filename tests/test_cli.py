@@ -324,85 +324,6 @@ def test_triples_two_column_fallback_matches_native(tmp_path, monkeypatch):
     np.testing.assert_array_equal(native[2], [0.0, 0.0])
 
 
-def test_measure_all_script_smoke(tmp_path):
-    """The L8 measurement script runs a subset and writes JSONL."""
-    import json
-    import os
-    import subprocess
-    import sys
-
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    out = tmp_path / "res.jsonl"
-    # the child inherits JAX_PLATFORMS=cpu and the 8-device XLA_FLAGS
-    # from conftest's environment
-    r = subprocess.run(
-        [sys.executable, os.path.join(root, "scripts", "measure_all.py"),
-         "--smoke", "--only", "kmeans", "--out", str(out)],
-        capture_output=True, text=True, timeout=240)
-    assert r.returncode == 0, r.stderr[-2000:]
-    recs = [json.loads(l) for l in out.read_text().splitlines()]
-    assert recs and recs[0]["config"] == "kmeans"
-    assert "iters_per_sec" in recs[0] and "error" not in recs[0]
-
-
-def test_measure_all_full_mode_kwargs_bind(monkeypatch):
-    """Every FULL-shape sweep config must CONSTRUCT correctly with no
-    chip: the lambdas' kwargs are bound against the real benchmark
-    signatures via stubs, so a typo'd/removed kwarg (or a config name
-    missing from SPRINT_ORDER) fails HERE — not twenty minutes into a
-    scarce TPU window.  Smoke mode only ever validates the smoke shapes;
-    this is the full-mode twin."""
-    import importlib.util
-    import inspect
-    import os
-
-    spec = importlib.util.spec_from_file_location(
-        "measure_all_bind", os.path.join(
-            os.path.dirname(__file__), "..", "scripts", "measure_all.py"))
-    ma = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(ma)
-
-    from harp_tpu.models import (kmeans, kmeans_stream, lda, mfsgd, mlp,
-                                 rf, subgraph, svm, wdamds)
-    from harp_tpu.utils import roofline
-
-    def stubbed(mod, attr):
-        sig = inspect.signature(getattr(mod, attr))
-
-        def stub(**kw):
-            sig.bind(**kw)  # TypeError on any kwarg the real fn rejects
-            return {"stub": 1.0}
-
-        monkeypatch.setattr(mod, attr, stub)
-
-    for mod in (kmeans, lda, mfsgd, mlp, rf, subgraph, svm, wdamds):
-        stubbed(mod, "benchmark")
-    stubbed(kmeans_stream, "benchmark_streaming")
-    from harp_tpu.serve import bench as serve_bench
-
-    stubbed(serve_bench, "benchmark")
-    stubbed(serve_bench, "benchmark_sustained")
-    monkeypatch.setattr(ma, "_bench_ingest",
-                        lambda smoke, quantize=None: {"stub": 1.0})
-    monkeypatch.setattr(roofline, "annotate", lambda name, res, kind: res)
-
-    rows = list(ma.run_all(smoke=False, only=None))
-    bad = [r for r in rows if "error" in r]
-    assert not bad, bad  # a binding failure shows up as the error row
-    assert [r["config"] for r in rows] == ma.SPRINT_ORDER
-
-    # PR 13: the perfmodel-pruned selection binds through the same
-    # machinery — the --predicted-top list is a valid --only list whose
-    # full-shape lambdas construct (and stays gate-closed, so a pruned
-    # sprint can still print verdicts)
-    only, ranked, _ = ma.predicted_only(4, "v4_32")
-    assert only and set(only) == ma.gate_closure(
-        c for c, _ in ranked[:4])
-    pruned = list(ma.run_all(smoke=False, only=only))
-    assert [r["config"] for r in pruned] == only
-    assert not [r for r in pruned if "error" in r]
-
-
 def test_dispatch_bench_smoke(capsys):
     rc = cli.main(["bench", "--verbs", "allreduce", "rotate",
                    "--min-kb", "1024", "--max-mb", "1", "--reps", "2"])
@@ -740,8 +661,8 @@ def test_health_cli_grades_profile_rows(capsys, tmp_path):
 def test_elastic_cli_knobs_bind_without_executing(capsys, monkeypatch):
     """PR-15 satellite: --elastic / --max-worker-loss on the mfsgd /
     lda / kmeans-stream apps forward into the elastic fit entries.
-    Each entry is stubbed with a signature-binding stub (the
-    measure_all full-mode pattern), so a typo'd or removed kwarg in the
+    Each entry is stubbed with a signature-binding stub, so a typo'd or
+    removed kwarg in the
     CLI wiring fails HERE — without training anything."""
     import inspect
 

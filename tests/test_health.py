@@ -21,9 +21,8 @@ Evidence layers, all on the 8-worker CPU sim:
    the flagship serve budgets (0 compiles / exact dispatch+readback
    totals) hold UNCHANGED with it armed;
 6. evidence regression: tolerance verdicts vs a committed incumbent,
-   model_invalidated on a magnitude-band breach, and the fail-closed
-   ``measure_all --predicted-top`` model gate (refusal + real-repo
-   pass).
+   model_invalidated on a magnitude-band breach, and the model gate
+   (``health --grade-model``) passing on the real repo.
 """
 
 import io
@@ -402,7 +401,7 @@ def test_grade_bench_row_magnitude_breach_invalidates_model(tmp_path):
 
 def test_model_gate_passes_on_committed_evidence():
     """The real repo's committed evidence grades clean (tier-1 already
-    pins perfmodel.grade ok), so the gate ALLOWS pruning and emits a
+    pins perfmodel.grade ok), so the gate passes and emits a
     confirmed info row that passes invariant 13."""
     from harp_tpu.health import grade as HG
 
@@ -415,39 +414,6 @@ def test_model_gate_passes_on_committed_evidence():
     assert check_jsonl._check_health_row("t", 1,
                                          {**finding, **stamp}) == []
     health.monitor.reset()
-
-
-def test_predicted_top_refuses_when_model_invalidated(monkeypatch):
-    """ROADMAP autotuning item (3), the gate pin: an invalidated model
-    must not choose what the next chip run measures — measure_all
-    --predicted-top exits 1 BEFORE computing any selection."""
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location(
-        "measure_all_gate", os.path.join(ROOT, "scripts",
-                                         "measure_all.py"))
-    ma = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(ma)
-
-    from harp_tpu.health import grade as HG
-
-    monkeypatch.setattr(
-        HG, "model_gate",
-        lambda repo: (False, {"verdict": "model_invalidated",
-                              "failures": 2, "detail": ["x", "y"]}))
-    with pytest.raises(SystemExit) as ei:
-        ma.predicted_only(3, "v4_32")
-    assert "REFUSED" in str(ei.value)
-    # ... and through the CLI surface, --dry-run included (the refusal
-    # must come before any selection is printed)
-    with pytest.raises(SystemExit) as ei:
-        ma.main(["--predicted-top", "2", "--dry-run"])
-    assert "REFUSED" in str(ei.value)
-    # gate open -> the selection machinery runs as before
-    monkeypatch.setattr(HG, "model_gate",
-                        lambda repo: (True, {"verdict": "confirmed"}))
-    only, ranked, _ = ma.predicted_only(2, "v4_32")
-    assert only and set(c for c, _ in ranked[:2]) <= set(only)
 
 
 # ---------------------------------------------------------------------------

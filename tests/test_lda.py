@@ -362,44 +362,18 @@ def test_carry_db_rejects_non_tiled_algos():
         L.LDAConfig(algo="pushpull", carry_db=True)
 
 
-def test_pack_cache_key_shared_across_non_layout_knobs(tmp_path):
-    """The prewarm script relies on sampler/rng/carry knobs NOT changing
-    the pack key (one pack serves lda/lda_carry/lda_exprace/lda_fast),
-    while algo and tiling MUST change it."""
-    args = (1, 1000, 50_000, 1000, 100, 0)
-
-    def path(**kw):
-        cfg = L._make_cfg(1000, kw.pop("algo", "dense"), **kw)
-        return L._pack_cache_path(str(tmp_path), cfg, args[0], *args[1:-1],
-                                  seed=args[-1])
-
-    base = path()
-    assert path(sampler="exprace") == base
-    assert path(sampler="exprace", rng_impl="rbg") == base
-    assert path(carry_db=True) == base
-    assert path(algo="pallas") != base
-    assert path(algo="scatter") != base
-    assert path(ndk_dtype="int16") != base
-    assert path(entry_cap=1024) != base
-
-
-def test_benchmark_pack_cache_roundtrip(mesh, tmp_path):
-    """pack_cache: the second benchmark run must install the cached pack
-    (one file, shared across sampler variants of the same tiling) and
-    produce an identical chain; a different tiling gets its own key."""
-    kw = dict(n_docs=128, vocab_size=64, n_topics=8, tokens_per_doc=8,
-              epochs=1, d_tile=16, w_tile=16, entry_cap=64, mesh=mesh,
-              pack_cache=str(tmp_path))
-    r1 = L.benchmark(**kw)
-    assert len(list(tmp_path.iterdir())) == 1
-    r2 = L.benchmark(**kw)  # cache hit
-    assert r1["log_likelihood"] == r2["log_likelihood"]
-    # sampler variants share the pack (layout-relevant knobs only)...
-    L.benchmark(sampler="exprace", **kw)
-    assert len(list(tmp_path.iterdir())) == 1
-    # ...a different tiling does not
-    L.benchmark(**{**kw, "entry_cap": 32})
-    assert len(list(tmp_path.iterdir())) == 2
+def test_defaults_are_the_measured_winners():
+    """The defaults follow what was measured (1x v5e, 2026-08-01): the
+    fused kernel stack with its carry is the default, and the dense-stack
+    carry, which lost its A/B, stays off."""
+    cfg = L.LDAConfig()
+    assert (cfg.algo, cfg.sampler, cfg.rng_impl) == (
+        "pallas", "exprace", "rbg")
+    # carry_db resolves at READ time: None stays stored, the resolver
+    # turns it on for the pallas stack only
+    assert cfg.carry_db is None
+    assert L.carry_db_resolved(cfg) is True
+    assert L.carry_db_resolved(L.LDAConfig(algo="dense")) is False
 
 
 def test_ndk_dtype_validation():

@@ -186,8 +186,8 @@ def test_enwiki_1m_pallas_program_lowers(mesh, monkeypatch, carry_db):
 
 @pytest.mark.parametrize("exact", [True, False])
 def test_hot_count_ab_shape_lowers_mosaic(mesh, monkeypatch, exact):
-    """The round-5 LL A/B pair (`lda_pallas_hot` / `_approx_hot`,
-    measure_all.py) runs at 20k docs x 256 vocab x 32 topics x 200
+    """The likelihood A/B pair of FLIP_DECISIONS.jsonl (`lda_pallas_hot` /
+    `_approx_hot`) ran at 20k docs x 256 vocab x 32 topics x 200
     tok/doc — avg Nwk cell ~488 > 256, where bf16 gather rounding CAN
     show.  The sprint must not discover a lowering error inside a scarce
     chip run: pin that BOTH gather variants Mosaic-compile at the

@@ -238,6 +238,15 @@ def test_dense_epoch_matches_numpy_model(mesh):
     assert abs(rmse - rmse_ref) < 1e-3
 
 
+def test_defaults_are_the_measured_winners():
+    """The defaults follow what was measured (1x v5e, 2026-08-01): the
+    fused tile kernel is the default; carry_w, within noise in its A/B,
+    stays off."""
+    cfg = MF.MFSGDConfig()
+    assert cfg.algo == "pallas"
+    assert cfg.carry_w is False
+
+
 def test_carry_w_bit_identical_chain(mesh):
     """carry_w=True (the LDA carry_db lever on MF-SGD's dense path)
     shares the entry core with the slice-per-entry path, so the trained

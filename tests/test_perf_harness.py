@@ -1,0 +1,42 @@
+"""The yardstick's own suite under the tier-1 floor: the cases of
+``perf/tests/test_harness.py`` (every cell rehearsed at a tiny shape, the
+shape of the last line, new cells as new files only),
+``test_program_telemetry.py`` (the spans, skew records and counters of
+the program that the per-layer metrics read) and ``test_trace_reduce.py``
+(trace -> numbers on the recorded trace), collected here as they are.
+They are the tests that fail when a program PR renames what a metric
+reads.  ``test_perf_lda_check.py`` holds the slow fourth file."""
+
+import jax
+import pytest
+
+from test_perf_generators import _cases_of
+
+_CACHE_OPTIONS = ("jax_compilation_cache_dir",
+                  "jax_persistent_cache_min_compile_time_secs",
+                  "jax_persistent_cache_min_entry_size_bytes")
+
+
+@pytest.fixture(autouse=True, scope="module")
+def _compile_cache_as_perf_tests_have_it():
+    """``tests/conftest.py`` sets ``JAX_COMPILATION_CACHE_DIR`` after jax
+    is imported: this process then has no persistent cache, and the
+    harness, which sees the variable, places none.  The job cell's
+    per-job compiles would each be cold, where under ``perf/tests`` (and
+    on the chip) they are cache hits.  So these cases run as they do
+    there, the harness placing ``<checkout>/.jax_cache`` itself, and the
+    process gets its settings back afterwards."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    before = {name: getattr(jax.config, name) for name in _CACHE_OPTIONS}
+    with pytest.MonkeyPatch.context() as patch:
+        patch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+        yield
+    for name, value in before.items():
+        jax.config.update(name, value)
+    compilation_cache.reset_cache()
+
+
+globals().update(_cases_of("test_harness.py", "checkout"))
+globals().update(_cases_of("test_program_telemetry.py", "checkout"))
+globals().update(_cases_of("test_trace_reduce.py"))

@@ -1,6 +1,6 @@
 """The predictive performance observatory (harp_tpu/perfmodel, PR 13).
 
-Four contracts, all tier-1:
+Three contracts, all tier-1:
 
 1. **Self-grading passes on the committed evidence** — the model's
    ranking agrees with every BENCH_local / FLIP_DECISIONS pair and
@@ -12,12 +12,8 @@ Four contracts, all tier-1:
 3. **The kernel registry prices without fallbacks** — every registered
    kernel declares its work model, and the VMEM pre-sizer reproduces
    the tiles the 2026-08-01 window calibrated by hand.
-4. **Sprint pruning respects the gates** — measure_all --predicted-top
-   can never drop a JOINT/EXCLUSIVE partner or CONDITIONAL anchor its
-   selection depends on (flip_decision's own tables are the source).
 """
 
-import importlib.util
 import json
 import os
 import sys
@@ -28,19 +24,10 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, os.path.join(ROOT, "scripts"))
 
 import check_jsonl  # noqa: E402
-import flip_decision  # noqa: E402
 
 from harp_tpu import perfmodel  # noqa: E402
 from harp_tpu.perfmodel import grade as G  # noqa: E402
 from harp_tpu.perfmodel import model as M  # noqa: E402
-
-
-def _load_measure_all():
-    spec = importlib.util.spec_from_file_location(
-        "measure_all_pm", os.path.join(ROOT, "scripts", "measure_all.py"))
-    ma = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(ma)
-    return ma
 
 
 # -- 1. self-grading against the committed evidence -------------------------
@@ -103,16 +90,6 @@ def test_sweep_points_match_their_committed_file():
     assert loaded["errors"] == []
 
 
-def test_family_pairs_mirror_flip_decision():
-    """The grading table and flip_decision.CANDIDATES must tell one
-    story about who competes with whom (and on which metric)."""
-    for cand, (inc, metric, fb) in G.FAMILY_PAIRS.items():
-        spec = flip_decision.CANDIDATES[cand]
-        assert spec["incumbent"] == inc, cand
-        assert spec["metric"] == metric, cand
-        assert spec.get("metric_fallback") == fb, cand
-
-
 def test_spearman():
     assert G.spearman([1, 2, 3], [10, 20, 30]) == 1.0
     assert G.spearman([1, 2, 3], [30, 20, 10]) == -1.0
@@ -164,20 +141,19 @@ def test_model_row_terms_sum_and_bound():
                                key=lambda b: row["terms"][f"{b}_s"])
 
 
-def test_vocabulary_and_sprint_sync():
-    """Frozen vocab pins: perfmodel <-> check_jsonl <-> measure_all."""
-    ma = _load_measure_all()
+def test_vocabulary_sync():
+    """Frozen vocab pins: perfmodel <-> check_jsonl."""
     assert tuple(perfmodel.BOUNDS) == check_jsonl.KNOWN_MODEL_BOUNDS
     assert tuple(perfmodel.RATES_SOURCES) == \
         check_jsonl.KNOWN_MODEL_RATES_SOURCES
-    assert set(check_jsonl.KNOWN_MODEL_CONFIGS) == set(ma.SPRINT_ORDER)
-    # every priced config and every program-mapped config is runnable
-    assert set(M.CONFIG_MODELS) <= set(ma.SPRINT_ORDER)
+    # every priced config and every program-mapped config is a name the
+    # checker admits in a model row
+    assert set(M.CONFIG_MODELS) <= set(check_jsonl.KNOWN_MODEL_CONFIGS)
     for prog, cfgs in M.PROGRAM_CONFIGS.items():
         assert prog in check_jsonl.KNOWN_LINT_PROGRAMS, prog
-        assert set(cfgs) <= set(ma.SPRINT_ORDER), prog
+        assert set(cfgs) <= set(check_jsonl.KNOWN_MODEL_CONFIGS), prog
     # and the drivers registry maps completely (a new byte-sheeted
-    # program must state its sprint configs, even as an explicit ())
+    # program must state its configs, even as an explicit ())
     from harp_tpu.analysis.drivers import DRIVERS
 
     assert set(M.PROGRAM_CONFIGS) == set(DRIVERS)
@@ -300,58 +276,3 @@ def test_presizer_picks_the_rf_row_tile():
                             n_classes=2, depth=6, num_workers=8)
     assert out["tile"] == 2048, out
     assert set(out["fits"]) >= {2048, 1024, 512}
-
-
-# -- 4. sprint pruning respects the gates -----------------------------------
-
-def test_gate_closure_never_drops_a_partner():
-    """For EVERY candidate: selecting it alone must pull in all its
-    JOINT partners, EXCLUSIVE partners, and CONDITIONAL anchors
-    (recursively) — reusing flip_decision's own gate tables, so a new
-    gate is automatically honored here."""
-    ma = _load_measure_all()
-    for cand in flip_decision.CANDIDATES:
-        closed = ma.gate_closure({cand})
-        for group in (flip_decision.JOINT_GATES
-                      + flip_decision.EXCLUSIVE_GATES):
-            if closed & set(group):
-                assert set(group) <= closed, (cand, group)
-        for name, (_, anchor) in flip_decision.CONDITIONAL_GATES.items():
-            if name in closed:
-                assert anchor in closed, (cand, name)
-
-
-def test_predicted_only_is_ordered_and_gate_closed():
-    ma = _load_measure_all()
-    only, ranked, unpriced = ma.predicted_only(3, "v4_32")
-    assert only == [c for c in ma.SPRINT_ORDER if c in only]  # order
-    assert set(only) == ma.gate_closure(c for c, _ in ranked[:3])
-    # rankings are real speedups over the committed evidence shapes
-    assert all(s > 0 for _, s in ranked)
-    # unpriceable candidates are reported, not silently dropped
-    assert set(unpriced) <= set(flip_decision.CANDIDATES)
-    for cand in unpriced:
-        assert cand not in M.CONFIG_MODELS or \
-            G.FAMILY_PAIRS[cand][0] not in M.CONFIG_MODELS
-
-
-def test_predicted_top_cli_dry_run_binds(capsys):
-    """The argparse surface: --predicted-top computes an --only list
-    and --dry-run prints it without importing jax or benchmarking."""
-    ma = _load_measure_all()
-    ma.main(["--predicted-top", "2", "--dry-run", "--topology",
-             "sim_ring_8"])
-    out = capsys.readouterr()
-    sel = json.loads(out.out.strip().splitlines()[-1])
-    assert sel["dry_run"] is True
-    meta = json.loads(out.err.strip().splitlines()[-1])
-    assert meta["only"] == sel["would_run"]
-    assert set(sel["would_run"]) == ma.gate_closure(
-        c for c, _ in meta["ranking"][:2])
-
-
-def test_predicted_top_conflicts_with_only():
-    ma = _load_measure_all()
-    with pytest.raises(SystemExit):
-        ma.main(["--predicted-top", "2", "--only", "kmeans",
-                 "--dry-run"])

@@ -1,10 +1,17 @@
 """Roofline annotation math (utils/roofline.py)."""
 
+import os
+import sys
+
 import numpy as np
 import pytest
 
-from harp_tpu.utils import roofline as R
-from harp_tpu.utils.roofline import V5E
+sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "scripts"))
+
+import check_jsonl  # noqa: E402
+
+from harp_tpu.utils import roofline as R  # noqa: E402
+from harp_tpu.utils.roofline import V5E  # noqa: E402
 
 
 def test_kmeans_annotation_math():
@@ -87,39 +94,13 @@ def test_unknown_device_kind_is_an_error_not_a_default():
     assert R.peaks_for(V5E)["hbm_gbs"] == 819e9
 
 
-def test_measure_all_smoke_record_names_its_device(mesh):
-    # end-to-end: the measure_all pipeline stamps the device on every row
-    # and — on the CPU simulation — adds no roofline fields
-    import importlib.util
-    import os
-
-    spec = importlib.util.spec_from_file_location(
-        "measure_all", os.path.join(os.path.dirname(__file__), "..",
-                                    "scripts", "measure_all.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    recs = list(mod.run_all(smoke=True, only=["kmeans"]))
-    assert len(recs) == 1, recs
-    assert recs[0]["platform"] == "cpu" and recs[0]["n_devices"] == 8
-    assert "device_kind" in recs[0] and "pct_peak_flops" not in recs[0]
-
-
 def test_variant_configs_share_their_family_model():
-    """EVERY mfsgd/lda config the sweep runs must be annotated with its
-    family's minimum-byte floor — a variant missing from WORK_MODELS
-    records an in-window row with no roofline fields, silently thinning
-    the very analysis the sprint exists to produce (round 5).  Derived
-    from SPRINT_ORDER so the NEXT variant added to the sweep is guarded
-    too, not just the six that existed when this was written."""
-    import importlib.util
-    import os
-
-    spec = importlib.util.spec_from_file_location(
-        "measure_all_rr", os.path.join(os.path.dirname(__file__), "..",
-                                       "scripts", "measure_all.py"))
-    ma = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(ma)
-    for cfg in ma.SPRINT_ORDER:
+    """EVERY mfsgd/lda config name a row may carry must be annotated
+    with its family's minimum-byte floor — a variant missing from
+    WORK_MODELS yields a row with no roofline fields.  Derived from the
+    checker's frozen list of config names so the NEXT variant is guarded
+    too."""
+    for cfg in check_jsonl.KNOWN_MODEL_CONFIGS:
         for fam in ("mfsgd", "lda"):
             if cfg == fam or cfg.startswith(fam + "_"):
                 assert R.WORK_MODELS.get(cfg) is R.WORK_MODELS[fam], cfg

@@ -308,7 +308,7 @@ def test_live_report_and_render_skew(mesh):
 
 
 # ---------------------------------------------------------------------------
-# scaling sweep / projection carry-through (satellite)
+# scaling sweep carry-through (satellite)
 # ---------------------------------------------------------------------------
 
 def _load_script(name):
@@ -333,23 +333,6 @@ def test_scaling_sweep_skew_columns_prefer_execution_phase():
     assert cols["skew_work"] == [9.0, 1.0]
     with telemetry.scope():
         assert ss.skew_columns() == {"skew_max_mean": None}  # nothing yet
-
-
-def test_project_scaling_measured_skew_picks_highest_worker_count(
-        tmp_path):
-    ps = _load_script("project_scaling")
-    p = tmp_path / "SCALING_local.jsonl"
-    rows = [
-        {"app": "lda", "n_workers": 4, "skew_max_mean": 1.5},
-        {"app": "lda", "n_workers": 8, "skew_max_mean": 1.2},
-        {"app": "mfsgd", "n_workers": 8, "skew_max_mean": None},
-        {"app": "kmeans", "n_workers": 8},
-        "not json at all",
-    ]
-    p.write_text("".join(
-        (r if isinstance(r, str) else json.dumps(r)) + "\n" for r in rows))
-    out = ps.measured_skew(str(p))
-    assert out == {"lda": 1.2}
 
 
 # ---------------------------------------------------------------------------
