@@ -311,9 +311,7 @@ def _lda_epoch():
                 LDAConfig(n_topics=4, algo="dense", d_tile=8, w_tile=8,
                           entry_cap=32), mesh, seed=0)
     model.set_tokens(d_ids, w_ids)
-    keys = mesh.shard_array(model._keys, 0)
-    return model._epoch_fn, (model.Ndk, model.Nwk, model.Nk,
-                             model.z_grid) + model._tokens + (keys,)
+    return model._epoch_fn, model._epoch_args()
 
 
 @register_driver("kmeans.fit_hier")

@@ -44,7 +44,8 @@ from harp_tpu.ops.pallas_compat import interpret_default
 from harp_tpu.parallel import collective as C
 from harp_tpu.parallel.mesh import WorkerMesh, current_mesh
 from harp_tpu.parallel.rotate import (ROTATE_WIRES, resident_chunk_index,
-                                      rotate_pipeline)
+                                      rotate_pipeline,
+                                      rotate_pipeline_resident)
 from harp_tpu.models.mfsgd import (
     _ceil_div,
     _dense_bounds,
@@ -480,6 +481,16 @@ def _epoch_device_fn(mesh: WorkerMesh, cfg: LDAConfig, vocab_size: int,
     entries, or one over fixed-size scatter chunks (see
     :func:`_sample_runs_pallas` / :func:`_sample_entry` /
     :func:`_sample_chunk`).
+
+    ``algo="pallas"`` takes and returns both tables TOPIC-MAJOR
+    (``[K, docs]``, ``[K, words]``: the layout the kernel reads and
+    :class:`LDA` stores, see ``LDA._Nwk``), the word slice's rotation
+    chunks being column ranges of it, and runs the same schedule through
+    :func:`rotate_pipeline_resident`: the kernel addresses the resident
+    chunk inside the whole slice, so no XLA op of a sweep writes a
+    buffer the size of the table or of a half-slice (on one worker;
+    across workers the in-flight chunk is cut out for its ring hop).
+    The other algos stay row-major and row-chunked.
     """
     nc = rotate_chunks_resolved(cfg)
     tiled = cfg.algo in _TILED_ALGOS
@@ -498,13 +509,8 @@ def _epoch_device_fn(mesh: WorkerMesh, cfg: LDAConfig, vocab_size: int,
         valid = ((tokens[0] < cfg.d_tile) if tiled
                  else (tokens[2] > 0)).sum()
         work_w = C.allgather(valid.astype(jnp.float32)[None])
-        if pallas:
-            # the fused kernel is topic-major: transpose once per epoch
-            # (~10 GB/epoch of HBM at enwiki scale — noise vs the epoch);
-            # the pipeline then chunks (and rotates) along axis 1
-            Ndk, Nwk_slice = Ndk.T, Nwk_slice.T
 
-        def step(st, computing, t):
+        def step(st, computing, t, slot=None):
             Ndk, Nk, z_grid, key = st
             chunk_idx = resident_chunk_index(t, nc)
             blk = jax.tree.map(lambda a: a[chunk_idx], tokens)
@@ -512,9 +518,17 @@ def _epoch_device_fn(mesh: WorkerMesh, cfg: LDAConfig, vocab_size: int,
             key, sub = jax.random.split(key)
 
             if pallas:
+                from harp_tpu.ops.lda_kernel import shift_chunk_meta
+
                 cd, cw, meta = blk  # [NCH, cc], [NCH]
+                # ``computing`` is the whole topic-major word slice and
+                # the chunk list names word tiles of its own half-slice:
+                # moved on by the tiles of the slots ahead, the kernel
+                # reads and writes that half-slice where it lies
+                tiles = computing.shape[1] // nc // cfg.w_tile
                 Ndk, computing, dNk, z_new = _sample_runs_pallas(
-                    Ndk, computing, Nk, z_blk, cd, cw, meta, sub, cfg,
+                    Ndk, computing, Nk, z_blk, cd, cw,
+                    shift_chunk_meta(meta, slot * tiles), sub, cfg,
                     vocab_size, count_bounds)
             elif tiled:
                 ed, ew, od, ow = blk  # [NE, C], [NE]
@@ -597,12 +611,14 @@ def _epoch_device_fn(mesh: WorkerMesh, cfg: LDAConfig, vocab_size: int,
             z_grid = z_grid.at[chunk_idx].set(z_new)
             return (Ndk, Nk, z_grid, key), computing
 
-        (Ndk, Nk, z_grid, key), Nwk_slice = rotate_pipeline(
-            step, (Ndk, Nk, z_grid, key), Nwk_slice,
-            n_chunks=nc, wire=cfg.rotate_wire,
-            chunk_axis=1 if pallas else 0)
         if pallas:
-            Ndk, Nwk_slice = Ndk.T, Nwk_slice.T
+            (Ndk, Nk, z_grid, key), Nwk_slice = rotate_pipeline_resident(
+                step, (Ndk, Nk, z_grid, key), Nwk_slice,
+                n_chunks=nc, wire=cfg.rotate_wire, chunk_axis=1)
+        else:
+            (Ndk, Nk, z_grid, key), Nwk_slice = rotate_pipeline(
+                step, (Ndk, Nk, z_grid, key), Nwk_slice,
+                n_chunks=nc, wire=cfg.rotate_wire)
         return Ndk, Nwk_slice, Nk, z_grid, work_w
 
     return epoch
@@ -651,11 +667,28 @@ def _device_epoch_fn(mesh: WorkerMesh, cfg: LDAConfig, vocab_size: int,
 
 
 #: the chain's state (Ndk, Nwk, Nk, z_grid) is donated to the sweep
-#: programs: the driver installs the outputs in its place, and a second
-#: word-topic table (4 GB at a 1M-word vocabulary and 1k topics) beside
-#: the sweep's own temporaries does not fit a 16 GB chip (18.7 GB
-#: undonated, 14.6 GB donated: tests/test_chip_compile.py)
+#: programs: the driver installs the outputs in its place, and the
+#: program holds no second word-topic table (4 GB at a 1M-word vocabulary
+#: and 1k topics; tests/test_chip_compile.py counts what it does hold)
 _STATE_ARGS = (0, 1, 2, 3)
+
+
+def _epoch_in_specs(mesh: WorkerMesh, cfg: LDAConfig):
+    """The worker axis lies on a count table's rows (documents, words),
+    which are the columns of ``algo="pallas"``'s topic-major tables."""
+    table = mesh.spec(1 if cfg.algo == "pallas" else 0)
+    return (table, table, P(), mesh.spec(0)) \
+        + (mesh.spec(0),) * _n_token_args(cfg)
+
+
+def make_relayout_fn(mesh: WorkerMesh, to_topic_major: bool):
+    """A count table from ``[rows, K]`` to topic-major ``[K, rows]`` or
+    back, on the device: every worker's block transposed where it lies."""
+    src, dst = mesh.spec(0), mesh.spec(1)
+    if not to_topic_major:
+        src, dst = dst, src
+    return jax.jit(mesh.shard_map(lambda a: a.T, in_specs=(src,),
+                                  out_specs=dst))
 
 
 def _n_token_args(cfg: LDAConfig) -> int:
@@ -666,7 +699,7 @@ def _n_token_args(cfg: LDAConfig) -> int:
 def _epoch_out_specs(mesh, cfg):
     """Pushpull epochs also return the global drop counter (replicated);
     every algo appends the replicated per-worker work vector (skew)."""
-    base = (mesh.spec(0), mesh.spec(0), P(), mesh.spec(0))
+    base = _epoch_in_specs(mesh, cfg)[:4]  # the state comes back as it went
     return base + ((P(),) if cfg.algo == "pushpull" else ()) + (P(),)
 
 
@@ -682,12 +715,13 @@ def make_epoch_fn(mesh: WorkerMesh, cfg: LDAConfig, vocab_size: int,
     The first four arguments (``Ndk``, ``Nwk``, ``Nk``, ``z_grid``) are
     DONATED (``_STATE_ARGS``): where the backend honours donation a
     handle to them is deleted by the call; keep what the call returns.
+    Under ``algo="pallas"`` both tables go in and come out topic-major
+    (:func:`epoch_arg_shapes`).
     """
     return jax.jit(
         mesh.shard_map(
             _device_epoch_fn(mesh, cfg, vocab_size, count_bounds),
-            in_specs=(mesh.spec(0), mesh.spec(0), P(), mesh.spec(0))
-            + (mesh.spec(0),) * _n_token_args(cfg),
+            in_specs=_epoch_in_specs(mesh, cfg),
             out_specs=_epoch_out_specs(mesh, cfg),
         ),
         donate_argnums=_STATE_ARGS,
@@ -732,8 +766,7 @@ def make_multi_epoch_fn(mesh: WorkerMesh, cfg: LDAConfig, vocab_size: int,
     return jax.jit(
         mesh.shard_map(
             many,
-            in_specs=(mesh.spec(0), mesh.spec(0), P(), mesh.spec(0))
-            + (mesh.spec(0),) * _n_token_args(cfg),
+            in_specs=_epoch_in_specs(mesh, cfg),
             out_specs=_epoch_out_specs(mesh, cfg),
         ),
         donate_argnums=_STATE_ARGS,
@@ -807,6 +840,10 @@ def epoch_arg_shapes(n_workers, n_docs, vocab_size, cfg: LDAConfig,
     """Shape/dtype of every compiled-epoch argument at a given scale,
     WITHOUT building a corpus — ``[(shape, dtype), ...]`` in
     :func:`make_epoch_fn` argument order (Ndk, Nwk, Nk, z, *tokens, keys).
+    The two count tables are ``[rows, K]``, a worker's rows one block of
+    dim 0, for every algo but ``"pallas"``, whose programs take them as
+    :class:`LDA` stores them: topic-major ``[K, rows]``, a worker's rows
+    one block of dim 1.
 
     This is the memory-budget model for graded shapes: the enwiki-1M
     lowering proof (tests/test_lda_scale.py, mirroring the 1B-point
@@ -863,10 +900,12 @@ def epoch_arg_shapes(n_workers, n_docs, vocab_size, cfg: LDAConfig,
     if cfg.algo in _TILED_ALGOS:
         d_own, w_own, d_bound, ib2 = _dense_bounds(
             n_docs, vocab_size, n, ns, cfg.d_tile, cfg.w_tile)
-        tables = [((d_bound * n, K), ndk_dt), ((2 * ib2 * n, K), f32), nk]
+        tables = [((d_bound * n, K), ndk_dt), ((ib2 * ns, K), f32), nk]
         row_tokens = _ceil_div(n_tokens, n * ns)
         if cfg.algo == "pallas":
             from harp_tpu.ops.lda_kernel import CHUNK
+
+            tables[:2] = [(shape[::-1], dt) for shape, dt in tables[:2]]
 
             if entry_width is not None:
                 raise ValueError("algo='pallas' stages lda_kernel.CHUNK-"
@@ -928,6 +967,14 @@ class LDA:
         self._count_bounds = (None, None)
         self._epoch_fn = flightrec.track(
             make_epoch_fn(self.mesh, self.cfg, vocab_size), "lda.epoch")
+        if self.cfg.algo == "pallas":
+            # between the tables as every reader has them ([rows, K], a
+            # worker's rows a block of dim 0) and as they are stored:
+            # each worker's block transposed where it lies, on the device
+            self._to_topic_major = flightrec.track(
+                make_relayout_fn(self.mesh, True), "lda.relayout")
+            self._to_row_major = flightrec.track(
+                make_relayout_fn(self.mesh, False), "lda.relayout")
         self._multi_fns: dict = {}
         self._seed = seed
         self._tokens = None
@@ -941,6 +988,59 @@ class LDA:
         # the elastic driver sets per-worker [(pack_id, load)] lists so
         # the sentinel's skew_trigger plan is whole-unit replayable
         self.skew_units = None
+
+    # The count tables.  ``Ndk`` [docs, K] and ``Nwk`` [words, K] are what
+    # every reader gets and every writer gives (storage rows: see
+    # :meth:`doc_topic_table` / :meth:`word_topic_table` for external
+    # ids).  ``_Ndk`` / ``_Nwk`` are what the device holds and the sweep
+    # programs take, donate and return: the same arrays for every algo
+    # but "pallas", whose fused kernel reads the tables TOPIC-MAJOR
+    # ([K, docs], [K, words], a rotation half-slice a column range), so
+    # there they stay topic-major from installation to read-out and a
+    # sweep neither transposes nor copies them (at a 1M-word vocabulary
+    # the word-topic table is 4 GB, and doing so was a third of the
+    # sweep: PERF.md section 6, PR 35).  A read makes a fresh row-major
+    # array on the device and keeps none; an assignment goes through the
+    # same relayout as installation.
+    @property
+    def Ndk(self):
+        return self._row_major(self._Ndk)
+
+    @Ndk.setter
+    def Ndk(self, rows):
+        self._Ndk = self._stored(rows)
+
+    @property
+    def Nwk(self):
+        return self._row_major(self._Nwk)
+
+    @Nwk.setter
+    def Nwk(self, rows):
+        self._Nwk = self._stored(rows)
+
+    def _row_major(self, table):
+        return (self._to_row_major(table) if self.cfg.algo == "pallas"
+                else table)
+
+    def _stored(self, rows):
+        """A ``[rows, K]`` table, the host's or one on the device, as the
+        device stores it.  A host table goes over as it is (the same
+        bytes, no copy of it made on the host); under ``algo="pallas"``
+        the relayout is one program on the device (12 ms for the 4 GB
+        table of the benchmark's cell).  Its input is not donated: a
+        transposed table cannot take its place, and a caller that gave a
+        device array keeps it; installation's own row-major copy is freed
+        as soon as the program has run, until when the device holds the
+        table twice."""
+        if not isinstance(rows, jax.Array):
+            rows = self.mesh.shard_array(rows, 0)
+        return (self._to_topic_major(rows) if self.cfg.algo == "pallas"
+                else rows)
+
+    def _epoch_args(self):
+        """What a sweep program takes, in its argument order."""
+        return (self._Ndk, self._Nwk, self.Nk, self.z_grid, *self._tokens,
+                self.mesh.shard_array(self._keys, 0))
 
     def suggest_pull_cap(self, apply=False):
         """Exact zero-drop ``pull_cap`` for the LOADED corpus (pushpull
@@ -1116,7 +1216,10 @@ class LDA:
         placed = (pack["Ndk"], pack["Nwk"], pack["z_grid"], *pack["tokens"])
         with telemetry.span("lda.install",
                             bytes=sum(a.nbytes for a in placed)):
-            self.Ndk, self.Nwk = sh(pack["Ndk"], 0), sh(pack["Nwk"], 0)
+            # the host's tables go over as they are; under algo="pallas"
+            # the assignment lays each out topic-major, once, on the
+            # device, and the row-major copy is dropped with it
+            self.Ndk, self.Nwk = pack["Ndk"], pack["Nwk"]
             self.Nk = jax.device_put(jnp.asarray(pack["Nk"]),
                                      self.mesh.replicated())
             self.z_grid = sh(np.asarray(pack["z_grid"], np.int32), 0)
@@ -1229,18 +1332,16 @@ class LDA:
             jitted = make_multi_epoch_fn(
                 self.mesh, self.cfg, self.vocab_size, epochs,
                 self._count_bounds)
-            keys = self.mesh.shard_array(self._keys, 0)
             # steps=0: lowering traces the sweep's comm sites under the
             # execution tag without counting an execution
             with telemetry.ledger.run("lda.epochs", steps=0):
                 fn = self._multi_fns[epochs] = flightrec.track(
-                    jitted.lower(
-                        self.Ndk, self.Nwk, self.Nk, self.z_grid,
-                        *self._tokens, keys).compile(), "lda.epochs")
+                    jitted.lower(*self._epoch_args()).compile(),
+                    "lda.epochs")
         return fn
 
     def _install_epoch_out(self, out):
-        self.Ndk, self.Nwk, self.Nk, self.z_grid = out[:4]
+        self._Ndk, self._Nwk, self.Nk, self.z_grid = out[:4]
         if self.cfg.algo == "pushpull":
             # drop counter (the "counted, never silently wrong" half of
             # the capacity contract) + per-worker work vector in ONE
@@ -1259,13 +1360,12 @@ class LDA:
         one sync) — see :func:`make_multi_epoch_fn`.  Use :meth:`fit` when
         checkpointing between sweeps."""
         fn = self.compile_epochs(epochs)
-        keys = self.mesh.shard_array(self._keys, 0)
+        args = self._epoch_args()
         # the scan body's traced comm sites execute once per Gibbs sweep
         with telemetry.span("lda.epochs", epochs=epochs), \
                 telemetry.ledger.run("lda.epochs", steps=epochs):
             t0 = time.perf_counter()
-            out = fn(self.Ndk, self.Nwk, self.Nk, self.z_grid,
-                     *self._tokens, keys)
+            out = fn(*args)
             self._advance_keys()
             self._install_epoch_out(out)
             skew.record_execution("lda.epochs", self.last_work,
@@ -1276,14 +1376,11 @@ class LDA:
     def sample_epoch(self):
         if self._tokens is None:
             raise RuntimeError("call set_tokens() before sample_epoch()")
-        keys = self.mesh.shard_array(self._keys, 0)
+        args = self._epoch_args()
         with telemetry.span("lda.epoch"), \
                 telemetry.ledger.run("lda.epochs", steps=1):
             t0 = time.perf_counter()
-            out = self._epoch_fn(
-                self.Ndk, self.Nwk, self.Nk, self.z_grid, *self._tokens,
-                keys
-            )
+            out = self._epoch_fn(*args)
             self._advance_keys()
             self._install_epoch_out(out)
             skew.record_execution("lda.epochs", self.last_work,
@@ -1317,16 +1414,21 @@ class LDA:
                     "z": self.z_grid, "keys": np.asarray(self._keys)}
 
         def set_state(state):
-            check_restored_shapes([("Ndk", state["Ndk"], self.Ndk),
-                                   ("Nwk", state["Nwk"], self.Nwk),
-                                   ("z", state["z"], self.z_grid)])
+            # the state is row-major whatever the device holds (shapes
+            # only: no table is made for the comparison)
+            flip = -1 if self.cfg.algo == "pallas" else 1
+            check_restored_shapes(
+                [(name, state[name],
+                  jax.ShapeDtypeStruct(held.shape[::flip], held.dtype))
+                 for name, held in (("Ndk", self._Ndk), ("Nwk", self._Nwk))]
+                + [("z", state["z"], self.z_grid)])
             if not isinstance(state["Ndk"], jax.Array):  # numpy from restore
                 sh = self.mesh.shard_array
                 # restore casts to the configured dtype (counts are exact
                 # integers in either, so f32↔int16 round-trips losslessly)
-                self.Ndk = sh(np.asarray(state["Ndk"]).astype(
-                    np.dtype(self.cfg.ndk_dtype)), 0)
-                self.Nwk = sh(np.asarray(state["Nwk"]), 0)
+                self.Ndk = np.asarray(state["Ndk"]).astype(
+                    np.dtype(self.cfg.ndk_dtype))
+                self.Nwk = np.asarray(state["Nwk"])
                 self.z_grid = sh(np.asarray(state["z"]), 0)
                 self.Nk = jax.device_put(jnp.asarray(np.asarray(state["Nk"])),
                                          self.mesh.replicated())

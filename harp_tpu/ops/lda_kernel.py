@@ -10,8 +10,10 @@ posterior, topic draw, count-delta scatters — inside VMEM, so HBM sees
 only the count tiles in and out plus the token stream.
 
 Layout (the kmeans/mfsgd kernels' lane rules): everything is
-**topic-major** — count tables arrive transposed ([K, docs]/[K, words],
-the epoch transposes the tables once), token ids/assignments ride rows
+**topic-major** — count tables arrive transposed ([K, docs]/[K, words]:
+``models/lda.LDA`` stores them so, and a call may be handed the whole word
+slice with its chunk list moved onto one half-slice of it,
+:func:`shift_chunk_meta`), token ids/assignments ride rows
 [1, cc], all one-hots are built in [tile, cc] orientation, and every
 matmul contracts over lanes or A-lane×B-sublane.
 
@@ -128,6 +130,14 @@ def unpack_chunk_meta(meta):
     """``(wt, noop)`` of packed metadata — numpy arrays on the host, a
     traced i32 scalar inside the kernel and its index maps."""
     return meta >> _WT_SHIFT, (meta & _NOOP) != 0
+
+
+def shift_chunk_meta(meta, tiles):
+    """Packed metadata with every chunk's word tile index moved on by
+    ``tiles`` (host or traced; the no-op flag stays): how a chunk list
+    staged against one half-slice addresses that half-slice inside the
+    whole resident slice, ``tiles`` being the tiles ahead of it."""
+    return meta + (tiles << _WT_SHIFT)
 
 
 def _gather_planes(tbl_f32, oh, dot, nplanes: int):

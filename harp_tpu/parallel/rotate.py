@@ -225,6 +225,86 @@ def rotate_pipeline(
     return carry, _join_chunks(buf, chunk_axis)
 
 
+def rotate_pipeline_resident(
+    step_fn: Callable[[Any, Any, Any, Any], Any],
+    carry: Any,
+    model_slice: Any,
+    *,
+    n_chunks: int,
+    shift: int = 1,
+    axis: str = WORKER_AXIS,
+    wire: str = "exact",
+    chunk_axis: int = 0,
+):
+    """The chunked :func:`rotate_pipeline`, for a slice too large to copy:
+    the slice stays in ONE buffer for the whole epoch and a chunk is a
+    range of ``chunk_axis`` that ``step_fn`` addresses in place.
+
+    Same schedule, same data, bit for bit (pinned against
+    :func:`rotate_pipeline` by tests/test_lda_layout.py): chunk ``p`` of
+    the local slice is slot ``p``, the range ``[p * m, (p + 1) * m)`` of
+    ``chunk_axis``; step ``t`` computes on slot ``t % n_chunks`` while
+    slot ``(t - 1) % n_chunks`` (computed the step before; at step 0 the
+    last slot) crosses the ring, and after ``n_chunks * num_workers``
+    steps every chunk is home in its own slot.  What differs is what
+    moves on the device.  :func:`rotate_pipeline` hands ``step_fn`` the
+    resident chunk and takes it back, so the loop's carry swaps its
+    queue and its in-flight chunk every step and XLA copies each; that is
+    nothing for MF-SGD's factor halves and a third of a sweep for LDA's
+    4 GB word-topic table.  Here ``step_fn`` is
+    ``(carry, model_slice, t, slot) -> (carry, model_slice)``: it gets the
+    whole slice, updates slot ``slot`` (a traced index) and nothing else,
+    and the loop carries one buffer that never changes place.  Only the
+    in-flight chunk is cut out and written back, around its ring hop, and
+    with one worker there is no hop and nothing is cut.
+
+    Must be called inside ``shard_map`` (device view).
+    """
+    if n_chunks < 1:
+        raise ValueError(f"n_chunks must be >= 1, got {n_chunks}")
+    if n_chunks == 1:
+        # one chunk is the whole slice: compute, then the hop, which is
+        # the unchunked pipeline as it is (it swaps nothing)
+        return rotate_pipeline(
+            lambda c, x, t: step_fn(c, x, t, 0), carry, model_slice,
+            shift=shift, axis=axis, wire=wire)
+    wrotate = _wire_rotate(wire, shift, axis)
+    n = lax.axis_size(axis)
+    if math.gcd(shift % n, n) != 1:
+        raise ValueError(
+            f"shift={shift} shares a factor with the ring size {n}: chunks "
+            "would revisit a worker subset instead of covering the ring")
+    for x in jax.tree.leaves(model_slice):
+        if x.shape[chunk_axis] % n_chunks:
+            raise ValueError(
+                f"model slice dim {chunk_axis} of size "
+                f"{x.shape[chunk_axis]} does not split into {n_chunks} "
+                f"equal rotation chunks")
+
+    def width(x):
+        return x.shape[chunk_axis] // n_chunks
+
+    def body(state, t):
+        c, buf = state
+        if n > 1:
+            fly = (t + n_chunks - 1) % n_chunks
+            # cut out before the step writes the buffer: the hop has no
+            # dependency on this step's compute and overlaps it
+            received = wrotate(jax.tree.map(
+                lambda x: lax.dynamic_slice_in_dim(
+                    x, fly * width(x), width(x), chunk_axis), buf))
+        c, buf = step_fn(c, buf, t, t % n_chunks)
+        if n > 1:
+            buf = jax.tree.map(
+                lambda x, r: lax.dynamic_update_slice_in_dim(
+                    x, r, fly * width(x), chunk_axis), buf, received)
+        return (c, buf), None
+
+    (carry, model_slice), _ = lax.scan(
+        body, (carry, model_slice), jnp.arange(n_chunks * n))
+    return carry, model_slice
+
+
 def resident_chunk_index(t, n_chunks: int, *, shift: int = 1,
                          axis: str = WORKER_AXIS):
     """Global index of the chunk this worker computes at step ``t`` of the

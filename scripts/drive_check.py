@@ -2714,3 +2714,33 @@ print(f"threadguard: map generated ({len(_tg_pats)} forbidden patterns, "
       f"(retry absorbed {_tg_fe.runner.fault_retries} injected fault), "
       f"forbidden-name readback caught, observers restored exactly")
 print("DRIVE OK round-40")
+
+# --- round-41 (PR 35): algo="pallas" keeps its count tables topic-major on
+# the device from installation to read-out; every reader still gets [rows, K]
+# and a sweep holds the counts of its own chain.  8 workers, the default
+# config's path (interpret mode off the chip), public API only.
+from harp_tpu.models import lda as _tm_lda
+
+_tm_d, _tm_w = _tm_lda.synthetic_corpus(64, 128, 4, tokens_per_doc=24, seed=2)
+_tm_m = _tm_lda.LDA(64, 128, _tm_lda.LDAConfig(
+    n_topics=8, d_tile=8, w_tile=8, entry_cap=16), seed=5)
+_tm_m.set_tokens(_tm_d, _tm_w)
+_tm_ll0 = _tm_m.log_likelihood()
+_tm_m.sample_epochs(3)
+_tm_m.sample_epoch()
+_tm_nwk = np.asarray(_tm_m.Nwk)
+assert _tm_m._Nwk.shape == _tm_nwk.shape[::-1]         # stored topic-major
+assert np.array_equal(np.asarray(_tm_m._Nwk), _tm_nwk.T)
+_tm_doc, _tm_word, _tm_z = _tm_m.token_state()
+_tm_want = np.zeros((128, 8), np.float32)
+np.add.at(_tm_want, (_tm_word, _tm_z), 1)
+assert np.array_equal(_tm_m.word_topic_table(), _tm_want)
+assert np.array_equal(np.asarray(_tm_m.Nk), _tm_want.sum(0))
+assert np.asarray(_tm_m.Ndk).sum() == _tm_m.n_tokens == len(_tm_d)
+assert _tm_m.log_likelihood() > _tm_ll0
+_tm_m.Nwk = _tm_nwk                                     # a host table back in
+assert np.array_equal(np.asarray(_tm_m.Nwk), _tm_nwk)
+print(f"lda topic-major storage: 4 sweeps on {_tm_m.mesh.num_workers} "
+      f"workers, tables = the chain's counts, ll {_tm_ll0:.3f} -> "
+      f"{_tm_m.log_likelihood():.3f}")
+print("DRIVE OK round-41")

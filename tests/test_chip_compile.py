@@ -25,6 +25,31 @@ import pytest
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
+def table_sized_writes(hlo: str, sizes: set,
+                       mosaic: str = "tpu_custom_call") -> list:
+    """``[name, opcode]`` of every instruction of a compiled module's text
+    whose result is ONE array of an element count in ``sizes`` and that
+    writes it: parameters, tuple elements and bitcasts name a buffer that
+    is there, and the Mosaic call is the one writer allowed (its tables
+    are aliased).  Instructions inside fusions count with the rest."""
+    import math
+    import re
+
+    found = []
+    for line in hlo.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = \w+\[([\d,]*)\]\S* "
+                     r"([\w\-]+)\(", line)
+        if not m:
+            continue
+        name, dims, op = m.groups()
+        n = math.prod(int(d) for d in dims.split(",") if d)
+        if (n in sizes
+                and op not in ("parameter", "get-tuple-element", "bitcast")
+                and not (op == "custom-call" and mosaic in line)):
+            found.append([name, op])
+    return found
+
+
 def _compile_all() -> dict:
     """Every check, in the child: {check name: {program: mosaic calls}}."""
     import functools
@@ -122,32 +147,39 @@ def _compile_all() -> dict:
     # chunks of 128 slots a half-slice) and count bounds above its
     # corpus' (a 6,411-token document, a word of 204,574 tokens: 2 and 3
     # gather planes).  What the chip must hold: the arguments and the
-    # program's temporaries, the state donated, both tables going through
-    # the kernel in place.
+    # program's temporaries, the state donated, both tables topic-major
+    # as the device stores them and going through the kernel in place.
+    # What the sweep must not do: write a buffer the size of the table or
+    # of a rotation half-slice with anything but the kernel.
     from harp_tpu.models import lda
 
     cfg = lda.LDAConfig(n_topics=1000)
     fn = lda.make_multi_epoch_fn(mesh, cfg, 1_000_000, 1, (7000, 210_000))
     compiled = fn.lower(*[
-        jax.ShapeDtypeStruct(
-            shape, dt, sharding=(mesh.replicated() if i == 2 else
-                                 mesh.sharding(mesh.spec(0, ndim=len(shape)))))
-        for i, (shape, dt) in enumerate(lda.epoch_arg_shapes(
-            1, 6656, 1_000_000, cfg, entries_per_row=13 * 1381))]).compile()
-    mem = compiled.memory_analysis()
+        jax.ShapeDtypeStruct(shape, dt, sharding=mesh.sharding(spec))
+        for (shape, dt), spec in zip(
+            lda.epoch_arg_shapes(1, 6656, 1_000_000, cfg,
+                                 entries_per_row=13 * 1381),
+            lda._epoch_in_specs(mesh, cfg))]).compile()
+    mem, hlo = compiled.memory_analysis(), compiled.as_text()
     table = lda.epoch_arg_shapes(1, 6656, 1_000_000, cfg)[1]
+    elems = int(np.prod(table[0]))
     out["lda_cell"] = {
-        "mosaic_calls": compiled.as_text().count(chip_smoke.MOSAIC_CALL),
-        "table_bytes": int(np.prod(table[0])) * table[1].itemsize,
+        "mosaic_calls": hlo.count(chip_smoke.MOSAIC_CALL),
+        "table_shape": list(table[0]),
+        "table_bytes": elems * table[1].itemsize,
         "aliased_bytes": mem.alias_size_in_bytes,
+        "temp_bytes": mem.temp_size_in_bytes,
+        "table_sized_writes": table_sized_writes(hlo, {elems, elems // 2}),
         "held_gb": round((mem.argument_size_in_bytes
                           + mem.temp_size_in_bytes) / 1e9, 1)}
 
     # the kernel call that program makes 26 times a sweep, by itself: one
     # document-tile run (1,381 chunks, their metadata prefetched into
-    # SMEM) against the whole doc table and one half-slice of the
-    # word-topic table, both aliased; two K = 1000 count tiles in and
-    # out, double-buffered, under the raised VMEM limit
+    # SMEM) against the whole doc table and the whole word-topic table
+    # (it addresses the resident half-slice in it), both aliased; two
+    # K = 1000 count tiles in and out, double-buffered, under the raised
+    # VMEM limit
     from harp_tpu.ops import lda_kernel
 
     i32, f32 = jnp.int32, jnp.float32
@@ -158,7 +190,7 @@ def _compile_all() -> dict:
         nwk_count_bound=210_000), donate_argnums=(0, 1)).lower(*[
             jax.ShapeDtypeStruct(shape, dt, sharding=one)
             for shape, dt in (
-                ((1000, 6656), f32), ((1000, table[0][0] // 2), f32),
+                ((1000, 6656), f32), (table[0], f32),
                 ((1000,), f32), *[((1381, lda_kernel.CHUNK), i32)] * 3,
                 ((1381,), i32), ((), i32), ((2,), i32))]).compile()
     out["lda_run"] = {
@@ -214,18 +246,50 @@ def test_lda_cell_sweep_compiles_for_v5e_and_fits(compiled):
     """The 4 GB word-topic table at the benchmark cell's real size: the
     state is donated (the output takes the table's place, so the aliased
     bytes are the table's and the little beside it: the doc-topic table,
-    the chain), and arguments plus temporaries stay under the chip's
-    16 GB: 14.2 GB as this client counts them (14.8 GB with the
-    fixed-width entries before PR 32, whose chain and token arrays held
-    52.7M slots where the chunk list holds 4.6M).  Without the donation
-    the program asked for 18.7 GB and the chip refused it at its first
-    block (my chip runs, PRs 27 and 29)."""
+    the chain), and the program holds the table ONCE: 4.1 GB of
+    arguments and half a megabyte of temporaries as this client counts
+    them, since the tables stay topic-major between sweeps and one buffer
+    goes through the rotation (PR 35).  Before, the sweep's temporaries
+    were 10.1 GB beside them (14.2 GB; 14.8 GB with the fixed-width
+    entries before PR 32), and without the donation it asked for 18.7 GB
+    and the chip refused it at its first block (my chip runs, PRs 27 and
+    29).  One Mosaic call: the rotation stays a loop over its steps."""
     cell = compiled["lda_cell"]
     assert cell["mosaic_calls"] == 1
+    assert cell["table_shape"] == [1000, 2 * 500_224]  # topic-major
     assert cell["table_bytes"] == 2 * 500_224 * 1000 * 4
     assert cell["table_bytes"] <= cell["aliased_bytes"] \
         < 1.02 * cell["table_bytes"]
-    assert cell["held_gb"] < 15.0
+    assert cell["temp_bytes"] < 64 << 20
+    assert cell["held_gb"] < 4.5
+
+
+def test_lda_cell_sweep_copies_no_table(compiled):
+    """Inside the sweep no XLA op writes a buffer of the table's or of a
+    half-slice's element count: nothing transposes the table, cuts it
+    into half-slices or joins them, and the resident half-slice is not
+    copied around the run scan (13 such instructions before PR 35, nine
+    ops and a third of the sweep on the chip: PERF.md section 6)."""
+    assert compiled["lda_cell"]["table_sized_writes"] == []
+
+
+def test_table_sized_writes_reads_an_hlo_text():
+    hlo = """
+  %p = f32[8,16]{1,0:T(8,128)} parameter(0), sharding={replicated}
+  %copy.1 = f32[16,8]{1,0:T(8,128)} copy(%p)
+  %fusion.2 = f32[2,4,8]{2,1,0} fusion(%copy.1), kind=kLoop
+  %gte = f32[8,16]{1,0} get-tuple-element(%w), index=1
+  %call = (f32[8,16]{1,0}, s32[4]{0}) custom-call(%p), custom_call_target="tpu_custom_call"
+  %k = f32[8,16]{1,0} custom-call(%p), custom_call_target="tpu_custom_call"
+  %other = f32[8,16]{1,0} custom-call(%p), custom_call_target="Sharding"
+  ROOT %small = f32[4,8]{1,0} slice(%copy.1)
+"""
+    assert table_sized_writes(hlo, {128}) == [
+        ["copy.1", "copy"], ["other", "custom-call"]]
+    assert table_sized_writes(hlo, {128, 64}) == [
+        ["copy.1", "copy"], ["fusion.2", "fusion"],
+        ["other", "custom-call"]]
+    assert table_sized_writes(hlo, {32}) == [["small", "slice"]]
 
 
 def test_lda_run_kernel_compiles_for_v5e(compiled):

@@ -28,8 +28,9 @@ def _even_corpus(n_docs, vocab, tokens_per_doc):
 
 
 def _actual_args(model):
-    return [model.Ndk, model.Nwk, model.Nk, model.z_grid,
-            *model._tokens, model._keys]
+    # what the sweep programs take: the tables as the device stores them
+    # (topic-major under algo="pallas", see LDA._Nwk)
+    return [*model._epoch_args()[:-1], model._keys]
 
 
 def _check_shapes(model, predicted):
@@ -121,11 +122,12 @@ def test_shape_model_takes_the_real_partitioners_entries_on_a_zipf_corpus():
                            entries_per_row=nch_real + 1)
 
 
-def _sds(mesh, shapes):
-    return [jax.ShapeDtypeStruct(
-        shape, dt, sharding=(mesh.replicated() if i == 2
-                             else mesh.sharding(mesh.spec(0))))
-        for i, (shape, dt) in enumerate(shapes)]
+def _sds(mesh, shapes, cfg):
+    # sharded as the programs take them: a worker's rows are a block of a
+    # count table's dim 0, or of dim 1 where it is topic-major
+    return [jax.ShapeDtypeStruct(shape, dt, sharding=mesh.sharding(spec))
+            for (shape, dt), spec in zip(shapes,
+                                         L._epoch_in_specs(mesh, cfg))]
 
 
 N_DOCS, VOCAB, K, N_TOK = 1_000_000, 50_000, 1000, 100_000_000
@@ -153,7 +155,7 @@ def test_enwiki_1m_program_lowers(mesh, algo):
     assert np.dtype(ndk_dt) == np.int16 and ndk_gb < 2.1
 
     fn = L.make_multi_epoch_fn(mesh, cfg, VOCAB, epochs=5)
-    text = fn.lower(*_sds(mesh, shapes)).as_text()
+    text = fn.lower(*_sds(mesh, shapes, cfg)).as_text()
     assert "while" in text       # the chunk/entry scans lowered
     assert "xi16" in text        # the int16 table is in the program
 
@@ -163,7 +165,7 @@ def test_enwiki_1m_pallas_program_lowers(mesh, monkeypatch, carry_db):
     """The fused-kernel epoch at the TRUE graded shapes, MOSAIC-compiled:
     HARP_PALLAS_FORCE_MOSAIC routes the kernel through the real Pallas→
     Mosaic lowering (not interpret), and the whole program — topic-major
-    transposes, the scan over document-tile runs, the scalar-prefetched
+    tables in and out, the scan over document-tile runs, the scalar-prefetched
     chunk metadata, the kernel itself with both tables aliased — lowers
     for TPU on this CPU host.  The doc-tile carry is the kernel's:
     ``carry_db=False`` has no program to lower and raises."""
@@ -177,7 +179,7 @@ def test_enwiki_1m_pallas_program_lowers(mesh, monkeypatch, carry_db):
     cfg = L.LDAConfig(**kw)
     shapes = L.epoch_arg_shapes(8, N_DOCS, VOCAB, cfg, n_tokens=N_TOK)
     fn = L.make_multi_epoch_fn(mesh, cfg, VOCAB, epochs=2)
-    lowered = fn.trace(*_sds(mesh, shapes)).lower(
+    lowered = fn.trace(*_sds(mesh, shapes, cfg)).lower(
         lowering_platforms=("tpu",))
     text = lowered.as_text()
     assert "tpu_custom_call" in text  # the Mosaic kernel is in the program
@@ -199,6 +201,6 @@ def test_hot_count_ab_shape_lowers_mosaic(mesh, monkeypatch, exact):
     shapes = L.epoch_arg_shapes(8, 20_000, 256, cfg,
                                 n_tokens=20_000 * 200)
     fn = L.make_multi_epoch_fn(mesh, cfg, 256, epochs=2)
-    text = fn.trace(*_sds(mesh, shapes)).lower(
+    text = fn.trace(*_sds(mesh, shapes, cfg)).lower(
         lowering_platforms=("tpu",)).as_text()
     assert "tpu_custom_call" in text
