@@ -292,19 +292,28 @@ def make_train_step(mesh: WorkerMesh, cfg: MLPConfig):
     ), tx
 
 
+def epoch_batch_order(key, epoch, n_batches: int):
+    """The batches epoch ``epoch`` of a resident run visits, in order:
+    batch ``i`` is rows ``[i * batch_per_worker, (i + 1) *
+    batch_per_worker)`` of every worker's shard.  ``key`` is the raw key
+    bits the run was handed; the epoch index is folded in, so every
+    epoch reshuffles.  The one definition: :func:`make_epoch_fn` scans
+    over it and :meth:`MLPTrainer.resident_batch_order` hands it out."""
+    return jax.random.permutation(
+        jax.random.fold_in(jax.random.wrap_key_data(key), epoch), n_batches)
+
+
 def make_epoch_fn(mesh: WorkerMesh, cfg: MLPConfig, batch_per_worker: int,
                   n_batches: int, epochs: int = 1):
     """Compile ``epochs`` epochs over a device-RESIDENT shard as ONE program.
 
     Harp-DAAL NN iterates minibatches of an in-memory NumericTable; the
     TPU analogue keeps the shard in HBM and scans batch steps (and epochs)
-    on device — one dispatch and one readback for the whole run.  A
-    per-step host round trip dwarfs the ~3 ms device epoch: the host-loop
-    path measured 2.8–5.2M samples/s vs 21.2M fully on-device (MNIST
-    shapes, batch 8192, 1× v5e, 2026-07-30, over that day's slower host
-    link).
-    Batch order reshuffles each epoch by folding the epoch index into the
-    passed RNG key (replicated, so workers visit their shards in step).
+    on device — one dispatch and one readback for the whole run, where a
+    host loop pays a round trip every step of a few tens of microseconds
+    of device work (PERF.md has the measured figures).
+    Batch order reshuffles each epoch (:func:`epoch_batch_order`; the key
+    is replicated, so workers visit their shards in step).
     Returns per-epoch (last-batch loss, acc) arrays.
     """
     tx = make_optimizer(cfg)
@@ -312,12 +321,9 @@ def make_epoch_fn(mesh: WorkerMesh, cfg: MLPConfig, batch_per_worker: int,
     opt_specs = _opt_specs_for(mesh, cfg)
 
     def run(params, opt_state, xs, ys, key):
-        base = jax.random.wrap_key_data(key)
-
         def epoch(carry, e):
             params, opt_state = carry
-            order = jax.random.permutation(
-                jax.random.fold_in(base, e), n_batches)
+            order = epoch_batch_order(key, e, n_batches)
 
             def body(c, i):
                 p, o = c
@@ -388,6 +394,8 @@ class MLPTrainer:
             jax.jit(lambda p, v: forward(p, v, self.cfg)), "mlp.forward")
         self._epoch_fns: dict = {}
         self._shuffle_counter = 0
+        # optimizer steps this trainer has run, whichever path ran them
+        self.steps_run = 0
 
     def train_batch(self, x, y):
         """x: [b, features], y: [b] int labels; b divisible by num_workers."""
@@ -396,7 +404,14 @@ class MLPTrainer:
         self.params, self.opt_state, loss, acc = self._step(
             self.params, self.opt_state, x, y
         )
+        self.steps_run += 1
         return float(device_sync(loss)), float(device_sync(acc))
+
+    def _on_mesh(self, a) -> bool:
+        """Is ``a`` a device array already row-sharded over this
+        trainer's mesh?"""
+        return isinstance(a, jax.Array) and a.sharding.is_equivalent_to(
+            self.mesh.sharding(self.mesh.spec(0, ndim=a.ndim)), a.ndim)
 
     def load_resident(self, x, y, batch_size=8192, seed=0):
         """Stage the dataset in HBM for :meth:`fit_resident`.
@@ -405,8 +420,11 @@ class MLPTrainer:
         drop rows it drops a uniform random subset (``seed``), so the
         trim stays unbiased without the pre-PR-8 full-row host reshuffle
         (a whole extra dataset copy).  Batch ORDER still reshuffles on
-        device every epoch (:func:`make_epoch_fn`).  The host→device
-        transfer happens here, once, not inside the training loop.
+        device every epoch (:func:`make_epoch_fn`).  Host arrays cross to
+        the device here, once, not inside the training loop; arrays
+        already row-sharded over this trainer's mesh are taken as they
+        are (cast and trim on the device: the trim is a gather, a second
+        table while it runs).
         Returns the usable sample count.
         """
         n = x.shape[0]
@@ -415,23 +433,55 @@ class MLPTrainer:
             raise ValueError(f"need at least {nw} samples (one per worker), got {n}")
         batch_size = _effective_batch(batch_size, n, nw)
         usable = (n // batch_size) * batch_size
-        # rows stage in INPUT order (zero extra host copies when x is
-        # already f32) — the pre-PR ``x[order]`` gather re-materialized
-        # the whole dataset just to randomize an order the on-device
-        # per-epoch batch shuffle already randomizes.  Only the
-        # divisibility trim still samples: the dropped rows are a
-        # uniform random subset (order preserved), so the trim stays
-        # unbiased without a full-row reshuffle.
-        xs_host = np.asarray(x, np.float32)
-        ys_host = np.asarray(y, np.int32)
+        # the dropped rows are a uniform random subset (order preserved),
+        # so the trim stays unbiased without a full-row reshuffle
+        keep = None
         if usable < n:
-            rng = np.random.default_rng(seed)
-            keep = np.sort(rng.choice(n, size=usable, replace=False))
-            xs_host, ys_host = xs_host[keep], ys_host[keep]
-        xs = self.mesh.shard_array(xs_host, 0)
-        ys = self.mesh.shard_array(ys_host, 0)
+            keep = np.sort(np.random.default_rng(seed).choice(
+                n, size=usable, replace=False))
+        with telemetry.span("mlp.load_resident", rows=usable,
+                            trimmed=n - usable,
+                            bytes=usable * (x.shape[1] * 4 + 4)):
+            if self._on_mesh(x) and self._on_mesh(y):
+                xs, ys = x.astype(jnp.float32), y.astype(jnp.int32)
+                if keep is not None:
+                    trim = flightrec.track(jax.jit(
+                        lambda a, b, k: (jnp.take(a, k, axis=0),
+                                         jnp.take(b, k, axis=0)),
+                        out_shardings=(xs.sharding, ys.sharding)),
+                        "mlp.trim")
+                    xs, ys = trim(xs, ys, self.mesh.shard_array(
+                        keep.astype(np.int32), None))
+            else:
+                # rows stage in INPUT order (zero extra host copies when
+                # x is already f32 and nothing is trimmed)
+                xs_host = np.asarray(x, np.float32)
+                ys_host = np.asarray(y, np.int32)
+                if keep is not None:
+                    xs_host, ys_host = xs_host[keep], ys_host[keep]
+                xs = self.mesh.shard_array(xs_host, 0)
+                ys = self.mesh.shard_array(ys_host, 0)
         self._resident = (xs, ys, batch_size // nw, usable // batch_size)
         return usable
+
+    def _resident_key(self, seed):
+        """Raw threefry key bits of the next :meth:`fit_resident` call,
+        built on host: ``jax.random.PRNGKey(int)`` specializes on the
+        Python int, so distinct seeds would each trigger a (remote)
+        compile.  The call counter advances the key so sequential calls
+        (natural when reusing a compiled epoch count) keep reshuffling
+        instead of repeating one order."""
+        return prng.key_bits(seed + 1 + self._shuffle_counter)
+
+    def resident_batch_order(self, epochs=1, seed=0):
+        """The batches the NEXT ``fit_resident(epochs, seed)`` visits:
+        int array ``[epochs, n_batches]`` (:func:`epoch_batch_order`)."""
+        if getattr(self, "_resident", None) is None:
+            raise RuntimeError(
+                "call load_resident() before resident_batch_order()")
+        key, nb = self._resident_key(seed), self._resident[3]
+        return np.stack([np.asarray(epoch_batch_order(key, e, nb))
+                         for e in range(epochs)])
 
     def fit_resident(self, epochs=1, seed=0):
         """Train on the :meth:`load_resident`-staged data — ALL epochs as
@@ -444,19 +494,19 @@ class MLPTrainer:
         xs, ys, bpw, nb = self._resident
         fn = self._epoch_fns.get((bpw, nb, epochs))
         if fn is None:
-            fn, _ = make_epoch_fn(self.mesh, self.cfg, bpw, nb, epochs)
-            self._epoch_fns[(bpw, nb, epochs)] = fn
-        # raw threefry key bits built on host: jax.random.PRNGKey(int)
-        # specializes on the Python int, so distinct seeds would each
-        # trigger a (remote) compile.  The call counter advances the key so
-        # sequential fit_resident calls (natural when reusing a compiled
-        # epoch count) keep reshuffling instead of repeating one order.
-        s = seed + 1 + self._shuffle_counter
+            fn = self._epoch_fns[(bpw, nb, epochs)] = flightrec.track(
+                make_epoch_fn(self.mesh, self.cfg, bpw, nb, epochs)[0],
+                "mlp.epochs")
+        key = self._resident_key(seed)
         self._shuffle_counter += epochs
-        key = prng.key_bits(s)
-        self.params, self.opt_state, losses, accs = fn(
-            self.params, self.opt_state, xs, ys, key)
-        stats = np.asarray(jnp.stack([losses, accs], axis=1))  # one readback
+        # the scan body's traced comm sites execute once per optimizer step
+        with telemetry.span("mlp.epochs", epochs=epochs), \
+                telemetry.ledger.run("mlp.epochs", steps=epochs * nb):
+            self.params, self.opt_state, losses, accs = fn(
+                self.params, self.opt_state, xs, ys, key)
+            stats = flightrec.readback(                    # one readback
+                jnp.stack([losses, accs], axis=1))
+        self.steps_run += epochs * nb
         return [(float(l), float(a)) for l, a in stats]
 
     def fit_ckpt(self, x, y, epochs, ckpt_dir=None, *, batch_size=8192,
@@ -569,6 +619,7 @@ class MLPTrainer:
                     for xb, yb in pipe.stream(n_batches):
                         self.params, self.opt_state, loss, acc = self._step(
                             self.params, self.opt_state, xb, yb)
+                        self.steps_run += 1
                         history.append((float(device_sync(loss)),
                                         float(device_sync(acc))))
         return history
@@ -688,8 +739,8 @@ def benchmark(n=60_000, batch=8192, steps=50, mesh=None, cfg=None, warmup=5):
     Headline is the device-resident epoch path (``fit_resident`` — data in
     HBM, one dispatch per epoch, like DAAL iterating an in-memory
     NumericTable); ``samples_per_sec_hostloop`` times the per-batch host
-    dispatch loop (a host input pipeline) for comparison.  Measured 1× v5e
-    2026-07-30: 21.2M resident vs 2.8–5.2M host-loop.
+    dispatch loop (a host input pipeline) for comparison.  What either
+    reads on a chip is in PERF.md (the cell ``mlp-epochs``).
     """
     mesh = mesh or current_mesh()
     cfg = cfg or MLPConfig()

@@ -52,21 +52,26 @@ def _run(root, trace, lines=None):
 
 
 def test_entries_are_appended_and_nothing_else_changed():
-    assert [w["name"] for w in BENCH["workloads"]][-1] == CELL
-    cell = BENCH["workloads"][-1]
+    """The cell's entries stand where PR 29 appended them: fourth cell,
+    third configuration, its six metrics together; later PRs append
+    theirs after them."""
+    assert [w["name"] for w in BENCH["workloads"]][3] == CELL
+    cell = BENCH["workloads"][3]
     assert (cell["config"], cell["traffic"], cell["chips"]) == (
         CONFIG, "sweeps", 1)
-    entry = BENCH["configs"][-1]
+    entry = BENCH["configs"][2]
     assert entry["name"] == CONFIG and len(entry["source"]) <= 200
     for part in ("BASELINE.json configs[2]", "edu.iu.lda", "rotation",
                  "enwiki", "1M-word vocabulary"):
         assert part in entry["source"]
-    assert [m["name"] for m in BENCH["per_layer"]][-len(MINE):] == MINE
+    names = [m["name"] for m in BENCH["per_layer"]]
+    at = names.index(MINE[0])
+    assert names[at:at + len(MINE)] == MINE
     by_name = {m["name"]: m for m in BENCH["per_layer"] + BENCH["end_to_end"]}
     for name in MINE:
         assert by_name[name]["workloads"] == [CELL]
     for name in SHARED + ["items_per_s_chip"]:
-        assert by_name[name]["workloads"][-1] == CELL
+        assert by_name[name]["workloads"][3] == CELL
     assert sum(w["chips"] == 4 for w in BENCH["workloads"]) == 1
     config = spec.Cell(ROOT, CELL).config
     assert config["reduced"] == ["n_docs", "n_tokens"] == entry["reduced"]
