@@ -10,9 +10,12 @@ TPU-native design: the training step is one jitted SPMD program —
 :func:`harp_tpu.parallel.collective.allreduce` verb every other app uses
 (demonstrating the DP path is app-level API, not a special case), then an
 optax update applied identically on every worker (weights stay replicated,
-like Harp's model tables after allreduce).  MXU notes: batch and hidden
-dims padded to 128 keep the matmuls on full tiles; bf16 activations with
-f32 params/optimizer is the standard mixed-precision recipe.
+like Harp's model tables after allreduce).  The loss is written out in
+:func:`loss_fn` and not optax's, whose gather of the label's logit was a
+third of the step on the chip (PERF.md section 6, PR 37).  MXU notes:
+batch and hidden dims padded to 128 keep the matmuls on full tiles; bf16
+activations with f32 params/optimizer is the standard mixed-precision
+recipe.
 """
 
 from __future__ import annotations
@@ -87,8 +90,22 @@ def forward(params, x, cfg: MLPConfig):
 
 
 def loss_fn(params, x, y, cfg: MLPConfig):
+    """``(mean softmax cross-entropy, logits)`` of integer labels ``y``.
+
+    Written out, not ``optax.softmax_cross_entropy_with_integer_labels``:
+    that picks the label's logit with ``take_along_axis``, which XLA:TPU
+    ran as a gather for a third of the step (PERF.md section 6, PR 37).
+    Here a compare with an iota over the class axis selects it and the
+    sum adds exact zeros, so the float and the gradient autodiff derives
+    (softmax − one-hot) are the same.  Labels are assumed to lie in
+    ``[0, classes)``: one outside selects nothing and the row's loss is
+    its log-normaliser, where ``take_along_axis`` wrapped a negative
+    label and filled with NaN past the last class.
+    """
     logits = forward(params, x, cfg)
-    ce = optax.softmax_cross_entropy_with_integer_labels(logits, y)
+    is_label = y[..., None] == jnp.arange(logits.shape[-1])
+    label_logit = jnp.where(is_label, logits, 0).sum(-1)
+    ce = jax.nn.logsumexp(logits, axis=-1) - label_logit
     return ce.mean(), logits
 
 

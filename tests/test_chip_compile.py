@@ -50,6 +50,14 @@ def table_sized_writes(hlo: str, sizes: set,
     return found
 
 
+def gathers_and_scatters(hlo: str) -> int:
+    """How many ``gather`` and ``scatter`` instructions a compiled
+    module's text holds, those inside fusions with the rest."""
+    import re
+
+    return len(re.findall(r" = \S+ (?:gather|scatter)\(", hlo))
+
+
 def _compile_all() -> dict:
     """Every check, in the child: {check name: {program: mosaic calls}}."""
     import functools
@@ -226,14 +234,14 @@ def _compile_all() -> dict:
                              sharding=mesh.sharding(mesh.spec(0, ndim=1))),
         jax.ShapeDtypeStruct((2,), jnp.uint32,
                              sharding=mesh.sharding(P()))).compile()
-    mem = compiled.memory_analysis()
+    mem, hlo = compiled.memory_analysis(), compiled.as_text()
     out["mlp_cell"] = {
         "table_bytes": n * d * 4,
         "argument_bytes": mem.argument_size_in_bytes,
         "temp_bytes": mem.temp_size_in_bytes,
-        "table_sized_writes": table_sized_writes(compiled.as_text(),
-                                                 {n * d}),
-        "mosaic_calls": compiled.as_text().count(chip_smoke.MOSAIC_CALL)}
+        "table_sized_writes": table_sized_writes(hlo, {n * d}),
+        "gathers_and_scatters": gathers_and_scatters(hlo),
+        "mosaic_calls": hlo.count(chip_smoke.MOSAIC_CALL)}
 
     # every builder in the registry through the real Mosaic compiler
     out["registry"] = {
@@ -324,6 +332,25 @@ def test_mlp_cell_epochs_compile_for_v5e_and_fit(compiled):
     assert 0.5 * cell["table_bytes"] <= cell["temp_bytes"] \
         < 0.6 * cell["table_bytes"]
     assert len(cell["table_sized_writes"]) == 1
+
+
+def test_mlp_cell_epochs_gather_nothing(compiled):
+    """The step picks the label's logit with a select over the class
+    columns: the 32-epoch program holds no ``gather`` and no ``scatter``.
+    With optax's ``take_along_axis`` it held one gather, ``f32[2048]`` out
+    of ``f32[2048,10]``, a third of the step on the chip (PERF.md section
+    6, PR 37)."""
+    assert compiled["mlp_cell"]["gathers_and_scatters"] == 0
+
+
+def test_gathers_and_scatters_reads_an_hlo_text():
+    hlo = """
+  %gather.13 = f32[2048]{0:T(1024)} gather(%param_0.421, %custom-call.12), offset_dims={}
+  ROOT %scatter.2 = f32[8,16]{1,0} scatter(%p, %i, %u), to_apply=%add
+  %fusion.131 = f32[2048]{0:T(1024)S(1)} fusion(%fusion.129), kind=kCustom, calls=%gather_computation
+  %all-gather.1 = f32[8,16]{1,0} all-gather(%p), dimensions={0}
+"""
+    assert gathers_and_scatters(hlo) == 2
 
 
 def test_table_sized_writes_reads_an_hlo_text():

@@ -1,12 +1,73 @@
 """MLP tests: DP-allreduce gradient equivalence + convergence."""
 
+from unittest import mock
+
 import jax
 import numpy as np
+import optax
 import pytest
 
 from harp_tpu.models import mlp as M
 
 N = 8
+
+
+def _optax_loss_fn(params, x, y, cfg):
+    """``loss_fn`` as it stood before PR 37: the same forward under
+    optax's cross-entropy, which gathers the label's logit."""
+    logits = M.forward(params, x, cfg)
+    ce = optax.softmax_cross_entropy_with_integer_labels(logits, y)
+    return ce.mean(), logits
+
+
+def _assert_leaves_equal(got, want):
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want), strict=True):
+        g, w = np.asarray(g), np.asarray(w)
+        np.testing.assert_allclose(g, w, rtol=1e-6,
+                                   atol=1e-6 * np.abs(w).max())
+
+
+@pytest.mark.parametrize("through", ["value_and_grad", "train_step",
+                                     "train_step_zero1"])
+@pytest.mark.parametrize("half", [False, True], ids=["f32", "bf16"])
+@pytest.mark.parametrize("classes", [4, 10, 1000])
+def test_loss_fn_equals_optax_cross_entropy(mesh, classes, half, through):
+    """The written-out loss (a select over the class columns picks the
+    label's logit) is optax's: the loss and every leaf of the gradient,
+    and the parameters one data-parallel step leaves, replicated
+    optimizer state or ZeRO-1."""
+    cfg = M.MLPConfig(sizes=(16, 32, classes), lr=0.1, half_precision=half,
+                      zero1=through == "train_step_zero1")
+    x, y = M.synthetic_mnist(n=64, d=16, classes=classes, seed=classes)
+    assert y.min() >= 0 and y.max() < classes
+
+    if through == "value_and_grad":
+        params = M.init_params(cfg, jax.random.key(1))
+
+        def read(loss_fn):
+            (loss, logits), grads = jax.jit(jax.value_and_grad(
+                lambda p: loss_fn(p, x, y, cfg), has_aux=True))(params)
+            return loss, logits, grads
+    else:
+        def read(loss_fn):
+            with mock.patch.object(M, "loss_fn", loss_fn):
+                tr = M.MLPTrainer(cfg, mesh, seed=1)
+                loss, acc = tr.train_batch(x, y)
+            return loss, acc, tr.params
+
+    _assert_leaves_equal(read(M.loss_fn), read(_optax_loss_fn))
+
+
+def test_loss_fn_label_outside_the_classes_selects_nothing():
+    """What ``loss_fn``'s docstring says of a label outside ``[0,
+    classes)``: no column matches, so the row's loss is its
+    log-normaliser (finite), whichever side the label lies on."""
+    cfg = M.MLPConfig(sizes=(16, 32, 4))
+    params = M.init_params(cfg, jax.random.key(0))
+    x, _ = M.synthetic_mnist(n=2, d=16, classes=4, seed=0)
+    loss, logits = M.loss_fn(params, x, np.array([-1, 4], np.int32), cfg)
+    np.testing.assert_allclose(
+        loss, jax.nn.logsumexp(logits, axis=-1).mean(), rtol=1e-6)
 
 
 def test_dp_grads_equal_fullbatch(mesh):
