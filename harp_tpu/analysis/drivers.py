@@ -591,14 +591,12 @@ def _subgraph_count():
     real driver loop; colors ride spec(1), everything else spec(0) —
     the traversal gather pattern the perfmodel's subgraph term prices.
 
-    Two lint-facing constraints: the model's `_FN_CACHE` is cleared so
+    One lint-facing constraint: the model's `_FN_CACHE` is cleared so
     every analysis layer re-traces (a cache hit skips the Python body
     and the CommLedger never records — HL301 fires on a wire that IS
-    verb-routed); and the trial chunk is 1 because the per-trial DP
-    allgather sits under `jax.vmap`, where the ledger records the
-    UNBATCHED payload — any larger chunk makes the static (batched)
-    sheet disagree with the ledger by exactly the chunk factor
-    (HL302)."""
+    verb-routed).  The trial chunk is 1; since PR 38 a chunk's trials
+    are the minor index of the tables (no `jax.vmap`), so the ledger
+    and the static sheet agree at any chunk."""
     import jax
     import jax.numpy as jnp
 

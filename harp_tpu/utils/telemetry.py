@@ -341,7 +341,10 @@ class SpanTracer:
         """``with span("epoch"): ...`` — records {span, path, t0, dur,
         depth} plus any ``attrs``; nesting comes from the live stack.  Also
         enters ``jax.profiler.TraceAnnotation(name)`` so the phase shows on
-        an XLA trace captured by :func:`harp_tpu.utils.profiling.trace`."""
+        an XLA trace captured by :func:`harp_tpu.utils.profiling.trace`.
+        An enabled span yields its ``attrs`` dict (``with span(...) as a``):
+        what is known only after the work is put there before the exit; a
+        disabled one yields ``None``."""
         if not _ENABLED:
             yield
             return
@@ -353,7 +356,7 @@ class SpanTracer:
         t0 = time.perf_counter()
         try:
             with jax.profiler.TraceAnnotation(name):
-                yield
+                yield attrs
         finally:
             dur = time.perf_counter() - t0
             self._stack.pop()

@@ -61,6 +61,8 @@ def gathers_and_scatters(hlo: str) -> int:
 def _compile_all() -> dict:
     """Every check, in the child: {check name: {program: mosaic calls}}."""
     import functools
+    import math
+    import re
 
     import jax
     import jax.numpy as jnp
@@ -243,6 +245,44 @@ def _compile_all() -> dict:
         "gathers_and_scatters": gathers_and_scatters(hlo),
         "mosaic_calls": hlo.count(chip_smoke.MOSAIC_CALL)}
 
+    # the benchmark's cell subgraph-colorings (perf/configs/
+    # subgraph-orkut-u5): one chip, the configuration's 3,072,441 vertices
+    # at 128 padded slots, the 54,903,737 tail entries every seed stages,
+    # one block of `trial_chunk` colourings of u5-tree drawn in the program.
+    # What the chip must hold: the resident graph (arguments) and the
+    # dynamic program's tables and gather tiles (temporaries).
+    from harp_tpu.models import subgraph
+    from perf import spec as perf_spec
+
+    scfg = perf_spec.load_json(os.path.join(
+        ROOT, "perf", "configs", "subgraph-orkut-u5.json"))
+    n, deg = scfg["data"]["n_vertices"], scfg["knobs"]["max_degree"]
+    tail, chunk = 54_903_737, scfg["knobs"]["trial_chunk"]
+    count = subgraph.make_colorful_count_fn(
+        subgraph.TEMPLATES[scfg["knobs"]["template"]],
+        scfg["knobs"]["n_colors"], mesh, scfg["knobs"]["overflow_algo"],
+        draw_trials=chunk)
+    compiled = count.lower(
+        sds((n, deg), jnp.int32), sds((n, deg), jnp.float32),
+        sds((tail,), jnp.int32), sds((tail,), jnp.int32),
+        sds((tail,), jnp.float32),
+        (jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=mesh.replicated()),
+         jax.ShapeDtypeStruct((), jnp.int32, sharding=mesh.replicated()))
+    ).compile()
+    mem, hlo = compiled.memory_analysis(), compiled.as_text()
+    out["subgraph_cell"] = {
+        "trial_chunk": chunk,
+        "resident_bytes": 8 * n * deg + 12 * tail,
+        "argument_bytes": mem.argument_size_in_bytes,
+        "temp_bytes": mem.temp_size_in_bytes,
+        "gathers": len(re.findall(r" = \S+ gather\(", hlo)),
+        "scatters": len(re.findall(r" = \S+ scatter\(", hlo)),
+        "loops": hlo.count(" while("),
+        "largest_gathered": max(
+            math.prod(int(d) for d in dims.split(","))
+            for dims in re.findall(r" = f32\[([\d,]+)\]\S* gather\(", hlo)),
+        "mosaic_calls": hlo.count(chip_smoke.MOSAIC_CALL)}
+
     # every builder in the registry through the real Mosaic compiler
     out["registry"] = {
         name: mosaic_calls(jax.jit(fn), [
@@ -341,6 +381,27 @@ def test_mlp_cell_epochs_gather_nothing(compiled):
     of ``f32[2048,10]``, a third of the step on the chip (PERF.md section
     6, PR 37)."""
     assert compiled["mlp_cell"]["gathers_and_scatters"] == 0
+
+
+def test_subgraph_cell_block_compiles_for_v5e_and_fits(compiled):
+    """One block of ``subgraph-colorings`` at the cell's shapes (com-Orkut's
+    3,072,441 vertices, 8 colourings): the 3.8 GB resident graph is the
+    arguments; the tables of the dynamic program, widened to whole
+    128-lane rows where they are gathered and scattered, and one gather
+    tile at a time are the temporaries, and the executable holds under
+    12 GB in all.  Two distinct sub-templates are summed over neighbours
+    (the leaf once, not three times: four gathers and two scatters, the
+    padded part and the tail of each), every one inside a loop over
+    tiles, so the largest gathered intermediate is a tile's 4,096 x 128
+    rows of 128 lanes (256 MiB) and not ``[n, 128, columns]``."""
+    cell = compiled["subgraph_cell"]
+    assert cell["mosaic_calls"] == 0
+    assert cell["resident_bytes"] <= cell["argument_bytes"] \
+        < 1.001 * cell["resident_bytes"]
+    assert cell["resident_bytes"] > 3.8e9 and cell["trial_chunk"] == 8
+    assert (cell["argument_bytes"] + cell["temp_bytes"]) / 1e9 < 12.0
+    assert (cell["gathers"], cell["scatters"], cell["loops"]) == (4, 2, 4)
+    assert cell["largest_gathered"] * 4 == 256 << 20
 
 
 def test_gathers_and_scatters_reads_an_hlo_text():

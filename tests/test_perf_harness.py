@@ -11,6 +11,7 @@ import jax
 import pytest
 
 import mlp_faults
+import subgraph_faults
 from test_perf_generators import _cases_of
 
 _CACHE_OPTIONS = ("jax_compilation_cache_dir",
@@ -41,14 +42,15 @@ def _compile_cache_as_perf_tests_have_it():
 _harness = _cases_of("test_harness.py", "checkout")
 _telemetry = _cases_of("test_program_telemetry.py", "checkout")
 # ``test_harness.py`` keys its toy shapes by driver name and has none for
-# the "mlp" driver (PR 36 may add files under perf/ and edit none): both
-# instances of it get the entry before their cases run, the one loaded
-# above and the bare ``test_harness`` that ``test_program_telemetry.py``
-# imports its helpers from
+# the "mlp" and "subgraph" drivers (PRs 36 and 38 may add files under
+# perf/ and edit none): both instances of it get the entries before their
+# cases run, the one loaded above and the bare ``test_harness`` that
+# ``test_program_telemetry.py`` imports its helpers from
 for _case in (_harness["test_cell_rehearses_untraced"],
               _telemetry["test_other_cells_print_none_of_the_six"]):
-    _case.__globals__["_tiny"].__globals__["TINY"].setdefault(
-        "mlp", mlp_faults.TINY)
+    _toy = _case.__globals__["_tiny"].__globals__["TINY"]
+    _toy.setdefault("mlp", mlp_faults.TINY)
+    _toy.setdefault("subgraph", subgraph_faults.TINY)
 globals().update(_harness)
 globals().update(_telemetry)
 globals().update(_cases_of("test_trace_reduce.py"))
