@@ -2744,3 +2744,35 @@ print(f"lda topic-major storage: 4 sweeps on {_tm_m.mesh.num_workers} "
       f"workers, tables = the chain's counts, ll {_tm_ll0:.3f} -> "
       f"{_tm_m.log_likelihood():.3f}")
 print("DRIVE OK round-41")
+
+# --- round-42 (PR 39): the neighbour sum gathers only the slots its rows
+# hold.  The CLI's default graph (100,000 vertices, mean degree 16, padded
+# to 64) through the public pair on one worker, at the program's own tiles:
+# the plan set_graph makes of the installed rows has a few segments near
+# the mean, the five installed arrays are as they were, and the counts are
+# those of the program without a plan (every row at the whole 64 slots).
+_do_rng = np.random.default_rng(3)
+_do_edges = _do_rng.integers(0, 100_000, (800_000, 2))
+_do_one = WorkerMesh(jax.devices()[:1])
+_do_c = SG.SubgraphCounter(SG.SubgraphConfig(
+    template="u5-tree", n_trials=2, trial_chunk=2, max_degree=64, seed=4),
+    _do_one)
+assert _do_c.set_graph(_do_edges, 100_000) == 0
+_do_slots = SG.plan_slots(_do_c.plan)
+assert 1 < len(_do_c.plan) <= 8 and _do_c.plan[-1][1] == 100_000
+assert all(w % 8 == 0 for _, _, w in _do_c.plan)
+assert 1_600_000 <= _do_slots < 0.4 * 64 * 100_000
+_do_nbr, _do_msk, *_do_tail = _do_c.installed()
+assert _do_nbr.shape == _do_msk.shape == (100_000, 64) and len(_do_tail) == 3
+assert (np.asarray(_do_msk).sum(1)
+        == np.bincount(_do_edges.ravel(), minlength=100_000)).all()
+_do_whole = SG.make_colorful_count_fn(_do_c.tpl, _do_c.k, _do_one,
+                                      draw_trials=2)
+_do_want = np.asarray(_do_whole(*_do_c.installed(),
+                                (_do_c._key, np.int32(0))))
+_do_got = _do_c.count_colorings()
+np.testing.assert_allclose(_do_got, _do_want, rtol=1e-6)
+print(f"subgraph degree order: {len(_do_c.plan)} segments "
+      f"{[w for _, _, w in _do_c.plan]}, {_do_slots:,} of 6,400,000 padded "
+      f"slots gathered, counts = the whole width's")
+print("DRIVE OK round-42")

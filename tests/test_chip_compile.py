@@ -58,6 +58,38 @@ def gathers_and_scatters(hlo: str) -> int:
     return len(re.findall(r" = \S+ (?:gather|scatter)\(", hlo))
 
 
+def padded_part_loops(hlo: str) -> list:
+    """``[rows, tile_rows, slots]`` of every ``while`` loop of a compiled
+    module's text that carries a segment of the degree order (a 1-D
+    ``s32`` array) and gathers table rows by a ``[tile_rows, slots]`` tile
+    of neighbour ids inside its body (``f32[tile_rows, slots, 128]`` rows
+    or ``u32[tile_rows, slots]`` packed colours)."""
+    import re
+
+    comps = {m.group(1): m.group(0) for m in re.finditer(
+        r"^(?:ENTRY )?%([\w.\-]+) \(.*?^\}", hlo, re.M | re.S)}
+
+    def reach(name, seen):
+        if name in comps and name not in seen:
+            seen.add(name)
+            for callee in re.findall(
+                    r"(?:calls|to_apply|body|condition)=%([\w.\-]+)",
+                    comps[name]):
+                reach(callee, seen)
+        return seen
+
+    found = []
+    for line in hlo.splitlines():
+        m = re.search(r"^(.*) while\(.*body=%([\w.\-]+)", line)
+        if not m:
+            continue
+        carried = re.findall(r" s32\[(\d+)\]", m.group(1))
+        body = "".join(comps[c] for c in reach(m.group(2), set()))
+        tiles = re.findall(r" = (?:f32\[(\d+),(\d+),128\]|u32\[(\d+),(\d+)\])"
+                           r"\S* gather\(", body)
+        for a, b, c, d in tiles:
+            found.append([int(carried[0]), int(a or c), int(b or d)])
+    return found
 def _compile_all() -> dict:
     """Every check, in the child: {check name: {program: mosaic calls}}."""
     import functools
@@ -248,31 +280,38 @@ def _compile_all() -> dict:
     # the benchmark's cell subgraph-colorings (perf/configs/
     # subgraph-orkut-u5): one chip, the configuration's 3,072,441 vertices
     # at 128 padded slots, the 54,903,737 tail entries every seed stages,
-    # one block of `trial_chunk` colourings of u5-tree drawn in the program.
-    # What the chip must hold: the resident graph (arguments) and the
-    # dynamic program's tables and gather tiles (temporaries).
+    # one block of `trial_chunk` colourings of u5-tree drawn in the program,
+    # the padded part summed by the plan `set_graph` makes of the
+    # configuration's degree sequence.  What the chip must hold: the
+    # resident graph and its degree order (arguments) and the dynamic
+    # program's tables and gather tiles (temporaries).
     from harp_tpu.models import subgraph
+    from perf import graph_like
     from perf import spec as perf_spec
 
     scfg = perf_spec.load_json(os.path.join(
         ROOT, "perf", "configs", "subgraph-orkut-u5.json"))
     n, deg = scfg["data"]["n_vertices"], scfg["knobs"]["max_degree"]
     tail, chunk = 54_903_737, scfg["knobs"]["trial_chunk"]
+    plan = subgraph.degree_plan(np.sort(np.minimum(
+        graph_like.degree_sequence(scfg["data"]), deg))[None], deg)
     count = subgraph.make_colorful_count_fn(
         subgraph.TEMPLATES[scfg["knobs"]["template"]],
         scfg["knobs"]["n_colors"], mesh, scfg["knobs"]["overflow_algo"],
-        draw_trials=chunk)
+        draw_trials=chunk, plan=plan)
     compiled = count.lower(
         sds((n, deg), jnp.int32), sds((n, deg), jnp.float32),
         sds((tail,), jnp.int32), sds((tail,), jnp.int32),
-        sds((tail,), jnp.float32),
+        sds((tail,), jnp.float32), sds((n,), jnp.int32),
         (jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=mesh.replicated()),
          jax.ShapeDtypeStruct((), jnp.int32, sharding=mesh.replicated()))
     ).compile()
     mem, hlo = compiled.memory_analysis(), compiled.as_text()
     out["subgraph_cell"] = {
         "trial_chunk": chunk,
-        "resident_bytes": 8 * n * deg + 12 * tail,
+        "plan": plan,
+        "padded_part_loops": padded_part_loops(hlo),
+        "resident_bytes": 8 * n * deg + 12 * tail + 4 * n,
         "argument_bytes": mem.argument_size_in_bytes,
         "temp_bytes": mem.temp_size_in_bytes,
         "gathers": len(re.findall(r" = \S+ gather\(", hlo)),
@@ -385,14 +424,17 @@ def test_mlp_cell_epochs_gather_nothing(compiled):
 
 def test_subgraph_cell_block_compiles_for_v5e_and_fits(compiled):
     """One block of ``subgraph-colorings`` at the cell's shapes (com-Orkut's
-    3,072,441 vertices, 8 colourings): the 3.8 GB resident graph is the
-    arguments; the tables of the dynamic program, widened to whole
-    128-lane rows where they are gathered and scattered, and one gather
-    tile at a time are the temporaries, and the executable holds under
-    12 GB in all.  Two distinct sub-templates are summed over neighbours
-    (the leaf once, not three times: four gathers and two scatters, the
-    padded part and the tail of each), every one inside a loop over
-    tiles, so the largest gathered intermediate is a tile's 4,096 x 128
+    3,072,441 vertices, 8 colourings): the 3.8 GB resident graph and its
+    12 MB degree order are the arguments; the tables of the dynamic
+    program, widened to whole 128-lane rows where they are gathered and
+    scattered, and one gather tile at a time are the temporaries, and
+    the executable holds under 12 GB in all.  Two distinct sub-templates
+    are summed over neighbours (the leaf once, not three times), each
+    over the plan's 16 segments and the tail, every one a loop over
+    tiles: 34 loops; a segment's loop gathers its tile's rows of ``nbr``
+    and of ``msk`` and the table's rows and sets the sums back into
+    vertex order, the tail's gathers and scatter-adds: 98 gathers, 34
+    scatters.  The largest gathered intermediate is a tile's 4,096 x 128
     rows of 128 lanes (256 MiB) and not ``[n, 128, columns]``."""
     cell = compiled["subgraph_cell"]
     assert cell["mosaic_calls"] == 0
@@ -400,8 +442,55 @@ def test_subgraph_cell_block_compiles_for_v5e_and_fits(compiled):
         < 1.001 * cell["resident_bytes"]
     assert cell["resident_bytes"] > 3.8e9 and cell["trial_chunk"] == 8
     assert (cell["argument_bytes"] + cell["temp_bytes"]) / 1e9 < 12.0
-    assert (cell["gathers"], cell["scatters"], cell["loops"]) == (4, 2, 4)
+    assert (cell["gathers"], cell["scatters"], cell["loops"]) == (98, 34, 34)
     assert cell["largest_gathered"] * 4 == 256 << 20
+
+
+def test_subgraph_cell_block_gathers_the_plans_slots(compiled):
+    """Every segment of the plan is one loop in each of the two neighbour
+    sums, over tiles of ``[rows, width]`` neighbour ids, ``width`` the
+    segment's and the rows ``_segment_tile``'s (as many as 256 MiB and
+    32,768 leave room for, evened out over the segment's tiles, the ids
+    no multiple of 8 groups of 128): the ids those loops gather, tile by
+    tile (the last tile of a loop is moved back and gathers some rows
+    again), are the plan's 188,526,608 slots and under 0.1% more, where
+    the whole width's are 393,272,448."""
+    from harp_tpu.models import subgraph
+
+    cell = compiled["subgraph_cell"]
+    plan = [tuple(seg) for seg in cell["plan"]]
+    assert len(plan) == 16 and subgraph.plan_slots(plan) == 188_526_608
+    want = [[stop - start, subgraph._segment_tile(
+        stop - start, width, subgraph._gather_tiles(width, 128)[0]), width]
+        for start, stop, width in plan]
+    assert sorted(cell["padded_part_loops"]) == sorted(want + want)
+    assert [t for _, t, _ in want][:3] + [want[-1][1]] == [
+        27_712, 32_728, 16_288, 4_090]
+    gathered = sum(-(-rows // tile) * tile * width
+                   for rows, tile, width in cell["padded_part_loops"])
+    assert 2 * 188_526_608 <= gathered < 1.001 * 2 * 188_526_608
+
+
+def test_padded_part_loops_reads_an_hlo_text():
+    hlo = """
+%gathers (p: f32[100,128], i: s32[8,16]) -> f32[8,16,128] {
+  ROOT %gather.1 = f32[8,16,128]{2,1,0} gather(%p, %i), offset_dims={2}
+}
+
+%body.1 (arg: (s32[], s32[50], f32[100,128])) -> (s32[], s32[50]) {
+  %fusion.1 = f32[8,16,128]{2,1,0} fusion(%p, %i), kind=kCustom, calls=%gathers
+}
+
+%tail (arg: (s32[], f32[100,128])) -> (s32[]) {
+  %gather.2 = f32[64,128]{1,0} gather(%p, %j), offset_dims={1}
+}
+
+ENTRY %main () -> f32[] {
+  %while.1 = (s32[], s32[50]{0}, f32[100,128]{1,0}) while(%t), condition=%c, body=%body.1
+  %while.2 = (s32[], f32[100,128]{1,0}) while(%u), condition=%c, body=%tail
+}
+"""
+    assert padded_part_loops(hlo) == [[50, 8, 16]]
 
 
 def test_gathers_and_scatters_reads_an_hlo_text():

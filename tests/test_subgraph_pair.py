@@ -30,6 +30,24 @@ def _cfg(**kw):
                                 "max_degree": 8, "seed": 2147489005, **kw})
 
 
+def _configuration_data():
+    """The ``data`` block of the cell's configuration file."""
+    import os
+
+    from perf import spec
+
+    return spec.load_json(os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "perf", "configs", "subgraph-orkut-u5.json"))["data"]
+
+
+def _spans_by_name():
+    by_name = {}
+    for r in telemetry.tracer.records:
+        by_name.setdefault(r["span"], []).append(r)
+    return by_name
+
+
 def _blocks(counter, n):
     return np.concatenate([counter.count_colorings() for _ in range(n)])
 
@@ -69,30 +87,36 @@ def test_run_is_one_dispatch_and_one_readback_and_uploads_nothing(toy_edges):
         for _ in range(3):
             counter.count_colorings()
     assert seen == {"dispatch": 3, "readback": 3, "h2d": 0, "compile": 0}
-    # a second counter of the same shape finds the program again
+    # a second counter of the same shape and plan finds the program again
     again = SG.SubgraphCounter(_cfg(seed=5), WorkerMesh(jax.devices()[:4]))
+    again.set_graph(toy_edges, 300)
     assert again._fn is counter._fn
 
 
 @pytest.mark.parametrize("algo", ["segment", "onehot"])
 def test_row_tiled_neighbour_sum_equals_the_untiled(algo, toy_edges,
                                                     monkeypatch):
-    """Tiles of 8 rows and 64 tail entries (37.5 row tiles, 20.9 entry
-    tiles a worker: both last tiles overlap the one before) against one
-    tile of everything: the same bits."""
+    """Tiles of 8 rows and 64 tail entries against one tile of
+    everything: the same bits.  At ``max_degree`` 16 the tiled counter's
+    plan has two segments, of 186 and 114 rows (23.25 and 14.25 row tiles:
+    both last tiles overlap the one before, as the last of the tail's 11.03
+    entry tiles does), where the untiled one's is one segment of the whole width:
+    the program without a plan."""
     mesh = WorkerMesh(jax.devices()[:1])
     cfg = _cfg(overflow_algo=algo, overflow_row_tile=8,
-               overflow_entry_tile=16)
+               overflow_entry_tile=16, max_degree=16)
     whole = SG.SubgraphCounter(cfg, mesh)
     whole.set_graph(toy_edges, 300)
-    assert SG._gather_tiles(8, 40) == (65536, 524288)
+    assert SG._gather_tiles(8, 40) == (32768, 524288)
+    assert whole.plan == ((0, 300, 16),) and whole._order == ()
     want = _blocks(whole, 2)
-    monkeypatch.setattr(SG, "_gather_tiles", lambda max_degree, width: (8, 64))
+    monkeypatch.setattr(SG, "_gather_tiles", lambda slots, width: (8, 64))
     SG._FN_CACHE.clear()  # a program is traced with the tiles of its day
     tiled = SG.SubgraphCounter(cfg, mesh)
-    assert tiled._fn is not whole._fn
     tiled.set_graph(toy_edges, 300)
-    text = tiled._fn.lower(*tiled.installed(),
+    assert tiled._fn is not whole._fn
+    assert tiled.plan == ((0, 186, 8), (186, 300, 16))
+    text = tiled._fn.lower(*tiled.installed(), *tiled._order,
                            (tiled._key, np.int32(0))).as_text()
     assert "while" in text
     assert (_blocks(tiled, 2) == want).all()
@@ -105,6 +129,150 @@ def test_gather_tiles_follow_the_shapes():
     assert SG._gather_tiles(128, 80) == (4096, 524288)
     assert SG._gather_tiles(128, 40) == (4096, 524288)
     assert SG._gather_tiles(64, 280) == (2048, 131072)
+    # a segment's tile is as many rows as its width leaves room for, up
+    # to 32,768
+    assert SG._gather_tiles(120, 80) == (4096, 524288)
+    assert SG._gather_tiles(16, 80) == (32768, 524288)
+    assert SG._gather_tiles(8, 80) == (32768, 524288)
+
+
+# ---- the degree order and its plan ------------------------------------------
+
+def _hub_on_one_worker():
+    """64 vertices: vertex 1 joined to all the others (worker 0 of four),
+    the rest a ring: every other worker's rows hold three."""
+    ring = [(i, i + 1) for i in range(2, 63)] + [(63, 0), (0, 2)]
+    return np.asarray([(1, i) for i in range(64) if i != 1] + ring,
+                      np.int32), 64
+
+
+def _all_rows_full():
+    """The complete graph on 16 vertices: 15 neighbours each, over
+    ``max_degree`` 8 on every row."""
+    return np.asarray([(i, j) for i in range(16) for j in range(i)],
+                      np.int32), 16
+
+
+@pytest.mark.parametrize("graph", ["toy", "hub", "full"])
+@pytest.mark.parametrize("algo", ["segment", "onehot"])
+@pytest.mark.parametrize("workers", [1, 4])
+def test_degree_ordered_sum_equals_the_whole_width(workers, algo, graph,
+                                                   toy_edges, monkeypatch):
+    """The installed program (rows in degree order, each segment at its
+    own width) against the program without a plan (every row at
+    ``max_degree``, in vertex order) on the same installed arrays and
+    colours: counts of this size are whole numbers in float32, the same
+    bits.  The plan is the widest over the workers: the hub's worker
+    widens every worker's last segment."""
+    edges, n = {"toy": (toy_edges, 300), "hub": _hub_on_one_worker(),
+                "full": _all_rows_full()}[graph]
+    monkeypatch.setattr(SG, "_gather_tiles", lambda slots, width: (8, 64))
+    SG._FN_CACHE.clear()
+    mesh = WorkerMesh(jax.devices()[:workers])
+    cfg = _cfg(overflow_algo=algo, overflow_row_tile=8,
+               overflow_entry_tile=16,
+               max_degree=8 if graph == "full" else 24)
+    counter = SG.SubgraphCounter(cfg, mesh)
+    counter.set_graph(edges, n)
+    loc, plan = counter.n_pad // workers, counter.plan
+    assert plan[0][0] == 0 and plan[-1][1] == loc
+    assert all(a[1] == b[0] and a[2] < b[2] for a, b in zip(plan, plan[1:]))
+    msk = np.asarray(counter.installed()[1])
+    counts = (msk > 0).sum(1).reshape(workers, loc)
+    if graph == "full":
+        assert plan == ((0, loc, 8),) and counter._order == ()
+    else:
+        (order,) = counter._order
+        order = np.asarray(order).reshape(workers, loc)
+        assert (np.sort(order, 1) == np.arange(loc)).all()  # a permutation
+        ranked = np.take_along_axis(counts, order, 1)
+        assert (np.diff(ranked, axis=1) >= 0).all()
+        for start, stop, width in plan:
+            assert width % 8 == 0 and width <= 24
+            assert ranked[:, start:stop].max() <= width
+    if graph == "hub" and workers == 4:
+        assert plan == ((0, 15, 8), (15, 16, 24)) \
+            and counts.max(1).tolist() == [24, 3, 3, 3]
+    whole = SG.make_colorful_count_fn(
+        counter.tpl, counter.k, mesh, algo, cfg.overflow_row_tile,
+        draw_trials=counter.chunk)
+    for block in range(2):
+        want = np.asarray(whole(*counter.installed(),
+                                (counter._key, np.int32(block))))
+        assert (want > 0).any()
+        assert (counter.count_colorings() == want).all()
+    SG._FN_CACHE.clear()
+
+
+@pytest.mark.parametrize("workers", [1, 4])
+def test_degree_plan_at_the_configurations_size(workers):
+    """The plan ``set_graph`` makes of the configuration's own degree
+    sequence: every position in one segment, every row no wider than its
+    segment, and under half of today's ``n x 128`` padded slots gathered
+    (188,526,608 of 393,272,448 on one worker)."""
+    data = _configuration_data()
+    counts = np.minimum(graph_like.degree_sequence(data), 128)
+    counts = np.sort(np.pad(counts, (0, -len(counts) % workers))
+                     .reshape(workers, -1), axis=1)
+    plan = SG.degree_plan(counts, 128)
+    assert plan[0][0] == 0 and plan[-1][1] == counts.shape[1]
+    assert all(a[1] == b[0] for a, b in zip(plan, plan[1:]))
+    assert [w for _, _, w in plan] == list(range(8, 129, 8))
+    for start, stop, width in plan:
+        assert counts[:, start:stop].max() <= width
+    slots = workers * SG.plan_slots(plan)
+    assert slots < 0.52 * 393_272_448
+    assert slots == {1: 188_526_608, 4: 188_653_376}[workers]
+    # what the skew record then states: 3.7% of the executed slots are
+    # padding (3.8% over four workers), where 47.7% of the staged were
+    assert 1 - 234_370_166 / (slots + 54_903_737) < 0.04
+
+
+def test_degree_plan_merges_short_segments_and_keeps_full_rows_whole():
+    # two rows of 3 are no tile of 32,768 x 8 slots: they ride with the 16s
+    few = np.asarray([[3, 3] + [12] * 70_000 + [40] * 10])
+    assert SG.degree_plan(few, 64) == ((0, 70_002, 16), (70_002, 70_012, 40))
+    assert SG.plan_slots(SG.degree_plan(few, 64)) == 70_002 * 16 + 400
+    assert SG.degree_plan(np.full((4, 100), 64), 64) == ((0, 100, 64),)
+    # never over max_degree, which need be no multiple of 8; a row of
+    # nothing still takes a slot
+    assert SG.degree_plan(np.asarray([[0, 4, 4]]), 4) == ((0, 3, 4),)
+    # the widest over the workers, position by position
+    assert SG.degree_plan(np.asarray([[1, 2, 3], [1, 2, 30]]), 64) \
+        == ((0, 3, 32),)
+
+
+@pytest.mark.parametrize("rows, slots, most, tile", [
+    (519_348, 128, 4096, 4090),   # 127 tiles; 4,090 groups of 128 ids
+    (156_326, 64, 8192, 7818),    # 20 tiles of 3,909 groups, not 3,912
+    (261_827, 40, 8192, 7952),    # 33 tiles: 32 would be 8,184 rows, ragged
+    (72_000, 104, 4096, 4000),    # 18 even tiles as they are
+    (300, 8, 8, 8),               # no tile of 8 rows is whole groups:
+    (66, 16, 8, 8),               # the even ones
+    (10, 4, 8, 5)])
+def test_segment_tiles_are_even_and_no_multiple_of_8_groups(rows, slots,
+                                                            most, tile):
+    assert SG._segment_tile(rows, slots, most) == tile
+    assert tile <= most and -(-rows // tile) * tile < rows + 0.01 * rows + 8
+    if most > 128:
+        assert tile * slots % 128 == 0 and tile * slots // 128 % 8
+
+
+def test_installed_returns_the_five_arrays_as_they_were_staged(toy_edges):
+    """The order is the counter's own: ``installed()`` is ``(nbr, msk,
+    o_nbr, o_row, o_msk)`` in vertex order, row ``v`` of ``msk`` holding
+    ``min(degree[v], max_degree)`` ones, the tail the rest."""
+    deg = graph_like.degree_sequence(TOY)
+    counter = SG.SubgraphCounter(_cfg(max_degree=16),
+                                 WorkerMesh(jax.devices()[:4]))
+    counter.set_graph(toy_edges, 300)
+    nbr, msk, o_nbr, o_row, o_msk = counter.installed()
+    assert nbr.shape == msk.shape == (300, 16)
+    assert o_nbr.shape == o_row.shape == o_msk.shape
+    msk = np.asarray(msk)
+    assert ((msk > 0).sum(1) == np.minimum(deg, 16)).all()
+    assert (msk[:, :-1] >= msk[:, 1:]).all()  # a row's entries come first
+    assert np.asarray(o_msk).sum() == np.maximum(deg - 16, 0).sum()
 
 
 def test_block_colour_query_returns_the_colours_the_block_used(toy_edges):
@@ -203,31 +371,39 @@ def test_reference_holds_all_colour_sets_and_imports_no_program():
 
 # ---- spans, the skew record, the ledger ------------------------------------
 
-def test_install_and_run_leave_their_spans_and_records(toy_edges):
+def test_install_and_run_leave_their_spans_and_records(toy_edges,
+                                                       monkeypatch):
+    """At ``max_degree`` 16 and tiles of 8 rows a worker's 75 rows are
+    two segments, 41 rows at 8 slots and 34 at 16: 3,488 padded slots
+    over the four workers where 4,800 are staged."""
+    monkeypatch.setattr(SG, "_gather_tiles", lambda slots, width: (8, 64))
     SG._FN_CACHE.clear()  # the ledger prices a program when it is traced
     with telemetry.scope(True):
-        counter = SG.SubgraphCounter(_cfg(), WorkerMesh(jax.devices()[:4]))
+        counter = SG.SubgraphCounter(_cfg(max_degree=16),
+                                     WorkerMesh(jax.devices()[:4]))
         counter.set_graph(toy_edges, 300)
         for _ in range(3):
             counter.count_colorings()
-        spans = telemetry.tracer.records
-        by_name = {}
-        for r in spans:
-            by_name.setdefault(r["span"], []).append(r)
+        by_name = _spans_by_name()
         install = by_name["subgraph.install"][0]
         assert (install["vertices"], install["entries"],
-                install["overflow_entries"]) == (300, 3000, 1336)
+                install["overflow_entries"]) == (300, 3000, 706)
         tail = counter.installed()[2].size
-        assert install["bytes"] == 8 * 300 * 8 + 12 * tail
+        assert counter.plan == ((0, 41, 8), (41, 75, 16))
+        assert (install["segments"], install["slots_staged"],
+                install["slots_executed"]) == (2, 4800 + tail, 3488 + tail)
+        # the five arrays and the order
+        assert install["bytes"] == 8 * 300 * 16 + 12 * tail + 4 * 300
         for child in ("subgraph.pad_csr", "subgraph.overflow",
-                      "mesh.shard_array"):
+                      "subgraph.order", "mesh.shard_array"):
             assert all(r["path"].startswith("subgraph.install/")
                        for r in by_name[child])
-        assert len(by_name["mesh.shard_array"]) == 5
+        assert len(by_name["mesh.shard_array"]) == 6
         assert [r["trials"] for r in by_name["subgraph.colorings"]] == [4] * 3
+        # the skew record states the slots a neighbour sum executes
         rec = skew.ledger.summary()["subgraph.partition"]
         assert rec["padding_frac"] == pytest.approx(
-            1 - 3000 / (300 * 8 + tail))
+            1 - 3000 / (3488 + tail))
         # two distinct child shapes, two allgathers of a worker's 75
         # rows: the leaf's packed colours (one word for the 4
         # colourings), the star's 10 columns x 4 colourings; and the
@@ -236,6 +412,26 @@ def test_install_and_run_leave_their_spans_and_records(toy_edges):
         assert led["executions"] == 3
         assert led["bytes_per_execution"] == 4 * (75 * (1 + 10 * 4) + 4)
     SG._FN_CACHE.clear()
+
+
+def test_rows_all_full_install_as_before(toy_edges):
+    """At ``max_degree`` 8 and the program's own tiles the toy graph's 300
+    rows are one segment of the whole width: five placements, no order,
+    and executed slots = staged slots."""
+    with telemetry.scope(True):
+        counter = SG.SubgraphCounter(_cfg(), WorkerMesh(jax.devices()[:4]))
+        counter.set_graph(toy_edges, 300)
+        by_name = _spans_by_name()
+        install = by_name["subgraph.install"][0]
+        tail = counter.installed()[2].size
+        assert counter.plan == ((0, 75, 8),) and counter._order == ()
+        assert install["slots_executed"] == install["slots_staged"] \
+            == 300 * 8 + tail
+        assert install["segments"] == 1
+        assert install["bytes"] == 8 * 300 * 8 + 12 * tail
+        assert len(by_name["mesh.shard_array"]) == 5
+        assert skew.ledger.summary()["subgraph.partition"][
+            "padding_frac"] == pytest.approx(1 - 3000 / (300 * 8 + tail))
 
 
 def test_spans_cost_one_flag_test_when_off(toy_edges):
@@ -274,12 +470,7 @@ def test_degree_sequence_at_the_configurations_size():
     """The configuration's counts, to the entry (com-Orkut's vertices,
     edges and ends), and the split at ``max_degree`` 128 that every seed
     then has."""
-    from perf import spec
-    import os
-
-    data = spec.load_json(os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        "perf", "configs", "subgraph-orkut-u5.json"))["data"]
+    data = _configuration_data()
     deg = graph_like.degree_sequence(data)
     assert len(deg) == 3_072_441 and deg.sum() == 234_370_166
     assert (deg.min(), deg.max()) == (1, 33_313)
