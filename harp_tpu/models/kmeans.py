@@ -117,26 +117,36 @@ def _partials_block(points, centroids, c2, mask=None):
     ``mask`` (optional [b], 0/1): rows with mask 0 contribute nothing —
     the streaming path pads its tail chunk to a fixed shape with these.
     """
-    dots = jax.lax.dot_general(
-        points, centroids.T, (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )  # [b, k]
-    scores = c2[None, :] - 2.0 * dots
-    assign = jnp.argmin(scores, axis=1)
-    onehot = jax.nn.one_hot(assign, c2.shape[0], dtype=points.dtype)
+    with jax.named_scope("kmeans.assign"):
+        dots = jax.lax.dot_general(
+            points, centroids.T, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )  # [b, k]
+        scores = c2[None, :] - 2.0 * dots
+        assign = jnp.argmin(scores, axis=1)
+    with jax.named_scope("kmeans.sums"):
+        onehot = jax.nn.one_hot(assign, c2.shape[0], dtype=points.dtype)
     if mask is None:
-        x2 = (points.astype(jnp.float32) ** 2).sum()
-        inertia = x2 + scores.min(axis=1).sum()
+        # Σx²: XLA hoists it out of the Lloyd loop, fused with the bf16
+        # copy of the points that the two dots read
+        with jax.named_scope("kmeans.cast"):
+            x2 = (points.astype(jnp.float32) ** 2).sum()
+        with jax.named_scope("kmeans.assign"):
+            inertia = x2 + scores.min(axis=1).sum()
     else:
         w = mask.astype(jnp.float32)
-        x2 = ((points.astype(jnp.float32) ** 2).sum(1) * w).sum()
-        inertia = x2 + (scores.min(axis=1) * w).sum()
-        onehot = onehot * mask.astype(onehot.dtype)[:, None]
-    sums = jax.lax.dot_general(
-        onehot, points, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )  # [k, d]
-    counts = onehot.sum(0).astype(jnp.float32)
+        with jax.named_scope("kmeans.cast"):
+            x2 = ((points.astype(jnp.float32) ** 2).sum(1) * w).sum()
+        with jax.named_scope("kmeans.assign"):
+            inertia = x2 + (scores.min(axis=1) * w).sum()
+        with jax.named_scope("kmeans.sums"):
+            onehot = onehot * mask.astype(onehot.dtype)[:, None]
+    with jax.named_scope("kmeans.sums"):
+        sums = jax.lax.dot_general(
+            onehot, points, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )  # [k, d]
+        counts = onehot.sum(0).astype(jnp.float32)
     return sums, counts, inertia
 
 
@@ -314,7 +324,8 @@ def kmeans_step(points, centroids, cfg: KMeansConfig, x2=None):
         sums, counts, partial_inertia = kmeans_kernel.kmeans_partials(
             points, centroids, interpret=interpret_default())
     elif block <= 0 or block >= n:
-        c2 = (centroids.astype(jnp.float32) ** 2).sum(-1)  # [k]
+        with jax.named_scope("kmeans.assign"):
+            c2 = (centroids.astype(jnp.float32) ** 2).sum(-1)  # [k]
         sums, counts, partial_inertia = _partials_block(points, centroids, c2)
     else:
         assert n % block == 0, "block_points must divide the local shard size"
@@ -357,14 +368,17 @@ def _combine_partials(sums, counts, partial_inertia, centroids, cfg, nw):
         inertia = C.allreduce(partial_inertia)
         return new_centroids, inertia
 
-    if cfg.psum_schedule == "hier":
-        # the planner's hierarchical two-stage psum (fail-closed flip
-        # candidate kmeans_hier_psum; see KMeansConfig.psum_schedule)
-        sums, counts, inertia = C.allreduce_hier(
-            (sums, counts, partial_inertia))
-    else:
-        sums, counts, inertia = C.allreduce((sums, counts, partial_inertia))
-    return normalize(sums, counts, centroids), inertia
+    with jax.named_scope("kmeans.combine"):
+        if cfg.psum_schedule == "hier":
+            # the planner's hierarchical two-stage psum (fail-closed flip
+            # candidate kmeans_hier_psum; see KMeansConfig.psum_schedule)
+            sums, counts, inertia = C.allreduce_hier(
+                (sums, counts, partial_inertia))
+        else:
+            sums, counts, inertia = C.allreduce(
+                (sums, counts, partial_inertia))
+    with jax.named_scope("kmeans.update"):
+        return normalize(sums, counts, centroids), inertia
 
 
 def _effective_variant(variant: str, k: int, num_workers: int) -> str:

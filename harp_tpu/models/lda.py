@@ -445,14 +445,15 @@ def _sample_runs_pallas(NdkT, NwkT, Nk, z, cd, cw, meta, key, cfg: LDAConfig,
     def run_body(st, inp):
         NdkT, NwkT, dNk_acc = st
         r, zc, cdc, cwc, mc, k = inp
-        NdkT, NwkT, z_new, dNk = cgs_run_update(
-            NdkT, NwkT, Nk + dNk_acc, zc, cdc, cwc, mc, r, k,
-            alpha=cfg.alpha, beta=cfg.beta, vbeta=vocab_size * cfg.beta,
-            d_tile=cfg.d_tile, w_tile=cfg.w_tile,
-            interpret=interpret_default(),
-            exact_gathers=cfg.pallas_exact_gathers,
-            ndk_count_bound=count_bounds[0],
-            nwk_count_bound=count_bounds[1])
+        with jax.named_scope("lda.kernel"):
+            NdkT, NwkT, z_new, dNk = cgs_run_update(
+                NdkT, NwkT, Nk + dNk_acc, zc, cdc, cwc, mc, r, k,
+                alpha=cfg.alpha, beta=cfg.beta, vbeta=vocab_size * cfg.beta,
+                d_tile=cfg.d_tile, w_tile=cfg.w_tile,
+                interpret=interpret_default(),
+                exact_gathers=cfg.pallas_exact_gathers,
+                ndk_count_bound=count_bounds[0],
+                nwk_count_bound=count_bounds[1])
         return (NdkT, NwkT, dNk_acc + dNk), z_new
 
     (NdkT, NwkT, dNk), z_new = lax.scan(
@@ -506,15 +507,17 @@ def _epoch_device_fn(mesh: WorkerMesh, cfg: LDAConfig, vocab_size: int,
         # budgets stay 1 dispatch / 1 readback, tests/test_flightrec.py).
         # Unconditional: a telemetry-gated output would make the traced
         # program differ with the flag (zero-cost contract).
-        valid = ((tokens[0] < cfg.d_tile) if tiled
-                 else (tokens[2] > 0)).sum()
-        work_w = C.allgather(valid.astype(jnp.float32)[None])
+        with jax.named_scope("lda.touched"):
+            valid = ((tokens[0] < cfg.d_tile) if tiled
+                     else (tokens[2] > 0)).sum()
+            work_w = C.allgather(valid.astype(jnp.float32)[None])
 
         def step(st, computing, t, slot=None):
             Ndk, Nk, z_grid, key = st
             chunk_idx = resident_chunk_index(t, nc)
-            blk = jax.tree.map(lambda a: a[chunk_idx], tokens)
-            z_blk = z_grid[chunk_idx]
+            with jax.named_scope("lda.slices"):
+                blk = jax.tree.map(lambda a: a[chunk_idx], tokens)
+                z_blk = z_grid[chunk_idx]
             key, sub = jax.random.split(key)
 
             if pallas:
@@ -607,18 +610,23 @@ def _epoch_device_fn(mesh: WorkerMesh, cfg: LDAConfig, vocab_size: int,
                 )
                 z_new = z_new.reshape(-1)
             # push/pull residue: topic totals sync via psum of deltas
-            Nk = Nk + C.allreduce(dNk)
-            z_grid = z_grid.at[chunk_idx].set(z_new)
+            with jax.named_scope("lda.nk"):
+                Nk = Nk + C.allreduce(dNk)
+            with jax.named_scope("lda.chain"):
+                z_grid = z_grid.at[chunk_idx].set(z_new)
             return (Ndk, Nk, z_grid, key), computing
 
-        if pallas:
-            (Ndk, Nk, z_grid, key), Nwk_slice = rotate_pipeline_resident(
-                step, (Ndk, Nk, z_grid, key), Nwk_slice,
-                n_chunks=nc, wire=cfg.rotate_wire, chunk_axis=1)
-        else:
-            (Ndk, Nk, z_grid, key), Nwk_slice = rotate_pipeline(
-                step, (Ndk, Nk, z_grid, key), Nwk_slice,
-                n_chunks=nc, wire=cfg.rotate_wire)
+        # the whole pipeline: alone on an op's path it is the rotation's
+        # own work (the in-flight chunk cut out and written back, the hop)
+        with jax.named_scope("lda.rotate"):
+            if pallas:
+                (Ndk, Nk, z_grid, key), Nwk_slice = rotate_pipeline_resident(
+                    step, (Ndk, Nk, z_grid, key), Nwk_slice,
+                    n_chunks=nc, wire=cfg.rotate_wire, chunk_axis=1)
+            else:
+                (Ndk, Nk, z_grid, key), Nwk_slice = rotate_pipeline(
+                    step, (Ndk, Nk, z_grid, key), Nwk_slice,
+                    n_chunks=nc, wire=cfg.rotate_wire)
         return Ndk, Nwk_slice, Nk, z_grid, work_w
 
     return epoch
@@ -749,7 +757,8 @@ def make_multi_epoch_fn(mesh: WorkerMesh, cfg: LDAConfig, vocab_size: int,
 
         def body(carry, e):
             st = carry[:4]
-            k = jax.random.key_data(jax.random.fold_in(base, e))[None]
+            with jax.named_scope("lda.keys"):
+                k = jax.random.key_data(jax.random.fold_in(base, e))[None]
             out = inner(*st, *tokens, k)
             if pp:  # accumulate the drop counter across sweeps
                 out = out[:4] + (carry[4] + out[4], out[5])
@@ -1334,7 +1343,8 @@ class LDA:
                 self._count_bounds)
             # steps=0: lowering traces the sweep's comm sites under the
             # execution tag without counting an execution
-            with telemetry.ledger.run("lda.epochs", steps=0):
+            with telemetry.ledger.run("lda.epochs", steps=0), \
+                    telemetry.current_names():
                 fn = self._multi_fns[epochs] = flightrec.track(
                     jitted.lower(*self._epoch_args()).compile(),
                     "lda.epochs")

@@ -499,12 +499,13 @@ def _pallas_tile_block_update(W, H, block, cfg: MFSGDConfig):
     from harp_tpu.ops.mfsgd_kernel import sgd_tile_update
 
     cu, ci, cv, meta = block
-    Wt, Ht, se, cnt = sgd_tile_update(
-        W.T, H.T, cu, ci, cv, meta,
-        lr=cfg.lr, reg=cfg.reg, u_tile=tiles(cfg)[0], i_tile=tiles(cfg)[1],
-        compute_dtype=cfg.compute_dtype,
-        interpret=interpret_default())
-    return Wt.T, Ht.T, se, cnt
+    with jax.named_scope("mfsgd.kernel"):
+        Wt, Ht, se, cnt = sgd_tile_update(
+            W.T, H.T, cu, ci, cv, meta,
+            lr=cfg.lr, reg=cfg.reg, u_tile=tiles(cfg)[0],
+            i_tile=tiles(cfg)[1], compute_dtype=cfg.compute_dtype,
+            interpret=interpret_default())
+        return Wt.T, Ht.T, se, cnt
 
 
 _UPDATERS = {"dense": _tile_block_update, "scatter": _block_update,
@@ -538,22 +539,28 @@ def _epoch_device_fn(mesh: WorkerMesh, cfg: MFSGDConfig):
         # block arrays arrive as this worker's [nc·n chunk-slices, ...] row
         def step(st, chunk, t):
             W, se, cnt = st
-            block = jax.tree.map(
-                lambda a: a[resident_chunk_index(t, nc)], blocks)
+            with jax.named_scope("mfsgd.slices"):
+                block = jax.tree.map(
+                    lambda a: a[resident_chunk_index(t, nc)], blocks)
             W, chunk, dse, dcnt = update(W, chunk, block, cfg)
             return (W, se + dse, cnt + dcnt), chunk
 
-        (W, se, cnt), H_slice = rotate_pipeline(
-            step, (W, jnp.float32(0.0), jnp.float32(0.0)), H_slice,
-            n_chunks=nc, wire=cfg.rotate_wire)
+        # the whole pipeline: alone on an op's path it is the rotation's
+        # own work (the carry's copies, the ring hop)
+        with jax.named_scope("mfsgd.rotate"):
+            (W, se, cnt), H_slice = rotate_pipeline(
+                step, (W, jnp.float32(0.0), jnp.float32(0.0)), H_slice,
+                n_chunks=nc, wire=cfg.rotate_wire)
         # per-worker visited-rating count BEFORE the psum — the skew
         # spine's execution counter (utils/skew.py), folded into the
         # epoch outputs so the driver's ONE stacked readback carries it
         # (flight budgets unchanged, tests/test_flightrec.py).
-        work_w = C.allgather(cnt[None])
-        # loss partials are per-worker; combine before leaving SPMD (the
-        # optional end-of-epoch allreduce-RMSE in Harp's MF-SGD loop)
-        se, cnt = C.allreduce((se, cnt))
+        with jax.named_scope("mfsgd.loss"):
+            work_w = C.allgather(cnt[None])
+            # loss partials are per-worker; combine before leaving SPMD
+            # (the optional end-of-epoch allreduce-RMSE in Harp's MF-SGD
+            # loop)
+            se, cnt = C.allreduce((se, cnt))
         return W, H_slice, se, cnt, work_w
 
     return epoch
@@ -739,7 +746,8 @@ class MFSGD:
             jitted = make_multi_epoch_fn(self.mesh, self.cfg, epochs)
             # steps=0: lowering traces the comm sites (attributed to the
             # same tag the executions count under) without executing them
-            with telemetry.ledger.run("mfsgd.epochs", steps=0):
+            with telemetry.ledger.run("mfsgd.epochs", steps=0), \
+                    telemetry.current_names():
                 fn = self._multi_fns[epochs] = flightrec.track(
                     jitted.lower(self.W, self.H, *self._blocks).compile(),
                     "mfsgd.epochs")

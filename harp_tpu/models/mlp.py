@@ -80,13 +80,16 @@ def init_params(cfg: MLPConfig, key):
 
 
 def forward(params, x, cfg: MLPConfig):
-    h = x.astype(jnp.bfloat16) if cfg.half_precision else x
-    for layer in params[:-1]:
-        w = layer["w"].astype(h.dtype)
-        h = jax.nn.relu(h @ w + layer["b"].astype(h.dtype))
+    with jax.named_scope("mlp.cast"):
+        h = x.astype(jnp.bfloat16) if cfg.half_precision else x
+    for i, layer in enumerate(params[:-1], 1):
+        with jax.named_scope(f"mlp.layer{i}"):
+            w = layer["w"].astype(h.dtype)
+            h = jax.nn.relu(h @ w + layer["b"].astype(h.dtype))
     last = params[-1]
-    logits = h @ last["w"].astype(h.dtype) + last["b"].astype(h.dtype)
-    return logits.astype(jnp.float32)
+    with jax.named_scope(f"mlp.layer{len(params)}"):
+        logits = h @ last["w"].astype(h.dtype) + last["b"].astype(h.dtype)
+        return logits.astype(jnp.float32)
 
 
 def loss_fn(params, x, y, cfg: MLPConfig):
@@ -103,10 +106,11 @@ def loss_fn(params, x, y, cfg: MLPConfig):
     label and filled with NaN past the last class.
     """
     logits = forward(params, x, cfg)
-    is_label = y[..., None] == jnp.arange(logits.shape[-1])
-    label_logit = jnp.where(is_label, logits, 0).sum(-1)
-    ce = jax.nn.logsumexp(logits, axis=-1) - label_logit
-    return ce.mean(), logits
+    with jax.named_scope("mlp.loss"):
+        is_label = y[..., None] == jnp.arange(logits.shape[-1])
+        label_logit = jnp.where(is_label, logits, 0).sum(-1)
+        ce = jax.nn.logsumexp(logits, axis=-1) - label_logit
+        return ce.mean(), logits
 
 
 def make_optimizer(cfg: MLPConfig):
@@ -130,10 +134,13 @@ def _step_body(tx, cfg: MLPConfig, combine):
         (loss, logits), grads = jax.value_and_grad(
             lambda p: loss_fn(p, x, y, cfg), has_aux=True
         )(params)
-        acc = (jnp.argmax(logits, -1) == y).mean()
-        grads, loss, acc = combine((grads, loss, acc))
-        updates, opt_state = tx.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        with jax.named_scope("mlp.accuracy"):
+            acc = (jnp.argmax(logits, -1) == y).mean()
+        with jax.named_scope("mlp.combine"):
+            grads, loss, acc = combine((grads, loss, acc))
+        with jax.named_scope("mlp.update"):
+            updates, opt_state = tx.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
         return params, opt_state, loss, acc
 
     return step
@@ -340,19 +347,23 @@ def make_epoch_fn(mesh: WorkerMesh, cfg: MLPConfig, batch_per_worker: int,
     def run(params, opt_state, xs, ys, key):
         def epoch(carry, e):
             params, opt_state = carry
-            order = epoch_batch_order(key, e, n_batches)
+            with jax.named_scope("mlp.order"):
+                order = epoch_batch_order(key, e, n_batches)
 
             def body(c, i):
                 p, o = c
-                xb = lax.dynamic_slice_in_dim(
-                    xs, i * batch_per_worker, batch_per_worker, 0)
-                yb = lax.dynamic_slice_in_dim(
-                    ys, i * batch_per_worker, batch_per_worker, 0)
+                with jax.named_scope("mlp.batch"):
+                    xb = lax.dynamic_slice_in_dim(
+                        xs, i * batch_per_worker, batch_per_worker, 0)
+                    yb = lax.dynamic_slice_in_dim(
+                        ys, i * batch_per_worker, batch_per_worker, 0)
                 p, o, loss, acc = step(p, o, xb, yb)
                 return (p, o), (loss, acc)
 
-            (params, opt_state), (losses, accs) = lax.scan(
-                body, (params, opt_state), order)
+            # alone on an op's path: the scan's own slices and stacking
+            with jax.named_scope("mlp.steps"):
+                (params, opt_state), (losses, accs) = lax.scan(
+                    body, (params, opt_state), order)
             return (params, opt_state), (losses[-1], accs[-1])
 
         (params, opt_state), (losses, accs) = lax.scan(

@@ -177,6 +177,23 @@ def _canon(tpl, i=0) -> str:
     return "(" + "".join(sorted(_canon(tpl, c) for c in _children(tpl)[i])) + ")"
 
 
+def _sum_scope_names(tpl) -> dict:
+    """The last part of a neighbour sum's scope ``subgraph.sum.<name>``,
+    one stable name per distinct child shape: ``leaf`` for a leaf, else
+    ``t<size>`` of the child's subtree, with a letter where two shapes
+    share a size (in the order of their canonical forms)."""
+    sizes = _subtree_sizes(tpl)
+    by_size: dict[int, set] = {}
+    for c in range(1, len(tpl)):
+        by_size.setdefault(sizes[c], set()).add(_canon(tpl, c))
+    names = {}
+    for size, shapes in by_size.items():
+        for j, shape in enumerate(sorted(shapes)):
+            names[shape] = "leaf" if shape == "()" else f"t{size}" + (
+                "abcdefghijklmnopqrstuvwxyz"[j] if len(shapes) > 1 else "")
+    return names
+
+
 def _alone(colors, k):
     """The table of a vertex by itself, ``[..., k * T]`` from colors
     ``[..., T]``: compact singleton — supp[1] is [1<<0, 1<<1, ...]
@@ -268,6 +285,7 @@ def make_colorful_count_fn(tpl, k, mesh: WorkerMesh,
     combos = _dp_subset_tables(tpl, k)
     n_subsets = 1 << k
     n_ovf_args = 3 if overflow_algo == "segment" else 4
+    sum_names = _sum_scope_names(tpl)
 
     def over_tiles(fn, rows, tile, width, *arrays):
         """``fn`` over tiles of ``tile`` rows of ``arrays``, written into
@@ -292,18 +310,24 @@ def make_colorful_count_fn(tpl, k, mesh: WorkerMesh,
         result set into those rows of ``out``; the last tile is moved
         back as in ``over_tiles``."""
         def put(out, ids):
+            sums = fn(ids)
             # never indices_are_sorted: on rows that were, that scatter
             # took some 670 ns a row on a v5e where this one takes 71
-            return out.at[ids].set(fn(ids), unique_indices=True,
-                                   mode="promise_in_bounds")
+            with jax.named_scope("subgraph.order.put"):
+                return out.at[ids].set(sums, unique_indices=True,
+                                       mode="promise_in_bounds")
 
         count = rows.shape[0]
         if count <= tile:
             return put(out, rows)
-        return jax.lax.fori_loop(
-            0, -(-count // tile),
-            lambda i, out: put(out, jax.lax.dynamic_slice_in_dim(
-                rows, jnp.minimum(i * tile, count - tile), tile)), out)
+
+        def body(i, out):
+            with jax.named_scope("subgraph.order.take"):
+                ids = jax.lax.dynamic_slice_in_dim(
+                    rows, jnp.minimum(i * tile, count - tile), tile)
+            return put(out, ids)
+
+        return jax.lax.fori_loop(0, -(-count // tile), body, out)
 
     def spmv_gather(rows_of, lanes, nbr, msk, order, ovf):
         # Σ_{u∈N(v)} rows_of(u): the padded part, each vertex's first
@@ -322,70 +346,77 @@ def make_colorful_count_fn(tpl, k, mesh: WorkerMesh,
         r_tile, e_tile = _gather_tiles(nbr.shape[1], lanes)
 
         def padded(nb, mk):
-            return (rows_of(nb) * mk[:, :, None]).sum(1)  # [tile, deg, S]
+            with jax.named_scope("subgraph.padded"):  # [tile, deg, S]
+                return (rows_of(nb) * mk[:, :, None]).sum(1)
 
         if plan is None:
             out = over_tiles(padded, n_loc, r_tile, lanes, nbr, msk)
         else:
             def summed(width):
                 def slots(a, ids):  # whole rows, cut to the segment
-                    return a.at[ids].get(
-                        unique_indices=True,
-                        mode="promise_in_bounds")[:, :width]
+                    with jax.named_scope("subgraph.order.take"):
+                        return a.at[ids].get(
+                            unique_indices=True,
+                            mode="promise_in_bounds")[:, :width]
 
                 return lambda ids: padded(slots(nbr, ids), slots(msk, ids))
 
             out = jnp.zeros((n_loc, lanes), jnp.float32)
             for start, stop, width in plan:
-                out = in_order(summed(width), out, order[start:stop],
+                with jax.named_scope("subgraph.order.take"):
+                    segment = order[start:stop]
+                out = in_order(summed(width), out, segment,
                                _segment_tile(stop - start, width,
                                              _gather_tiles(width, lanes)[0]))
-        if overflow_algo == "segment":
-            o_nbr, o_row, o_msk = ovf
-            m = o_nbr.shape[0]
-            if m <= e_tile:
-                og = rows_of(o_nbr) * o_msk[:, None]
-                # _partition_overflow emits o_row ascending (padding id 0
-                # first), so the sorted segment-sum lowering applies — the
-                # cheap mitigant for the v5e ~25 GB/s small-row scatter
-                # floor (CLAUDE.md)
-                return out + jax.ops.segment_sum(
-                    og, o_row, num_segments=n_loc, indices_are_sorted=True)
+        with jax.named_scope("subgraph.tail"):
+            if overflow_algo == "segment":
+                o_nbr, o_row, o_msk = ovf
+                m = o_nbr.shape[0]
+                if m <= e_tile:
+                    og = rows_of(o_nbr) * o_msk[:, None]
+                    # _partition_overflow emits o_row ascending (padding
+                    # id 0 first), so the sorted segment-sum lowering
+                    # applies — the cheap mitigant for the v5e ~25 GB/s
+                    # small-row scatter floor (CLAUDE.md)
+                    return out + jax.ops.segment_sum(
+                        og, o_row, num_segments=n_loc,
+                        indices_are_sorted=True)
 
-            def body(i, acc):
-                # the same sorted scatter-add, e_tile entries at a time;
-                # the last tile ends at the last entry and masks what
-                # the tile before it already added
-                lo = jnp.minimum(i * e_tile, m - e_tile)
-                nb, rw, mk = (jax.lax.dynamic_slice_in_dim(a, lo, e_tile)
-                              for a in (o_nbr, o_row, o_msk))
-                mk = mk * (lo + jnp.arange(e_tile) >= i * e_tile)
-                return acc.at[rw].add(rows_of(nb) * mk[:, None],
-                                      indices_are_sorted=True)
+                def body(i, acc):
+                    # the same sorted scatter-add, e_tile entries at a
+                    # time; the last tile ends at the last entry and masks
+                    # what the tile before it already added
+                    lo = jnp.minimum(i * e_tile, m - e_tile)
+                    nb, rw, mk = (
+                        jax.lax.dynamic_slice_in_dim(a, lo, e_tile)
+                        for a in (o_nbr, o_row, o_msk))
+                    mk = mk * (lo + jnp.arange(e_tile) >= i * e_tile)
+                    return acc.at[rw].add(rows_of(nb) * mk[:, None],
+                                          indices_are_sorted=True)
 
-            return out + jax.lax.fori_loop(
-                0, -(-m // e_tile), body,
-                jnp.zeros((n_loc, lanes), jnp.float32))
-        # "onehot": no scatter at all — each (entry × row-window) tile is
-        # one one-hot MXU matmul into a dynamic-sliced block (the
-        # mfsgd/lda pattern); acc is padded by row_tile so the last
-        # window's slice stays in bounds
-        t_nbr, t_loc, t_msk, t_lo = ovf
-        acc = jnp.concatenate(
-            [out, jnp.zeros((row_tile, out.shape[1]), out.dtype)], 0)
+                return out + jax.lax.fori_loop(
+                    0, -(-m // e_tile), body,
+                    jnp.zeros((n_loc, lanes), jnp.float32))
+            # "onehot": no scatter at all — each (entry × row-window) tile
+            # is one one-hot MXU matmul into a dynamic-sliced block (the
+            # mfsgd/lda pattern); acc is padded by row_tile so the last
+            # window's slice stays in bounds
+            t_nbr, t_loc, t_msk, t_lo = ovf
+            acc = jnp.concatenate(
+                [out, jnp.zeros((row_tile, out.shape[1]), out.dtype)], 0)
 
-        def body(a, tile):
-            nb, lc, mk, lo = tile
-            og = rows_of(nb) * mk[:, None]                        # [TE, S]
-            oh = jax.nn.one_hot(lc, row_tile, dtype=og.dtype)     # [TE, R]
-            contrib = jax.lax.dot_general(  # ohᵀ @ og → [R, S], MXU
-                oh, og, (((0,), (0,)), ((), ())))
-            blk = jax.lax.dynamic_slice_in_dim(a, lo, row_tile, 0)
-            return jax.lax.dynamic_update_slice_in_dim(
-                a, blk + contrib, lo, 0), None
+            def body(a, tile):
+                nb, lc, mk, lo = tile
+                og = rows_of(nb) * mk[:, None]                     # [TE, S]
+                oh = jax.nn.one_hot(lc, row_tile, dtype=og.dtype)  # [TE, R]
+                contrib = jax.lax.dot_general(  # ohᵀ @ og → [R, S], MXU
+                    oh, og, (((0,), (0,)), ((), ())))
+                blk = jax.lax.dynamic_slice_in_dim(a, lo, row_tile, 0)
+                return jax.lax.dynamic_update_slice_in_dim(
+                    a, blk + contrib, lo, 0), None
 
-        acc, _ = jax.lax.scan(body, acc, (t_nbr, t_loc, t_msk, t_lo))
-        return acc[: out.shape[0]]
+            acc, _ = jax.lax.scan(body, acc, (t_nbr, t_loc, t_msk, t_lo))
+            return acc[: out.shape[0]]
 
     # Colorful counting: a partial rooted at i with j template vertices
     # absorbed uses EXACTLY j distinct colors, so its table is supported
@@ -417,7 +448,8 @@ def make_colorful_count_fn(tpl, k, mesh: WorkerMesh,
         def cols(table, c):
             return table[:, c * T:(c + 1) * T]
 
-        singleton = _alone(colors, k)
+        with jax.named_scope("subgraph.singleton"):
+            singleton = _alone(colors, k)
 
         # post-order DP: table[i] = counts for subtree rooted at i.
         # Sub-templates of one rooted shape have one table (every leaf's
@@ -442,22 +474,27 @@ def make_colorful_count_fn(tpl, k, mesh: WorkerMesh,
             width = table.shape[1]
             lanes = 128 * -(-width // 128)
             widen = ((0, lanes - width),)
-            if shape == "()":
-                packed = C.allgather(_pack_colors(colors, k))  # Harp step
+            with jax.named_scope("subgraph.sum." + sum_names[shape]):
+                if shape == "()":
+                    with jax.named_scope("subgraph.allgather"):
+                        packed = C.allgather(  # Harp step
+                            _pack_colors(colors, k))
 
-                def rows_of(ids):
-                    got = _unpack_colors(jnp.take(packed, ids, axis=0), k, T)
-                    return jnp.pad(_alone(got, k),
-                                   ((0, 0),) * (got.ndim - 1) + widen)
-            else:
-                child_full = jnp.pad(C.allgather(table),  # compact Harp step
-                                     ((0, 0),) + widen)
+                    def rows_of(ids):
+                        got = _unpack_colors(
+                            jnp.take(packed, ids, axis=0), k, T)
+                        return jnp.pad(_alone(got, k),
+                                       ((0, 0),) * (got.ndim - 1) + widen)
+                else:
+                    with jax.named_scope("subgraph.allgather"):
+                        child_full = jnp.pad(  # compact Harp step
+                            C.allgather(table), ((0, 0),) + widen)
 
-                def rows_of(ids):
-                    return jnp.take(child_full, ids, axis=0)
+                    def rows_of(ids):
+                        return jnp.take(child_full, ids, axis=0)
 
-            nbr_sums[shape] = spmv_gather(
-                rows_of, lanes, nbr, msk, order, ovf)[:, :width]
+                nbr_sums[shape] = spmv_gather(
+                    rows_of, lanes, nbr, msk, order, ovf)[:, :width]
             return nbr_sums[shape]
 
         for i in reversed(range(len(tpl))):
@@ -472,24 +509,26 @@ def make_colorful_count_fn(tpl, k, mesh: WorkerMesh,
                 new_size = acc_size + sizes[c]
                 # subset convolution: output column S sums, in the
                 # plan's order, acc[S1] · nbr_counts[S2] over S1 ⊎ S2 = S
-                out_cols = [None] * len(supp[new_size])
-                for S, S1, S2 in combos(acc_size, sizes[c]):
-                    term = (cols(acc, pos[acc_size][S1])
-                            * cols(nbr_counts, pos[sizes[c]][S2]))
-                    j = pos[new_size][S]
-                    out_cols[j] = term if out_cols[j] is None \
-                        else out_cols[j] + term
-                acc = jnp.concatenate(out_cols, axis=1)
+                with jax.named_scope("subgraph.convolve"):
+                    out_cols = [None] * len(supp[new_size])
+                    for S, S1, S2 in combos(acc_size, sizes[c]):
+                        term = (cols(acc, pos[acc_size][S1])
+                                * cols(nbr_counts, pos[sizes[c]][S2]))
+                        j = pos[new_size][S]
+                        out_cols[j] = term if out_cols[j] is None \
+                            else out_cols[j] + term
+                    acc = jnp.concatenate(out_cols, axis=1)
                 acc_size = new_size
             tables[shape] = acc
 
         # the root table's support IS the size-s subsets (one column when
         # k == s): summing the compact table covers both cases
         root = tables[_canon(tpl, 0)]
-        rooted = cols(root, 0)
-        for c in range(1, root.shape[1] // T):
-            rooted = rooted + cols(root, c)
-        return C.allreduce(rooted.sum(0))  # [trial_chunk], replicated
+        with jax.named_scope("subgraph.count"):
+            rooted = cols(root, 0)
+            for c in range(1, root.shape[1] // T):
+                rooted = rooted + cols(root, c)
+            return C.allreduce(rooted.sum(0))  # [trial_chunk], replicated
 
     body = mesh.shard_map(
         prog,
@@ -501,8 +540,9 @@ def make_colorful_count_fn(tpl, k, mesh: WorkerMesh,
 
     if draw_trials:
         def program(nbr, msk, *rest):
-            colors = jax.lax.with_sharding_constraint(block_colors(
-                *rest[-1], nbr.shape[0], draw_trials, k), rows)
+            with jax.named_scope("subgraph.draw"):
+                colors = jax.lax.with_sharding_constraint(block_colors(
+                    *rest[-1], nbr.shape[0], draw_trials, k), rows)
             return body(nbr, msk, *rest[:-1], colors)
     else:
         def program(nbr, msk, *rest):

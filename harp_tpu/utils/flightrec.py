@@ -49,7 +49,7 @@ import sys
 import warnings
 from typing import Any, Callable
 
-from harp_tpu.utils import telemetry
+from harp_tpu.utils import memrec, telemetry
 
 _BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 _CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
@@ -256,8 +256,6 @@ def record_h2d(nbytes: int, site: str | None = None) -> None:
             cb(nbytes, site)
     if telemetry.enabled():
         transfers.record_h2d(nbytes, site)
-        from harp_tpu.utils import memrec
-
         memrec.on_staged(nbytes, site or _call_site())
 
 
@@ -373,7 +371,7 @@ class _Tracked:
     every other attribute (``lower``, ``trace``, ...) to the wrapped
     callable so a tracked ``jax.jit`` keeps its full surface."""
 
-    __slots__ = ("__wrapped__", "_label")
+    __slots__ = ("__wrapped__", "_label", "__weakref__")
 
     def __init__(self, fn: Callable, label: str):
         self.__wrapped__ = fn
@@ -388,10 +386,16 @@ class _Tracked:
                 cb(self._label)                    # that never launched
         if telemetry.enabled():
             transfers.record_dispatch(self._label)
-            from harp_tpu.utils import memrec
-
             memrec.on_dispatch(self._label, args)
-            out = self.__wrapped__(*args, **kw)
+            if telemetry.scopes.record(self, self._label, self.__wrapped__,
+                                       args, kw):
+                # this program's first call with telemetry on: its op map
+                # was read, and the dispatch goes under the same cache
+                # key, so that a program not yet compiled is compiled once
+                with telemetry.current_names():
+                    out = self.__wrapped__(*args, **kw)
+            else:
+                out = self.__wrapped__(*args, **kw)
             memrec.on_output(self._label, out)
             return out
         return self.__wrapped__(*args, **kw)
@@ -407,14 +411,18 @@ def track(fn: Callable, label: str,
     call and never touches the arguments — the traced program and its
     dispatch count are identical with telemetry on or off.
 
+    With telemetry on, the program's first call also reads its op map
+    into ``telemetry.scopes`` (instruction of the optimized HLO → the
+    ``op_name`` that holds the program's ``jax.named_scope``s): from its
+    text where ``fn`` is an AOT ``Compiled``, else from a lowering for
+    that call's arguments; once per tracked object.
+
     ``donate_argnums`` (PR 19) declares the callable's donation
     signature to the memory ledger: at each call memrec claims the
     newest live buffers matching the donated args' byte sizes and
     records them leaving the live set (the runtime twin of HL303) —
     metadata only, the args are never materialized."""
     if donate_argnums is not None:
-        from harp_tpu.utils import memrec
-
         memrec.register_dispatch(label, donate_argnums)
     return _Tracked(fn, label)
 

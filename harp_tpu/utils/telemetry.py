@@ -8,7 +8,7 @@ collectives move how much — are the prerequisite for optimizing them, and
 the quantized-wire verbs (`allreduce_quantized`, `push_quantized`) make
 EQuARX-style bandwidth claims this module lets a run audit.
 
-Two cooperating pieces:
+Three cooperating pieces:
 
 **CommLedger** — every verb in :mod:`harp_tpu.parallel.collective` calls
 :func:`record_comm` at *trace time* (the only time Python runs inside
@@ -39,6 +39,18 @@ shape as :class:`harp_tpu.utils.timing.Timer.summary`, so report code can
 merge both; :meth:`SpanTracer.durations` is the query a reader uses
 (by name, ancestor and absolute ``perf_counter`` time).
 
+**ScopeMap** (``scopes``) — which part of the program each device op
+belongs to, under the program's own names.  The step programs put
+``jax.named_scope("<app>.<part>")`` around their parts; XLA carries the
+name stack into every optimized instruction's ``op_name``, fusions
+included, and a profiler trace names a device op by that instruction.
+The map ``{HLO module: {instruction: op_name}}`` is read from a tracked
+program's optimized HLO text (fed from ``flightrec.track``'s wrapper on
+the program's first call with telemetry on), so a trace reducer can put
+``fusion.396`` down to ``subgraph.sum.t3/subgraph.tail``
+(``perf/scope_reduce.py``, which also says what in an ``op_name`` is a
+scope: a lower-case segment with a dot, as no JAX primitive has).
+
 Everything is **zero-cost when disabled** (the default): ``record_comm``
 returns before touching the tree, ``span`` yields without bookkeeping, and
 neither ever does per-element work — so telemetry can stay on for
@@ -54,8 +66,10 @@ import contextlib
 import json
 import math
 import os
+import re
 import sys
 import time
+import weakref
 from typing import Any
 
 _ENABLED = os.environ.get("HARP_TELEMETRY", "0").lower() not in (
@@ -76,14 +90,16 @@ def enable(on: bool = True) -> None:
 @contextlib.contextmanager
 def scope(on: bool = True, *, reset: bool = True):
     """Enable (or disable) telemetry within a block, restoring the prior
-    flag on exit; ``reset`` clears every collector (ledger, tracer, and
-    the flight recorder) on entry so a test sees only its own records."""
+    flag on exit; ``reset`` clears every collector (ledger, tracer, scope
+    map and the flight recorder) on entry so a test sees only its own
+    records."""
     global _ENABLED
     prev = _ENABLED
     _ENABLED = bool(on)
     if reset:
         ledger.reset()
         tracer.reset()
+        scopes.reset()
         from harp_tpu import elastic, health
         from harp_tpu.utils import (flightrec, memrec, reqtrace, skew,
                                     steptrace)
@@ -395,11 +411,133 @@ class SpanTracer:
 
 
 # ---------------------------------------------------------------------------
+# ScopeMap
+# ---------------------------------------------------------------------------
+
+_HLO_MODULE = re.compile(r"^HloModule ([^\s,]+)")
+_HLO_INSTRUCTION = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = ")
+_HLO_OP_NAME = re.compile(r'\bop_name="([^"]*)"')
+_CACHE_KEY_METADATA = "jax_compilation_cache_include_metadata_in_key"
+
+
+@contextlib.contextmanager
+def current_names():
+    """Compile with the names in the persistent cache's key while
+    telemetry is on.  JAX leaves ``op_name`` and source lines out of that
+    key, so a cache filled by another version of the source hands back an
+    executable that carries THAT version's names (measured: a program
+    compiled without its scopes, then with them, was a hit and its text
+    had none).  A program whose map is read is compiled, or looked up,
+    under a key that holds them.  Nothing changes with telemetry off."""
+    if not _ENABLED:
+        yield
+        return
+    import jax
+
+    before = getattr(jax.config, _CACHE_KEY_METADATA)
+    jax.config.update(_CACHE_KEY_METADATA, True)
+    try:
+        yield
+    finally:
+        jax.config.update(_CACHE_KEY_METADATA, before)
+
+
+class ScopeMap:
+    """``{HLO module name: {instruction name: op_name}}`` of the tracked
+    programs (see module docstring)."""
+
+    def __init__(self):
+        self.reset()
+
+    def reset(self) -> None:
+        self.modules: dict[str, dict[str, str]] = {}
+        self.labels: dict[str, str] = {}    # module -> track() label
+        self.skipped: dict[str, str] = {}   # label -> why it gave no map
+        self._seen = weakref.WeakSet()
+
+    def record(self, tracked: Any, label: str, fn: Any, args: tuple,
+               kw: dict) -> bool:
+        """Read the map of the program ``fn`` that ``tracked`` wraps, once
+        per tracked object whatever it gives; ``True`` when this call read
+        it.  An AOT ``Compiled`` gives its text as it is; a ``jax.jit`` is
+        lowered for these arguments (only their avals are read: donated
+        ones are safe) and compiled under :func:`current_names`, so that
+        the dispatch that follows under the same finds it in the
+        persistent cache where there is one (the compile watch then counts
+        the map's compile and the load; without a cache, two compiles: a
+        ``budget(compiles=...)`` around a tracked program's FIRST call with
+        telemetry on has to allow for the map's).  A
+        callable that is neither, or whose text cannot be had, is counted
+        in ``skipped`` with the reason: the map never raises into a
+        dispatch."""
+        if tracked in self._seen:
+            return False
+        self._seen.add(tracked)
+        self._read(label, fn, args, kw)
+        return True
+
+    def _read(self, label: str, fn: Any, args: tuple, kw: dict) -> None:
+        try:
+            if hasattr(fn, "lower"):
+                with current_names():
+                    text = fn.lower(*args, **kw).compile().as_text()
+            elif hasattr(fn, "as_text"):
+                text = fn.as_text()
+            else:
+                self.skipped[label] = "neither a jax.jit nor a Compiled"
+                return
+        except Exception as e:  # noqa: BLE001 - see the docstring
+            self.skipped[label] = f"{type(e).__name__}: {e}"[:200]
+            return
+        if not self.add_text(text or "", label):
+            self.skipped[label] = "no HloModule line in its text"
+
+    def add_text(self, hlo_text: str, label: str | None = None) -> bool:
+        """Parse one optimized HLO module's text into the map; a second
+        program of the same module name adds to the first's entries."""
+        head = _HLO_MODULE.match(hlo_text)
+        if head is None:
+            return False
+        instructions = self.modules.setdefault(head.group(1), {})
+        if label is not None:
+            self.labels[head.group(1)] = label
+        for line in hlo_text.splitlines():
+            name = _HLO_INSTRUCTION.match(line)
+            if name is None:
+                continue
+            op_name = _HLO_OP_NAME.search(line, name.end())
+            if op_name is not None:
+                instructions[name.group(1)] = op_name.group(1)
+        return True
+
+    def lookup(self, module: str, instruction: str) -> str | None:
+        return self.modules.get(module, {}).get(instruction)
+
+    def summary(self) -> dict:
+        """``{"modules": {module: {"label", "instructions"}}, "skipped":
+        {label: reason}}``; ``instructions`` counts those with an
+        ``op_name``."""
+        return {"modules": {m: {"label": self.labels.get(m),
+                                "instructions": len(i)}
+                            for m, i in sorted(self.modules.items())},
+                "skipped": dict(self.skipped)}
+
+    def export_jsonl(self, fh) -> None:
+        for module, instructions in sorted(self.modules.items()):
+            for name, op_name in instructions.items():
+                fh.write(json.dumps({
+                    "kind": "scope", "module": module,
+                    "label": self.labels.get(module),
+                    "instruction": name, "op_name": op_name}) + "\n")
+
+
+# ---------------------------------------------------------------------------
 # Module singletons + the verbs' hook
 # ---------------------------------------------------------------------------
 
 ledger = CommLedger()
 tracer = SpanTracer()
+scopes = ScopeMap()
 
 
 def span(name: str, **attrs: Any):
@@ -422,12 +560,12 @@ def record_comm(verb: str, tree: Any, *, axis: str,
 
 
 def export(path: str) -> None:
-    """Write every collected record (spans + ledger + flight recorder +
-    skew ledger + request traces + health findings + elastic actions +
-    memory ledger) as one JSONL file — the input format of ``python -m
-    harp_tpu report``, ``python -m harp_tpu trace``, ``python -m
-    harp_tpu timeline``, ``python -m harp_tpu health``, and ``python -m
-    harp_tpu memory``."""
+    """Write every collected record (spans + ledger + scope map + flight
+    recorder + skew ledger + request traces + health findings + elastic
+    actions + memory ledger) as one JSONL file — the input format of
+    ``python -m harp_tpu report``, ``python -m harp_tpu trace``,
+    ``python -m harp_tpu timeline``, ``python -m harp_tpu health``, and
+    ``python -m harp_tpu memory``."""
     from harp_tpu import elastic, health
     from harp_tpu.utils import (flightrec, memrec, reqtrace, skew,
                                 steptrace)
@@ -435,6 +573,7 @@ def export(path: str) -> None:
     with open(path, "w") as fh:
         tracer.export_jsonl(fh)
         ledger.export_jsonl(fh)
+        scopes.export_jsonl(fh)
         flightrec.export_jsonl(fh)
         skew.export_jsonl(fh)
         reqtrace.tracer.export_jsonl(fh)
@@ -517,7 +656,8 @@ def load_rows(path: str) -> dict[str, list[dict]]:
     """Read an :func:`export` file back, keyed by record kind:
     ``{"span": [...], "comm": [...], "compile": [...], "transfer":
     [...], "skew": [...], "trace": [...], "health": [...],
-    "elastic": [...], "steptrace": [...], "memory": [...]}`` (unknown
+    "elastic": [...], "steptrace": [...], "memory": [...], "scope":
+    [...]}`` (unknown
     kinds land under ``"comm"`` for backward compatibility with
     pre-flight-recorder exports, whose only unmarked rows were the
     ledger's)."""
@@ -525,7 +665,7 @@ def load_rows(path: str) -> dict[str, list[dict]]:
                                   "transfer": [], "skew": [],
                                   "trace": [], "health": [],
                                   "elastic": [], "steptrace": [],
-                                  "memory": []}
+                                  "memory": [], "scope": []}
     with open(path) as fh:
         for line in fh:
             line = line.strip()

@@ -50,6 +50,52 @@ def table_sized_writes(hlo: str, sizes: set,
     return found
 
 
+def metadata_stripped(hlo: str) -> str:
+    """A compiled module's text without what names its instructions'
+    origin: every ``metadata={...}`` (``op_name``, source line, stack
+    frame) and the tables of files, functions, locations and stack frames
+    they index.  What is left is the program."""
+    import re
+
+    hlo = re.sub(r",? ?metadata=\{[^}]*\}", "", hlo)
+    return re.sub(r"^(?:FileNames|FunctionNames|FileLocations|StackFrames)"
+                  r"\n(?:.+\n)*\n?", "", hlo, flags=re.M)
+
+
+def program_alone(hlo: str) -> str:
+    """:func:`metadata_stripped`, and besides: every instruction and
+    computation renamed by the order of its first appearance, and a Mosaic
+    call's serialized body left out.  On the TPU compiler two things
+    follow an instruction's ``op_name`` that are not metadata: the numbers
+    XLA gives its instructions (the same program under other names comes
+    out as ``reshape.1945`` where it was ``reshape.1897``), and the name of
+    a Mosaic call, which is the part of its ``op_name`` before
+    ``pallas_call`` (``closed_call.23`` becomes ``lda.kernel.4``); and the
+    kernel's body travels as bytecode with its own source locations.  Two
+    texts that are equal here are the same instructions in the same
+    order."""
+    import re
+
+    names: dict = {}
+    hlo = re.sub(r'(custom_call_target="tpu_custom_call".*?'
+                 r'backend_config=)\{.*?\}(?=[,\s]|$)', r"\1{}",
+                 metadata_stripped(hlo), flags=re.M)
+    return re.sub(r"%[\w.\-]+",
+                  lambda m: names.setdefault(m.group(0), f"%n{len(names)}"),
+                  hlo)
+
+
+def _without_scopes():
+    """``jax.named_scope`` as a context that names nothing."""
+    import contextlib
+    from unittest import mock
+
+    import jax
+
+    return mock.patch.object(jax, "named_scope",
+                             lambda name: contextlib.nullcontext())
+
+
 def gathers_and_scatters(hlo: str) -> int:
     """How many ``gather`` and ``scatter`` instructions a compiled
     module's text holds, those inside fusions with the rest."""
@@ -112,6 +158,12 @@ def _compile_all() -> dict:
 
     def mosaic_calls(fn, sds):
         return fn.lower(*sds).compile().as_text().count(chip_smoke.MOSAIC_CALL)
+
+    def scopes_in(hlo, app):
+        """The ``<app>.<part>`` segments of the text's ``op_name``s."""
+        return sorted({seg for op_name in re.findall(r'op_name="([^"]*)"', hlo)
+                       for seg in re.findall(rf"\b{app}\.[a-z0-9.]+\b",
+                                             op_name)})
 
     def mfsgd_args(algo, n_dev, ns, u_bound, ibc, ne, c):
         """W, H and a half-slice's block: ``ne`` entries ``c`` wide with
@@ -196,14 +248,20 @@ def _compile_all() -> dict:
     from harp_tpu.models import lda
 
     cfg = lda.LDAConfig(n_topics=1000)
-    fn = lda.make_multi_epoch_fn(mesh, cfg, 1_000_000, 1, (7000, 210_000))
-    compiled = fn.lower(*[
-        jax.ShapeDtypeStruct(shape, dt, sharding=mesh.sharding(spec))
-        for (shape, dt), spec in zip(
-            lda.epoch_arg_shapes(1, 6656, 1_000_000, cfg,
-                                 entries_per_row=13 * 1381),
-            lda._epoch_in_specs(mesh, cfg))]).compile()
+
+    def lda_sweep():
+        return lda.make_multi_epoch_fn(
+            mesh, cfg, 1_000_000, 1, (7000, 210_000)).lower(*[
+                jax.ShapeDtypeStruct(shape, dt, sharding=mesh.sharding(spec))
+                for (shape, dt), spec in zip(
+                    lda.epoch_arg_shapes(1, 6656, 1_000_000, cfg,
+                                         entries_per_row=13 * 1381),
+                    lda._epoch_in_specs(mesh, cfg))]).compile()
+
+    compiled = lda_sweep()
     mem, hlo = compiled.memory_analysis(), compiled.as_text()
+    with _without_scopes():
+        bare = lda_sweep().as_text()
     table = lda.epoch_arg_shapes(1, 6656, 1_000_000, cfg)[1]
     elems = int(np.prod(table[0]))
     out["lda_cell"] = {
@@ -213,6 +271,9 @@ def _compile_all() -> dict:
         "aliased_bytes": mem.alias_size_in_bytes,
         "temp_bytes": mem.temp_size_in_bytes,
         "table_sized_writes": table_sized_writes(hlo, {elems, elems // 2}),
+        "scopes": scopes_in(hlo, "lda"),
+        "scopes_without": scopes_in(bare, "lda"),
+        "scopes_change_names_only": program_alone(hlo) == program_alone(bare),
         "held_gb": round((mem.argument_size_in_bytes
                           + mem.temp_size_in_bytes) / 1e9, 1)}
 
@@ -295,18 +356,24 @@ def _compile_all() -> dict:
     tail, chunk = 54_903_737, scfg["knobs"]["trial_chunk"]
     plan = subgraph.degree_plan(np.sort(np.minimum(
         graph_like.degree_sequence(scfg["data"]), deg))[None], deg)
-    count = subgraph.make_colorful_count_fn(
-        subgraph.TEMPLATES[scfg["knobs"]["template"]],
-        scfg["knobs"]["n_colors"], mesh, scfg["knobs"]["overflow_algo"],
-        draw_trials=chunk, plan=plan)
-    compiled = count.lower(
-        sds((n, deg), jnp.int32), sds((n, deg), jnp.float32),
-        sds((tail,), jnp.int32), sds((tail,), jnp.int32),
-        sds((tail,), jnp.float32), sds((n,), jnp.int32),
-        (jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=mesh.replicated()),
-         jax.ShapeDtypeStruct((), jnp.int32, sharding=mesh.replicated()))
-    ).compile()
+    def subgraph_block():
+        subgraph._FN_CACHE.clear()  # a program is traced under its names
+        return subgraph.make_colorful_count_fn(
+            subgraph.TEMPLATES[scfg["knobs"]["template"]],
+            scfg["knobs"]["n_colors"], mesh, scfg["knobs"]["overflow_algo"],
+            draw_trials=chunk, plan=plan).lower(
+                sds((n, deg), jnp.int32), sds((n, deg), jnp.float32),
+                sds((tail,), jnp.int32), sds((tail,), jnp.int32),
+                sds((tail,), jnp.float32), sds((n,), jnp.int32),
+                (jax.ShapeDtypeStruct((2,), jnp.uint32,
+                                      sharding=mesh.replicated()),
+                 jax.ShapeDtypeStruct((), jnp.int32,
+                                      sharding=mesh.replicated()))).compile()
+
+    compiled = subgraph_block()
     mem, hlo = compiled.memory_analysis(), compiled.as_text()
+    with _without_scopes():
+        bare = subgraph_block().as_text()
     out["subgraph_cell"] = {
         "trial_chunk": chunk,
         "plan": plan,
@@ -317,6 +384,9 @@ def _compile_all() -> dict:
         "gathers": len(re.findall(r" = \S+ gather\(", hlo)),
         "scatters": len(re.findall(r" = \S+ scatter\(", hlo)),
         "loops": hlo.count(" while("),
+        "scopes": scopes_in(hlo, "subgraph"),
+        "scopes_without": scopes_in(bare, "subgraph"),
+        "scopes_change_names_only": program_alone(hlo) == program_alone(bare),
         "largest_gathered": max(
             math.prod(int(d) for d in dims.split(","))
             for dims in re.findall(r" = f32\[([\d,]+)\]\S* gather\(", hlo)),
@@ -384,6 +454,56 @@ def test_lda_cell_sweep_compiles_for_v5e_and_fits(compiled):
         < 1.02 * cell["table_bytes"]
     assert cell["temp_bytes"] < 64 << 20
     assert cell["held_gb"] < 4.5
+
+
+@pytest.mark.parametrize("cell,scopes", [
+    ("lda_cell", ["lda.chain", "lda.kernel", "lda.keys", "lda.nk",
+                  "lda.rotate", "lda.slices", "lda.touched"]),
+    ("subgraph_cell", [
+        "subgraph.allgather", "subgraph.convolve", "subgraph.count",
+        "subgraph.draw", "subgraph.order.put", "subgraph.order.take",
+        "subgraph.padded", "subgraph.singleton", "subgraph.sum.leaf",
+        "subgraph.sum.t3", "subgraph.tail"]),
+])
+def test_cell_programs_scopes_change_names_only_on_v5e(compiled, cell,
+                                                      scopes):
+    """The cell's program compiled for the chip with its
+    ``jax.named_scope``s and with none: every scope reaches the optimized
+    text, and the two are the same instructions in the same order
+    (:func:`program_alone` says what besides ``metadata={...}`` follows a
+    name on this compiler).  ``tests/test_opscopes.py`` holds the five
+    programs to the letter on the CPU."""
+    got = compiled[cell]
+    assert got["scopes"] == scopes and got["scopes_without"] == []
+    assert got["scopes_change_names_only"] is True
+
+
+def test_program_alone_reads_an_hlo_text():
+    a = ('HloModule jit_f\n\nFileNames\n1 "a.py"\n\nStackFrames\n'
+         '1 {file_location_id=1 parent_frame_id=1}\n\n'
+         'ENTRY %main.3 (p.1: f32[8]) -> f32[8] {\n'
+         '  %p.1 = f32[8]{0} parameter(0), metadata={op_name="x"}\n'
+         '  %lda.kernel.4 = f32[8]{0} custom-call(%p.1), '
+         'custom_call_target="tpu_custom_call", backend_config={"custom_'
+         'call_config": {"body": "QUJD"}}, metadata={op_name="a/lda.kernel/'
+         'pallas_call" stack_frame_id=1}\n'
+         '  ROOT %add.7 = f32[8]{0} add(%lda.kernel.4, %p.1), '
+         'metadata={op_name="jit(f)/lda.nk/add"}\n}\n')
+    b = ('HloModule jit_f\n\n'
+         'ENTRY %main.3 (p.1: f32[8]) -> f32[8] {\n'
+         '  %p.1 = f32[8]{0} parameter(0)\n'
+         '  %closed_call.9 = f32[8]{0} custom-call(%p.1), '
+         'custom_call_target="tpu_custom_call", backend_config={"custom_'
+         'call_config": {"body": "REVG"}}\n'
+         '  ROOT %add.2 = f32[8]{0} add(%closed_call.9, %p.1)\n}\n')
+    assert metadata_stripped(a) != metadata_stripped(b)
+    assert program_alone(a) == program_alone(b)
+    assert "FileNames" not in metadata_stripped(a) \
+        and "op_name" not in metadata_stripped(a)
+    # another opcode, or another operand, is another program
+    assert program_alone(a) != program_alone(b.replace("add(", "subtract("))
+    assert program_alone(a) != program_alone(
+        b.replace("add(%closed_call.9, %p.1)", "add(%p.1, %p.1)"))
 
 
 def test_lda_cell_sweep_copies_no_table(compiled):

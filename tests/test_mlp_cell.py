@@ -89,7 +89,10 @@ def test_entries_are_appended_and_reduced_agrees():
     mine = [m for m in BENCH["per_layer"] if m["workloads"] == [CELL]]
     assert [(m["name"], m["layer"], m["moves"], m["source"])
             for m in mine] == [
-        ("mlp_step_us", "step programs", "items_per_s_chip", "device_trace")]
+        ("mlp_step_us", "step programs", "items_per_s_chip", "device_trace"),
+        # PR 40, after it: the share of the step under the scope mlp.layer1
+        ("mlp_layer1_share", "step programs", "items_per_s_chip",
+         "device_trace")]
     # still one four-chip cell
     assert [w["name"] for w in BENCH["workloads"] if w["chips"] == 4] == [
         "kmeans-resident-4chip"]

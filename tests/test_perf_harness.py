@@ -2,8 +2,10 @@
 ``perf/tests/test_harness.py`` (every cell rehearsed at a tiny shape, the
 shape of the last line, new cells as new files only),
 ``test_program_telemetry.py`` (the spans, skew records and counters of
-the program that the per-layer metrics read) and ``test_trace_reduce.py``
-(trace -> numbers on the recorded trace), collected here as they are.
+the program that the per-layer metrics read), ``test_trace_reduce.py``
+(trace -> numbers on the recorded trace) and ``test_scope_reduce.py``
+(trace and the program's op map -> time by scope), collected here as they
+are.
 They are the tests that fail when a program PR renames what a metric
 reads.  ``test_perf_lda_check.py`` holds the slow fourth file."""
 
@@ -54,3 +56,4 @@ for _case in (_harness["test_cell_rehearses_untraced"],
 globals().update(_harness)
 globals().update(_telemetry)
 globals().update(_cases_of("test_trace_reduce.py"))
+globals().update(_cases_of("test_scope_reduce.py", "traced_run"))

@@ -103,8 +103,16 @@ def test_entries_are_appended_and_reduced_agrees():
         ("subgraph_executed_pad_share", "step programs", "items_per_s_chip",
          "program_counter", "lower"),
         ("subgraph_ns_per_entry", "step programs", "items_per_s_chip",
+         "device_trace", "lower"),
+        # PR 40, after them: shares of the block by the program's scopes
+        ("subgraph_tail_share", "step programs", "items_per_s_chip",
+         "device_trace", "lower"),
+        ("subgraph_padded_share", "step programs", "items_per_s_chip",
+         "device_trace", "lower"),
+        ("subgraph_order_share", "step programs", "items_per_s_chip",
          "device_trace", "lower")]
-    assert BENCH["per_layer"][-len(mine):] == mine
+    at = BENCH["per_layer"].index(mine[0])
+    assert BENCH["per_layer"][at:at + 3] == mine[:3]
     # still one four-chip cell
     assert [w["name"] for w in BENCH["workloads"] if w["chips"] == 4] == [
         "kmeans-resident-4chip"]
