@@ -19,7 +19,8 @@ other bitmask column is identically zero), so each DP level becomes
 — a sparse-neighbor aggregation (a gather + mask over the compact
 columns: each vertex's first ``max_degree`` neighbors from a padded
 table, rows taken in degree order so that a tile gathers only as many
-slots as its widest row holds, and an exact tail for the rest) followed
+slots as its widest row holds, and an exact tail for the rest, cut into
+rows of at most ``max_degree`` slots and summed the same way) followed
 by a subset convolution through static position maps.  The distributed
 step is one ``allgather`` of the compact partner table per DP level,
 matching Harp's communication pattern verb-for-verb at the C(k, j)/2ᵏ
@@ -32,10 +33,11 @@ neighbor-row gather serves the whole chunk, and children that are the
 same rooted sub-template (u5-tree's three leaves) share one allgather
 and one neighbor sum.  The sum runs over row tiles, the padded part and
 the exact tail alike, so the largest gathered intermediate is a tile's
-(:func:`_gather_tiles`), never ``[n, max_degree, columns]``; the padded
-part's tiles follow the installed graph's own degree counts
-(:func:`degree_plan`: no knob), and on the cell's graph gather 48% of
-the ``n x max_degree`` slots that are resident.
+(:func:`_gather_tiles`), never ``[n, max_degree, columns]``; the tiles
+of both follow the installed graph's own counts (:func:`degree_plan`: no
+knob): on the cell's graph the padded part gathers 48% of the
+``n x max_degree`` slots that are resident, and the tail's 54.9M entries
+go as 718,773 rows of 56.6M slots, each row's sum added to its owner.
 
 Two entry points, one implementation: :class:`SubgraphCounter` installs
 a graph once (``set_graph``) and then counts chunk after chunk of fresh
@@ -247,7 +249,8 @@ def block_colors(key_bits, block, n_rows: int, trials: int, k: int):
 def make_colorful_count_fn(tpl, k, mesh: WorkerMesh,
                            overflow_algo: str = "segment",
                            row_tile: int = 512, draw_trials: int = 0,
-                           plan: tuple | None = None):
+                           plan: tuple | None = None,
+                           tail_plan: tuple | None = None):
     """Compile the color-coding DP:
     (nbr [n, deg], msk [n, deg], *overflow, colors [trial_chunk, n]) →
     [trial_chunk] colorful rooted counts — a chunk of trials per program
@@ -263,7 +266,10 @@ def make_colorful_count_fn(tpl, k, mesh: WorkerMesh,
     ``overflow_algo`` picks the exact tail
     for past-max_degree adjacency (see SubgraphConfig): "segment" takes
     the 3 flattened arrays of :func:`_partition_overflow`, "onehot" the
-    4 tiled arrays of :func:`_partition_overflow_tiles`.
+    4 tiled arrays of :func:`_partition_overflow_tiles`.  With a plan
+    "segment" takes the 3 arrays of :func:`_tail_rows` in their place and
+    sums them by ``tail_plan`` (theirs; static; ``()`` where the graph has
+    no tail: a program with no tail loop).
 
     Counts maps φ: template→graph with all image colors distinct (hence
     injective), rooted at template vertex 0 — the quantity Harp's DP
@@ -275,9 +281,13 @@ def make_colorful_count_fn(tpl, k, mesh: WorkerMesh,
     # WorkerMesh wrapper, whose id could be reused after collection;
     # row_tile only shapes the onehot trace — keying it under "segment"
     # would cache duplicate byte-identical programs
+    by_rows = plan is not None and overflow_algo == "segment"
+    if by_rows and tail_plan is None:
+        raise ValueError("a planned program sums its tail as rows: it "
+                         "needs the tail's plan too (() for no tail)")
     cache_key = (tuple(tpl), k, mesh.mesh, overflow_algo,
                  row_tile if overflow_algo == "onehot" else None,
-                 draw_trials, plan)
+                 draw_trials, plan, tail_plan if by_rows else None)
     if cache_key in _FN_CACHE:
         return _FN_CACHE[cache_key]
     ch = _children(tpl)
@@ -329,6 +339,43 @@ def make_colorful_count_fn(tpl, k, mesh: WorkerMesh,
 
         return jax.lax.fori_loop(0, -(-count // tile), body, out)
 
+    def tail_by_rows(rows_of, lanes, out, t_nbr, t_own, t_msk):
+        """``out`` + the exact tail as :func:`_tail_rows` staged it: for
+        each segment of the tail's plan, tiles of its rows, each row's
+        first ``width`` slots gathered, masked and summed as the padded
+        part's are, and the sums added into ``out`` at the rows' owners.
+        A vertex's tail may be several rows (a hub's, hundreds), in one
+        tile or in several: an add, and no ``unique_indices``."""
+        def add(out, lo, rows, width, first):
+            with jax.named_scope("subgraph.tail.rows"):
+                nb, mk = (jax.lax.dynamic_slice(a, (lo, 0), (rows, width))
+                          for a in (t_nbr, t_msk))
+                # a last tile moved back: what the tile before it added
+                # already is masked out
+                mk = mk * (lo + jnp.arange(rows) >= first)[:, None]
+                sums = (rows_of(nb) * mk[:, :, None]).sum(1)
+            # never indices_are_sorted, as in ``in_order``: with owners
+            # ascending in every tile it made the cell's block 4.85 s
+            # where this one is 3.72 (PERF.md section 6, PR 41)
+            with jax.named_scope("subgraph.tail.add"):
+                return out.at[jax.lax.dynamic_slice_in_dim(t_own, lo, rows)
+                              ].add(sums, mode="promise_in_bounds")
+
+        for start, stop, width in tail_plan:
+            count = stop - start
+            tile = _segment_tile(count, width, _gather_tiles(width, lanes)[0])
+            if count <= tile:
+                out = add(out, start, count, width, start)
+                continue
+
+            def body(i, out, start=start, stop=stop, width=width, tile=tile):
+                first = start + i * tile
+                return add(out, jnp.minimum(first, stop - tile), tile, width,
+                           first)
+
+            out = jax.lax.fori_loop(0, -(-count // tile), body, out)
+        return out
+
     def spmv_gather(rows_of, lanes, nbr, msk, order, ovf):
         # Σ_{u∈N(v)} rows_of(u): the padded part, each vertex's first
         # max_degree neighbors as dense [rows, slots] id tiles, + an
@@ -339,7 +386,8 @@ def make_colorful_count_fn(tpl, k, mesh: WorkerMesh,
         # order: a segment's tiles take their rows of nbr and msk by
         # ``order`` and only the segment's ``width`` slots of them (the
         # slots past it hold mask 0 on every row of the segment), and
-        # set their sums back into vertex order.
+        # set their sums back into vertex order; and the "segment" tail
+        # goes as rows too (tail_by_rows).
         # ``rows_of(ids)`` gives the float32 ``[..., lanes]`` rows of
         # vertices ``ids``; the result is ``[n_loc, lanes]``.
         n_loc = nbr.shape[0]
@@ -369,7 +417,11 @@ def make_colorful_count_fn(tpl, k, mesh: WorkerMesh,
                                _segment_tile(stop - start, width,
                                              _gather_tiles(width, lanes)[0]))
         with jax.named_scope("subgraph.tail"):
+            if by_rows:
+                return tail_by_rows(rows_of, lanes, out, *ovf)
             if overflow_algo == "segment":
+                # the program without a plan alone (the planned one
+                # returned above): the flat tail, an add an entry
                 o_nbr, o_row, o_msk = ovf
                 m = o_nbr.shape[0]
                 if m <= e_tile:
@@ -480,9 +532,19 @@ def make_colorful_count_fn(tpl, k, mesh: WorkerMesh,
                         packed = C.allgather(  # Harp step
                             _pack_colors(colors, k))
 
+                    # one word a vertex (a chunk's colourings fit 32
+                    # bits): a vector; XLA re-lays a [n, 1] table out
+                    # inside every loop that gathers from it, 33 in the
+                    # cell's program (0.10 s of its 3.72 s block, PERF.md
+                    # section 6, PR 41).  The program without a plan and
+                    # the "onehot" arm stay as they were
+                    if by_rows and packed.shape[1] == 1:
+                        packed = packed[:, 0]
+
                     def rows_of(ids):
                         got = _unpack_colors(
-                            jnp.take(packed, ids, axis=0), k, T)
+                            jnp.take(packed, ids, axis=0).reshape(
+                                ids.shape + (-1,)), k, T)
                         return jnp.pad(_alone(got, k),
                                        ((0, 0),) * (got.ndim - 1) + widen)
                 else:
@@ -572,9 +634,16 @@ class SubgraphConfig:
     seed: int = 0
     # The exact tail for adjacency past max_degree, two formulations
     # (bitwise-equal keeps per tile/segment ordering aside; tested):
-    # "segment" — sorted segment-sum over the overflow edge list (the
-    # shipped default; v5e scatters small rows at ~25 GB/s, the sorted
-    # lowering is the cheap mitigant);
+    # "segment" — the shipped default.  In the installed program (a graph
+    # whose rows differ in degree: degree_plan) the tail goes as rows of
+    # at most max_degree slots, staged by their entries and gathered and
+    # summed as the padded part is, one add a row into its owner
+    # (_tail_rows; the cell's 54.9M tail entries: 0.79 s a block where
+    # the per-entry scatter-add below took 2.84, PERF.md section 6, PR
+    # 41).  In the program without a plan, a sorted segment-sum over the
+    # overflow edge list, entry by entry (24-27 ns an entry on a v5e; the
+    # sorted lowering is the cheap mitigant of its ~25 GB/s small-row
+    # scatter floor): the plain arm the tests compare against;
     # "onehot"  — the mfsgd/lda pattern: overflow entries grouped into
     # (entry_tile × row_tile) tiles, each applied as ONE one-hot MXU
     # matmul into a dynamic-sliced block (trades ~2·TE·R·S flops per
@@ -642,6 +711,55 @@ def _partition_overflow(overflow, n_pad, nw):
         o_nbr[w, m_pad - t:] = nbrs[idx][order]
         o_msk[w, m_pad - t:] = 1.0
     return o_nbr.reshape(-1), o_row.reshape(-1), o_msk.reshape(-1)
+
+
+def _tail_rows(ovf, nw, max_degree):
+    """The flat tail of :func:`_partition_overflow` cut into *tail rows*
+    for the planned program: every vertex's tail entries, in the order
+    they have there, fill rows of at most ``max_degree`` slots (the last
+    of a vertex partial), and each worker's rows are staged by their
+    real entries, fewest first (stable: equal counts by owner), padded
+    with empty rows in front to the most any worker has (>= 1).
+
+    Returns ``((t_nbr [nw*R, max_degree], t_own [nw*R] worker-LOCAL
+    vertex rows, t_msk [nw*R, max_degree]), plan, rows)``: the plan is
+    :func:`degree_plan`'s over the staged rows' counts (``()`` for no
+    tail), so a segment is a contiguous slice of rows and of their first
+    ``width`` columns; ``rows`` counts the rows that hold entries."""
+    cut = []
+    for nbrs, owners, real in zip(*(a.reshape(nw, -1) for a in ovf)):
+        t = int(np.count_nonzero(real))  # padding first, then the entries
+        nbrs, owners = nbrs[len(nbrs) - t:], owners[len(owners) - t:]
+        # the run of each vertex: where it starts, how long it is, and
+        # the rows it fills, each full but the last
+        starts = np.flatnonzero(np.concatenate(
+            [[t > 0], owners[1:] != owners[:-1]]))
+        lengths = np.diff(starts, append=t)
+        n_rows = -(-lengths // max_degree)
+        counts = np.full(int(n_rows.sum()), max_degree)
+        counts[np.cumsum(n_rows) - 1] = lengths - (n_rows - 1) * max_degree
+        cut.append((nbrs, counts, np.repeat(owners[starts], n_rows)))
+    most = max(1, max(len(counts) for _, counts, _ in cut))
+    slots = np.arange(max_degree)
+    t_nbr = np.zeros((nw, most, max_degree), np.int32)
+    t_own = np.zeros((nw, most), np.int32)
+    ranked = np.zeros((nw, most), np.int64)
+    for w, (nbrs, counts, owners) in enumerate(cut):
+        front = (most - len(counts), 0)  # empty rows: the fewest of all
+        counts, owners = np.pad(counts, front), np.pad(owners, front)
+        # by owner first: the entries fill their rows one after another,
+        # each row from its first slot
+        by_owner = np.zeros((most, max_degree), np.int32)
+        by_owner[slots < counts[:, None]] = nbrs
+        order = np.argsort(counts, kind="stable")
+        t_nbr[w], t_own[w], ranked[w] = (
+            by_owner[order], owners[order], counts[order])
+    rows = sum(len(counts) for _, counts, _ in cut)
+    # a row's entries come first: its mask is its count
+    t_msk = np.empty((nw * most, max_degree), np.float32)
+    np.less(slots, ranked.reshape(-1, 1), out=t_msk)
+    return ((t_nbr.reshape(nw * most, max_degree), t_own.reshape(-1), t_msk),
+            degree_plan(ranked, max_degree) if rows else (), rows)
 
 
 def _partition_overflow_tiles(overflow, n_pad, nw, row_tile, entry_tile):
@@ -721,11 +839,11 @@ class SubgraphCounter:
     ``train_epochs`` shape of MF-SGD, for ``edu.iu.subgraph``).
 
     ``set_graph`` does the host's work once: the padded CSR, the exact
-    tail's partition, the rows' degree order with its plan, and the
-    placement.  ``count_colorings`` is one dispatch and one readback:
-    the block's colorings are drawn on the device from ``(cfg.seed,
-    block)`` (:func:`block_colors`), and ``block_colors(b)`` says which
-    colors block ``b`` uses.
+    tail's partition, the rows' degree order with its plan, the tail's
+    rows with theirs, and the placement.  ``count_colorings`` is one
+    dispatch and one readback: the block's colorings are drawn on the
+    device from ``(cfg.seed, block)`` (:func:`block_colors`), and
+    ``block_colors(b)`` says which colors block ``b`` uses.
     """
 
     def __init__(self, cfg: SubgraphConfig, mesh: WorkerMesh | None = None):
@@ -744,7 +862,7 @@ class SubgraphCounter:
         self.chunk = max(1, min(cfg.n_trials, cfg.trial_chunk))
         self.blocks_run = 0      # the next block's index
         self.colorings_run = 0
-        self._graph = None
+        self._graph = None       # (nbr, msk) on the mesh
         self._key = prng.key_bits(cfg.seed)
         self._fn = None          # built by set_graph, for the graph's plan
         self._colors_fn = None
@@ -786,21 +904,33 @@ class SubgraphCounter:
                 else:
                     order = (order.astype(np.int32).reshape(-1),)
             tail = ovf[2]
-            executed = nw * plan_slots(self.plan) + tail.size
+            # the planned program sums the "segment" tail as rows: its
+            # own three arrays, and the flat ones wait on the host for
+            # whoever asks installed() for them
+            self.tail_plan, staged, n_tail_rows = None, ovf, 0
+            if plan is not None and not tiled:
+                with telemetry.span("subgraph.tail_rows"):
+                    staged, self.tail_plan, n_tail_rows = _tail_rows(
+                        ovf, nw, cfg.max_degree)
+            tail_executed = tail.size if self.tail_plan is None \
+                else nw * plan_slots(self.tail_plan)
+            executed = nw * plan_slots(self.plan) + tail_executed
             if attrs is not None:  # telemetry on
                 # known only now: the tail's size, what is placed, and
                 # the slots a neighbor sum gathers of those staged
                 attrs.update(
                     overflow_entries=len(overflow), bytes=sum(
-                        a.nbytes for a in (nbr, msk, *ovf, *order)),
-                    slots_staged=msk.size + tail.size,
-                    slots_executed=executed, segments=len(self.plan))
+                        a.nbytes for a in (nbr, msk, *staged, *order)),
+                    slots_staged=msk.size + staged[2].size,
+                    slots_executed=executed, segments=len(self.plan),
+                    tail_rows=n_tail_rows, tail_slots_executed=tail_executed,
+                    tail_segments=len(self.tail_plan or ()))
                 # ingest skew record (utils/skew.py): real adjacency
                 # entries per vertex-partition worker vs the slots a
                 # neighbor sum executes for them, padded part and tail
                 # together — powerlaw graphs are exactly where "one
                 # worker holds the hub" shows up (it widens every
-                # worker's last segment)
+                # worker's last segment, and the tail's)
                 skew.record_partition(
                     "subgraph.partition",
                     # the tail's in float64: a float32 total of 1e8 ones
@@ -808,29 +938,44 @@ class SubgraphCounter:
                     counts.sum(1)
                     + tail.reshape(nw, -1).sum(1, dtype=np.float64),
                     unit="edges", padded_total=executed)
-            self._graph = tuple(mesh.shard_array(a, 0)
-                                for a in (nbr, msk, *ovf))
+            self._graph = tuple(mesh.shard_array(a, 0) for a in (nbr, msk))
+            self._tail = tuple(mesh.shard_array(a, 0) for a in staged)
+            self._flat = ovf if staged is not ovf else None
             self._order = tuple(mesh.shard_array(a, 0) for a in order)
         self._fn = make_colorful_count_fn(
             self.tpl, self.k, mesh, cfg.overflow_algo,
-            cfg.overflow_row_tile, draw_trials=self.chunk, plan=plan)
+            cfg.overflow_row_tile, draw_trials=self.chunk, plan=plan,
+            tail_plan=self.tail_plan)
         self.n_vertices, self.n_pad = n_vertices, n_pad
         self.overflow_entries = len(overflow)
         return self.overflow_entries
 
-    def installed(self):
-        """The installed arrays on the mesh: ``(nbr, msk, *tail)``, in
-        vertex order (the degree order is the counter's own)."""
+    def _held(self):
         if self._graph is None:
             raise RuntimeError("call set_graph() first")
         return self._graph
+
+    def _args(self):
+        """What the counter's own program takes before the colours."""
+        return self._held() + self._tail + self._order
+
+    def installed(self):
+        """The installed arrays on the mesh: ``(nbr, msk, *tail)``, in
+        vertex order, the tail as :func:`_partition_overflow` (or
+        ``_tiles``) made it: what the program without a plan takes.  The
+        degree order and the tail's rows are the counter's own: where
+        its program reads those, the flat tail waits on the host and is
+        placed for whoever asks here."""
+        graph = self._held()
+        return graph + (self._tail if self._flat is None else tuple(
+            self.mesh.shard_array(a, 0) for a in self._flat))
 
     # -- run ----------------------------------------------------------------
     def block_colors(self, block: int | None = None):
         """The colors block ``block`` (default: the next one) counts
         under: int32 ``[chunk, n_vertices]`` on the device, by the
         program's own :func:`block_colors`."""
-        self.installed()
+        self._held()
         if self._colors_fn is None:
             n, n_pad, chunk, k = self.n_vertices, self.n_pad, self.chunk, self.k
             self._colors_fn = flightrec.track(jax.jit(
@@ -840,7 +985,7 @@ class SubgraphCounter:
             self._key, np.int32(self.blocks_run if block is None else block))
 
     def _dispatch(self):
-        out = self._fn(*self.installed(), *self._order,
+        out = self._fn(*self._args(),
                        (self._key, np.int32(self.blocks_run)))
         self.blocks_run += 1
         self.colorings_run += self.chunk
@@ -870,12 +1015,13 @@ def count_template(edges, n_vertices, cfg: SubgraphConfig,
 
     Returns ``(estimate, per_trial_estimates, overflow_edges)`` —
     ``overflow_edges`` counts adjacency entries past ``cfg.max_degree``,
-    which are handled EXACTLY by the segment-sum side path (nothing is
-    dropped; the count is a perf diagnostic — a tail entry costs more
-    than a padded slot, PERF.md section 5, and since the padded part
-    gathers only the slots its rows hold, raising ``max_degree`` costs
-    resident bytes, ``8 * n`` a slot, and no gathers).  A thin caller of
-    :class:`SubgraphCounter`:
+    which are handled EXACTLY by the tail (nothing is dropped; the count
+    is a perf diagnostic — in the installed program a tail slot costs
+    what a padded slot costs and a tail row one add more, PERF.md
+    section 5; only the program without a plan still pays 24-27 ns an
+    entry — and since both parts gather only the slots their rows hold,
+    raising ``max_degree`` costs resident bytes, ``8 * n`` a slot, and
+    no gathers).  A thin caller of :class:`SubgraphCounter`:
     install, then ``n_trials`` colorings in equal chunks (one compile).
     """
     counter = SubgraphCounter(cfg, mesh)

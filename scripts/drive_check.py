@@ -2776,3 +2776,46 @@ print(f"subgraph degree order: {len(_do_c.plan)} segments "
       f"{[w for _, _, w in _do_c.plan]}, {_do_slots:,} of 6,400,000 padded "
       f"slots gathered, counts = the whole width's")
 print("DRIVE OK round-42")
+
+# --- round-43 (PR 41): the exact tail is summed as rows too.  The CLI's
+# powerlaw graph (100,000 vertices, zipf-1.3 sources, padded to 64: a
+# third of the entries past 64, one hub of tens of thousands) through the
+# public pair on four workers, at the program's own tiles: the tail is
+# staged as rows of at most 64 slots in the order of their entries, the
+# plan is the widest over the workers, the five installed arrays are the
+# flat ones still, and the counts are those of the program without a plan
+# to float32 summation order: that program adds the hub's 200,000 entries
+# to one float32 row one by one, the rows add 64 at a time and then 3,000
+# row sums (they differ by 1.3e-5 here; 6e-8 x the entries is the bound).
+_tr_rng = np.random.default_rng(5)
+_tr_edges = np.stack([(_tr_rng.zipf(1.3, 800_000) - 1) % 100_000,
+                      _tr_rng.integers(0, 100_000, 800_000)], 1)
+_tr_mesh = WorkerMesh(jax.devices()[:4])
+_tr_c = SG.SubgraphCounter(SG.SubgraphConfig(
+    template="u5-tree", n_trials=2, trial_chunk=2, max_degree=64, seed=4),
+    _tr_mesh)
+_tr_tail = _tr_c.set_graph(_tr_edges, 100_000)
+_tr_deg = np.bincount(_tr_edges.ravel(), minlength=100_000)
+assert _tr_tail == np.maximum(_tr_deg - 64, 0).sum() > 400_000
+_tr_plan = _tr_c.tail_plan
+_tr_rows = sum(-(-int(d - 64) // 64) for d in _tr_deg if d > 64)
+assert _tr_plan[0][0] == 0 and all(w % 8 == 0 for _, _, w in _tr_plan)
+assert _tr_tail <= 4 * SG.plan_slots(_tr_plan)
+_tr_t_nbr, _tr_t_own, _tr_t_msk = _tr_c._tail
+assert _tr_t_nbr.shape == _tr_t_msk.shape == (4 * _tr_plan[-1][1], 64)
+assert int(np.asarray(_tr_t_msk).sum()) == _tr_tail
+assert int((np.asarray(_tr_t_msk).sum(1) > 0).sum()) == _tr_rows
+_tr_nbr, _tr_msk, *_tr_flat = _tr_c.installed()
+assert len(_tr_flat) == 3 and _tr_flat[0].ndim == 1
+assert int(np.asarray(_tr_flat[2]).sum()) == _tr_tail
+_tr_whole = SG.make_colorful_count_fn(_tr_c.tpl, _tr_c.k, _tr_mesh,
+                                      draw_trials=2)
+_tr_want = np.asarray(_tr_whole(_tr_nbr, _tr_msk, *_tr_flat,
+                                (_tr_c._key, np.int32(0))))
+_tr_got = _tr_c.count_colorings()
+np.testing.assert_allclose(_tr_got, _tr_want, rtol=1e-4)
+print(f"subgraph tail rows: {_tr_tail:,} entries past 64 as {_tr_rows:,} "
+      f"rows, {len(_tr_plan)} segments {[w for _, _, w in _tr_plan]}, "
+      f"{4 * SG.plan_slots(_tr_plan):,} slots gathered on 4 workers, "
+      f"counts = the flat tail's")
+print("DRIVE OK round-43")

@@ -104,12 +104,12 @@ def gathers_and_scatters(hlo: str) -> int:
     return len(re.findall(r" = \S+ (?:gather|scatter)\(", hlo))
 
 
-def padded_part_loops(hlo: str) -> list:
-    """``[rows, tile_rows, slots]`` of every ``while`` loop of a compiled
-    module's text that carries a segment of the degree order (a 1-D
-    ``s32`` array) and gathers table rows by a ``[tile_rows, slots]`` tile
-    of neighbour ids inside its body (``f32[tile_rows, slots, 128]`` rows
-    or ``u32[tile_rows, slots]`` packed colours)."""
+def _tile_loops(hlo: str):
+    """``(while line, [[tile_rows, slots], ...])`` of every ``while`` loop
+    of a compiled module's text that gathers table rows by a
+    ``[tile_rows, slots]`` tile of neighbour ids inside its body
+    (``f32[tile_rows, slots, 128]`` rows or ``u32[tile_rows, slots]``
+    packed colours)."""
     import re
 
     comps = {m.group(1): m.group(0) for m in re.finditer(
@@ -124,23 +124,46 @@ def padded_part_loops(hlo: str) -> list:
                 reach(callee, seen)
         return seen
 
-    found = []
     for line in hlo.splitlines():
-        m = re.search(r"^(.*) while\(.*body=%([\w.\-]+)", line)
+        m = re.search(r"^.* while\(.*body=%([\w.\-]+)", line)
         if not m:
             continue
-        carried = re.findall(r" s32\[(\d+)\]", m.group(1))
-        body = "".join(comps[c] for c in reach(m.group(2), set()))
+        body = "".join(comps[c] for c in reach(m.group(1), set()))
         tiles = re.findall(r" = (?:f32\[(\d+),(\d+),128\]|u32\[(\d+),(\d+)\])"
                            r"\S* gather\(", body)
-        for a, b, c, d in tiles:
-            found.append([int(carried[0]), int(a or c), int(b or d)])
-    return found
+        yield line, [[int(a or c), int(b or d)] for a, b, c, d in tiles]
+
+
+def _under_the_tail(line: str) -> bool:
+    return "subgraph.tail/while" in line
+
+
+def padded_part_loops(hlo: str) -> list:
+    """``[rows, tile_rows, slots]`` of every ``while`` loop of a compiled
+    module's text that carries a segment of the degree order (a 1-D
+    ``s32`` array) and gathers by it (:func:`_tile_loops`), the tail's
+    loops, which carry their rows' owners, set aside by their scope."""
+    import re
+
+    return [[int(re.search(r"[ /]s32\[(\d+)\]", line).group(1)), *tile]
+            for line, tiles in _tile_loops(hlo)
+            if not _under_the_tail(line) for tile in tiles]
+
+
+def tail_loops(hlo: str) -> list:
+    """``[tile_rows, slots]`` of every ``while`` loop under the scope
+    ``subgraph.tail`` that gathers (:func:`_tile_loops`): the tail rows'
+    tiles (the text must carry its ``op_name``s)."""
+    return [tile for line, tiles in _tile_loops(hlo)
+            if _under_the_tail(line) for tile in tiles]
+
+
 def _compile_all() -> dict:
     """Every check, in the child: {check name: {program: mosaic calls}}."""
     import functools
     import math
     import re
+    import time
 
     import jax
     import jax.numpy as jnp
@@ -353,32 +376,44 @@ def _compile_all() -> dict:
     scfg = perf_spec.load_json(os.path.join(
         ROOT, "perf", "configs", "subgraph-orkut-u5.json"))
     n, deg = scfg["data"]["n_vertices"], scfg["knobs"]["max_degree"]
-    tail, chunk = 54_903_737, scfg["knobs"]["trial_chunk"]
-    plan = subgraph.degree_plan(np.sort(np.minimum(
-        graph_like.degree_sequence(scfg["data"]), deg))[None], deg)
+    chunk = scfg["knobs"]["trial_chunk"]
+    degrees = graph_like.degree_sequence(scfg["data"])
+    plan = subgraph.degree_plan(np.sort(np.minimum(degrees, deg))[None], deg)
+    # the tail's rows by their entries, as `_tail_rows` stages them: every
+    # vertex's entries past 128 in rows of 128, the last partial
+    past = np.maximum(degrees - deg, 0)
+    tail_plan = subgraph.degree_plan(np.sort(np.concatenate(
+        [np.full(int((past // deg).sum()), deg),
+         past[past % deg > 0] % deg]))[None], deg)
+    tail = tail_plan[-1][1]
     def subgraph_block():
         subgraph._FN_CACHE.clear()  # a program is traced under its names
         return subgraph.make_colorful_count_fn(
             subgraph.TEMPLATES[scfg["knobs"]["template"]],
             scfg["knobs"]["n_colors"], mesh, scfg["knobs"]["overflow_algo"],
-            draw_trials=chunk, plan=plan).lower(
+            draw_trials=chunk, plan=plan, tail_plan=tail_plan).lower(
                 sds((n, deg), jnp.int32), sds((n, deg), jnp.float32),
-                sds((tail,), jnp.int32), sds((tail,), jnp.int32),
-                sds((tail,), jnp.float32), sds((n,), jnp.int32),
+                sds((tail, deg), jnp.int32), sds((tail,), jnp.int32),
+                sds((tail, deg), jnp.float32), sds((n,), jnp.int32),
                 (jax.ShapeDtypeStruct((2,), jnp.uint32,
                                       sharding=mesh.replicated()),
                  jax.ShapeDtypeStruct((), jnp.int32,
                                       sharding=mesh.replicated()))).compile()
 
+    started = time.perf_counter()
     compiled = subgraph_block()
+    compile_s = time.perf_counter() - started
     mem, hlo = compiled.memory_analysis(), compiled.as_text()
     with _without_scopes():
         bare = subgraph_block().as_text()
     out["subgraph_cell"] = {
         "trial_chunk": chunk,
         "plan": plan,
+        "tail_plan": tail_plan,
+        "compile_s": round(compile_s, 1),
         "padded_part_loops": padded_part_loops(hlo),
-        "resident_bytes": 8 * n * deg + 12 * tail + 4 * n,
+        "tail_loops": tail_loops(hlo),
+        "resident_bytes": 8 * n * deg + (8 * deg + 4) * tail + 4 * n,
         "argument_bytes": mem.argument_size_in_bytes,
         "temp_bytes": mem.temp_size_in_bytes,
         "gathers": len(re.findall(r" = \S+ gather\(", hlo)),
@@ -463,7 +498,8 @@ def test_lda_cell_sweep_compiles_for_v5e_and_fits(compiled):
         "subgraph.allgather", "subgraph.convolve", "subgraph.count",
         "subgraph.draw", "subgraph.order.put", "subgraph.order.take",
         "subgraph.padded", "subgraph.singleton", "subgraph.sum.leaf",
-        "subgraph.sum.t3", "subgraph.tail"]),
+        "subgraph.sum.t3", "subgraph.tail", "subgraph.tail.add",
+        "subgraph.tail.rows"]),
 ])
 def test_cell_programs_scopes_change_names_only_on_v5e(compiled, cell,
                                                       scopes):
@@ -544,26 +580,34 @@ def test_mlp_cell_epochs_gather_nothing(compiled):
 
 def test_subgraph_cell_block_compiles_for_v5e_and_fits(compiled):
     """One block of ``subgraph-colorings`` at the cell's shapes (com-Orkut's
-    3,072,441 vertices, 8 colourings): the 3.8 GB resident graph and its
-    12 MB degree order are the arguments; the tables of the dynamic
-    program, widened to whole 128-lane rows where they are gathered and
-    scattered, and one gather tile at a time are the temporaries, and
-    the executable holds under 12 GB in all.  Two distinct sub-templates
-    are summed over neighbours (the leaf once, not three times), each
-    over the plan's 16 segments and the tail, every one a loop over
-    tiles: 34 loops; a segment's loop gathers its tile's rows of ``nbr``
-    and of ``msk`` and the table's rows and sets the sums back into
-    vertex order, the tail's gathers and scatter-adds: 98 gathers, 34
-    scatters.  The largest gathered intermediate is a tile's 4,096 x 128
-    rows of 128 lanes (256 MiB) and not ``[n, 128, columns]``."""
+    3,072,441 vertices, 8 colourings): the 3.9 GB resident graph (the
+    padded part, the tail's 718,773 rows of 128 slots with their owners)
+    and its 12 MB degree order are the arguments; the tables of the
+    dynamic program, widened to whole 128-lane rows where they are
+    gathered and scattered, and one gather tile at a time are the
+    temporaries (5.8 GB; 6.8 GB while the tail was a scatter-add of
+    524,288 entries a tile), and the executable holds under 12 GB in
+    all.  Two distinct sub-templates are summed over neighbours (the
+    leaf once, not three times), each over the plan's 16 segments and the
+    tail plan's 16, every one a loop over tiles: 64 loops; a padded
+    segment's loop gathers its tile's rows of ``nbr`` and of ``msk`` and
+    the table's rows and sets the sums back into vertex order, a tail
+    segment's slices its tile's rows, gathers the table's and adds the
+    sums to their owners: 128 gathers, 64 scatters.  The largest
+    gathered intermediate is a tile's 4,091 x 128 rows of 128 lanes
+    (under the 256 MiB a tile may take) and not ``[n, 128, columns]``.
+    It compiles here in 26-30 s (17.5-21.6 s with 34 loops, PR 39; 34-36
+    s on the chip's host, PERF.md section 6, PR 41)."""
     cell = compiled["subgraph_cell"]
     assert cell["mosaic_calls"] == 0
     assert cell["resident_bytes"] <= cell["argument_bytes"] \
         < 1.001 * cell["resident_bytes"]
     assert cell["resident_bytes"] > 3.8e9 and cell["trial_chunk"] == 8
     assert (cell["argument_bytes"] + cell["temp_bytes"]) / 1e9 < 12.0
-    assert (cell["gathers"], cell["scatters"], cell["loops"]) == (98, 34, 34)
-    assert cell["largest_gathered"] * 4 == 256 << 20
+    assert cell["temp_bytes"] < 6.5e9
+    assert (cell["gathers"], cell["scatters"], cell["loops"]) == (128, 64, 64)
+    assert cell["largest_gathered"] == 4091 * 128 * 128 < (256 << 20) // 4
+    assert 5 < cell["compile_s"] < 240
 
 
 def test_subgraph_cell_block_gathers_the_plans_slots(compiled):
@@ -591,6 +635,28 @@ def test_subgraph_cell_block_gathers_the_plans_slots(compiled):
     assert 2 * 188_526_608 <= gathered < 1.001 * 2 * 188_526_608
 
 
+def test_subgraph_cell_block_gathers_the_tail_plans_slots(compiled):
+    """The tail likewise: every segment of its plan one loop in each of
+    the two sums, over tiles of ``[rows, width]`` ids sliced from the
+    staged tail rows, the rows ``_segment_tile``'s: 56,582,336 slots a sum
+    and under 1% more for the last tiles moved back (masked, not added
+    twice), where the flat tail's 105 tiles scatter-added 54,903,737
+    entries one by one."""
+    from harp_tpu.models import subgraph
+
+    cell = compiled["subgraph_cell"]
+    plan = [tuple(seg) for seg in cell["tail_plan"]]
+    assert len(plan) == 16 and subgraph.plan_slots(plan) == 56_582_336
+    assert plan[-1][1] == 718_773
+    want = [[subgraph._segment_tile(
+        stop - start, width, subgraph._gather_tiles(width, 128)[0]), width]
+        for start, stop, width in plan]
+    assert sorted(cell["tail_loops"]) == sorted(want + want)
+    gathered = sum(-(-(stop - start) // tile) * tile * width
+                   for (start, stop, width), (tile, _) in zip(plan, want))
+    assert 56_582_336 <= gathered < 1.01 * 56_582_336
+
+
 def test_padded_part_loops_reads_an_hlo_text():
     hlo = """
 %gathers (p: f32[100,128], i: s32[8,16]) -> f32[8,16,128] {
@@ -611,6 +677,11 @@ ENTRY %main () -> f32[] {
 }
 """
     assert padded_part_loops(hlo) == [[50, 8, 16]]
+    assert tail_loops(hlo) == []
+    named = hlo.replace(
+        "body=%body.1", 'body=%body.1, metadata={op_name="jit(f)/'
+        'subgraph.sum.t3/subgraph.tail/while"}')
+    assert padded_part_loops(named) == [] and tail_loops(named) == [[8, 16]]
 
 
 def test_gathers_and_scatters_reads_an_hlo_text():

@@ -116,7 +116,7 @@ def test_row_tiled_neighbour_sum_equals_the_untiled(algo, toy_edges,
     tiled.set_graph(toy_edges, 300)
     assert tiled._fn is not whole._fn
     assert tiled.plan == ((0, 186, 8), (186, 300, 16))
-    text = tiled._fn.lower(*tiled.installed(), *tiled._order,
+    text = tiled._fn.lower(*tiled._args(),
                            (tiled._key, np.int32(0))).as_text()
     assert "while" in text
     assert (_blocks(tiled, 2) == want).all()
@@ -153,25 +153,42 @@ def _all_rows_full():
                       np.int32), 16
 
 
-@pytest.mark.parametrize("graph", ["toy", "hub", "full"])
+def _tail_rows_longhand(o_nbr, o_row, o_msk, cap):
+    """One worker's tail rows, a vertex at a time: ``[(entries, owner,
+    [neighbours])]`` in the order they are staged (fewest entries first,
+    equal counts by owner, a vertex's rows in its tail's order)."""
+    rows = []
+    for v in np.unique(o_row[o_msk > 0]):
+        mine = o_nbr[(o_row == v) & (o_msk > 0)].tolist()
+        rows += [(len(mine[i:i + cap]), int(v), mine[i:i + cap])
+                 for i in range(0, len(mine), cap)]
+    return sorted(rows, key=lambda r: r[:2])
+
+
+@pytest.mark.parametrize("graph", ["toy", "hub", "hub-wide", "full"])
 @pytest.mark.parametrize("algo", ["segment", "onehot"])
-@pytest.mark.parametrize("workers", [1, 4])
+@pytest.mark.parametrize("workers", [1, 4, 8])
 def test_degree_ordered_sum_equals_the_whole_width(workers, algo, graph,
                                                    toy_edges, monkeypatch):
     """The installed program (rows in degree order, each segment at its
-    own width) against the program without a plan (every row at
-    ``max_degree``, in vertex order) on the same installed arrays and
-    colours: counts of this size are whole numbers in float32, the same
-    bits.  The plan is the widest over the workers: the hub's worker
-    widens every worker's last segment."""
+    own width, and under ``"segment"`` the tail as rows of at most
+    ``max_degree`` slots, in the order of THEIR entries, each row's sum
+    added to its owner) against the program without a plan (every row at
+    ``max_degree``, in vertex order, the flat sorted tail scatter-added
+    entry by entry) on the same installed graph and colours: counts of
+    this size are whole numbers in float32, the same bits.  The plans
+    are the widest over the workers: the hub's worker widens every
+    worker's last segment.  ``hub-wide`` is the hub graph at a
+    ``max_degree`` that holds the hub: a plan and no tail."""
     edges, n = {"toy": (toy_edges, 300), "hub": _hub_on_one_worker(),
+                "hub-wide": _hub_on_one_worker(),
                 "full": _all_rows_full()}[graph]
     monkeypatch.setattr(SG, "_gather_tiles", lambda slots, width: (8, 64))
     SG._FN_CACHE.clear()
     mesh = WorkerMesh(jax.devices()[:workers])
     cfg = _cfg(overflow_algo=algo, overflow_row_tile=8,
                overflow_entry_tile=16,
-               max_degree=8 if graph == "full" else 24)
+               max_degree={"full": 8, "hub-wide": 64}.get(graph, 24))
     counter = SG.SubgraphCounter(cfg, mesh)
     counter.set_graph(edges, n)
     loc, plan = counter.n_pad // workers, counter.plan
@@ -179,8 +196,11 @@ def test_degree_ordered_sum_equals_the_whole_width(workers, algo, graph,
     assert all(a[1] == b[0] and a[2] < b[2] for a, b in zip(plan, plan[1:]))
     msk = np.asarray(counter.installed()[1])
     counts = (msk > 0).sum(1).reshape(workers, loc)
-    if graph == "full":
-        assert plan == ((0, loc, 8),) and counter._order == ()
+    # every row in one tile (the hub graph's 8 a worker on 8) or every
+    # row full: one segment of the whole width, the program without a plan
+    whole_width = graph == "full" or (workers == 8 and "hub" in graph)
+    if whole_width:
+        assert plan == ((0, loc, cfg.max_degree),) and counter._order == ()
     else:
         (order,) = counter._order
         order = np.asarray(order).reshape(workers, loc)
@@ -188,11 +208,49 @@ def test_degree_ordered_sum_equals_the_whole_width(workers, algo, graph,
         ranked = np.take_along_axis(counts, order, 1)
         assert (np.diff(ranked, axis=1) >= 0).all()
         for start, stop, width in plan:
-            assert width % 8 == 0 and width <= 24
+            assert width % 8 == 0 and width <= cfg.max_degree
             assert ranked[:, start:stop].max() <= width
     if graph == "hub" and workers == 4:
         assert plan == ((0, 15, 8), (15, 16, 24)) \
             and counts.max(1).tolist() == [24, 3, 3, 3]
+    flat = tuple(np.asarray(a).reshape(workers, -1)
+                 for a in counter.installed()[2:])
+    if algo == "onehot" or whole_width:
+        # the program's own tail is what installed() hands out
+        assert counter.tail_plan is None
+        assert all(a is b for a, b in zip(counter.installed()[2:],
+                                          counter._tail))
+    else:
+        t_nbr, t_own, t_msk = (np.asarray(a).reshape(
+            (workers, -1) + a.shape[1:]) for a in counter._tail)
+        tail_plan, cap = counter.tail_plan, cfg.max_degree
+        staged = t_msk.sum(2).astype(int)
+        assert t_nbr.shape == t_msk.shape == staged.shape + (cap,)
+        assert (t_msk[:, :, :-1] >= t_msk[:, :, 1:]).all()
+        assert (np.diff(staged, axis=1) >= 0).all()  # fewest first
+        most = 0
+        for w in range(workers):
+            want = _tail_rows_longhand(*(a[w] for a in flat), cap)
+            most = max(most, len(want))
+            front = staged.shape[1] - len(want)  # empty rows in front
+            assert not staged[w, :front].any() and front >= 0
+            assert [(c, o, nb[:c].tolist()) for c, o, nb in zip(
+                staged[w, front:], t_own[w, front:], t_nbr[w, front:])
+            ] == want
+        assert staged.shape[1] == max(most, 1)
+        if graph == "hub-wide":
+            assert tail_plan == () and counter.overflow_entries == 0
+        else:
+            assert tail_plan[0][0] == 0 \
+                and tail_plan[-1][1] == staged.shape[1]
+            assert all(a[1] == b[0] and a[2] < b[2]
+                       for a, b in zip(tail_plan, tail_plan[1:]))
+            for start, stop, width in tail_plan:
+                assert width % 8 == 0 and width <= cap
+                assert staged[:, start:stop].max() <= width
+        if graph == "hub" and workers == 4:  # 39 past 24, one worker
+            assert tail_plan == ((0, 2, 24),) \
+                and staged.tolist() == [[15, 24], [0, 0], [0, 0], [0, 0]]
     whole = SG.make_colorful_count_fn(
         counter.tpl, counter.k, mesh, algo, cfg.overflow_row_tile,
         draw_trials=counter.chunk)
@@ -228,6 +286,103 @@ def test_degree_plan_at_the_configurations_size(workers):
     assert 1 - 234_370_166 / (slots + 54_903_737) < 0.04
 
 
+@pytest.mark.parametrize("workers", [1, 4])
+def test_tail_plan_at_the_configurations_size(workers):
+    """The tail's plan as ``_tail_rows`` makes it, from the configuration's
+    degree sequence alone: the 54,903,737 entries past 128 are 249,431
+    full rows and 469,342 partial ones, 718,773 in all; on one worker 16
+    segments of widths 8...128 whose 56,582,336 slots are the partial rows
+    rounded up to 8 and nothing more (3.0% padding where the flat tail
+    had none: the layout's price), over four workers 11 segments (the
+    widest over the workers) of 58,678,688."""
+    data = _configuration_data()
+    past = np.maximum(graph_like.degree_sequence(data) - 128, 0)
+    assert past.sum() == 54_903_737
+    past = np.pad(past, (0, -len(past) % workers)).reshape(workers, -1)
+    rows = [np.sort(np.concatenate(
+        [np.full(int((p // 128).sum()), 128), p[p % 128 > 0] % 128]))
+        for p in past]
+    assert sum(int((r == 128).sum()) for r in rows) == 249_431
+    assert sum(int((r < 128).sum()) for r in rows) == 469_342
+    most = max(len(r) for r in rows)
+    counts = np.stack([np.pad(r, (most - len(r), 0)) for r in rows])
+    plan = SG.degree_plan(counts, 128)
+    assert plan[0][0] == 0 and plan[-1][1] == most
+    assert all(a[1] == b[0] for a, b in zip(plan, plan[1:]))
+    for start, stop, width in plan:
+        assert counts[:, start:stop].max() <= width
+    slots = workers * SG.plan_slots(plan)
+    assert (most, len(plan), slots) == {
+        1: (718_773, 16, 56_582_336), 4: (179_988, 11, 58_678_688)}[workers]
+    if workers == 1:
+        assert [w for _, _, w in plan] == list(range(8, 129, 8))
+        assert [stop - start for start, stop, _ in plan][::5] == [
+            55_767, 33_509, 21_186, 261_775]
+        assert slots == sum(int((-(-r // 8) * 8).sum()) for r in rows)
+        # every tile on the fast gather rate, none over 32,768 rows
+        tiles = [SG._segment_tile(stop - start, w, SG._gather_tiles(w, 128)[0])
+                 for start, stop, w in plan]
+        assert tiles[:2] + tiles[-1:] == [27_888, 24_968, 4_091]
+        assert all(t * w % 128 == 0 and t * w // 128 % 8
+                   for t, (_, _, w) in zip(tiles, plan))
+    # what the skew record then states of a neighbour sum's slots, padded
+    # part and tail: 4.4% padding (5.2% over four workers), 3.7% before
+    padded = {1: 188_526_608, 4: 188_653_376}[workers]
+    assert 1 - 234_370_166 / (padded + slots) < {1: 0.0439, 4: 0.0525}[workers]
+
+
+def test_tail_rows_of_an_empty_tail_are_one_empty_row_and_no_plan():
+    none = (np.zeros(4, np.int32), np.zeros(4, np.int32),
+            np.zeros(4, np.float32))  # four workers' padding, no entry
+    (t_nbr, t_own, t_msk), plan, rows = SG._tail_rows(none, 4, 8)
+    assert (plan, rows) == ((), 0)
+    assert t_nbr.shape == t_msk.shape == (4, 8) and t_own.shape == (4,)
+    assert not t_msk.any()
+    # one worker's single vertex with 17 entries past 8: rows of 8, 8, 1,
+    # the fewest first, and the other worker's two empty rows in front
+    one = (np.asarray([0, 0] + [0] * 17 + list(range(1, 18)), np.int32),
+           np.asarray([0] * 19 + [5] * 17, np.int32),
+           np.asarray([0] * 19 + [1] * 17, np.float32))
+    (t_nbr, t_own, t_msk), plan, rows = SG._tail_rows(one, 2, 8)
+    assert (plan, rows) == (((0, 3, 8),), 3)
+    assert t_msk.sum(1).tolist() == [0, 0, 0, 1, 8, 8]
+    assert t_own.tolist() == [0, 0, 0, 5, 5, 5]
+    assert t_nbr[3:].tolist() == [[17] + [0] * 7, list(range(1, 9)),
+                                  list(range(9, 17))]
+
+
+def test_a_graph_without_a_tail_gets_a_program_without_a_tail_loop(
+        monkeypatch):
+    """The hub graph at a ``max_degree`` that holds the hub: rows of 3 and
+    one of 63, so a plan, and an empty tail plan: no instruction of the
+    compiled program stands under ``subgraph.tail``'s inner names, where
+    the same graph cut at 24 has them inside loops."""
+    monkeypatch.setattr(SG, "_gather_tiles", lambda slots, width: (8, 64))
+    mesh = WorkerMesh(jax.devices()[:1])
+    edges, n = _hub_on_one_worker()
+
+    def compiled(max_degree):
+        SG._FN_CACHE.clear()
+        counter = SG.SubgraphCounter(_cfg(max_degree=max_degree), mesh)
+        counter.set_graph(edges, n)
+        return counter, counter._fn.lower(
+            *counter._args(), (counter._key, np.int32(0))).compile().as_text()
+
+    counter, text = compiled(64)
+    assert counter.plan == ((0, 63, 8), (63, 64, 64))
+    assert counter.tail_plan == () and "subgraph.tail." not in text
+    counter, text = compiled(24)
+    assert counter.tail_plan == ((0, 2, 24),)
+    assert "subgraph.tail/subgraph.tail.rows" in text \
+        and "subgraph.tail/subgraph.tail.add" in text
+    # shorter than a tile: one gather and one add, no loop
+    assert "subgraph.tail/while" not in text
+    with pytest.raises(ValueError, match="tail's plan"):
+        SG.make_colorful_count_fn(counter.tpl, counter.k, mesh,
+                                  plan=counter.plan)
+    SG._FN_CACHE.clear()
+
+
 def test_degree_plan_merges_short_segments_and_keeps_full_rows_whole():
     # two rows of 3 are no tile of 32,768 x 8 slots: they ride with the 16s
     few = np.asarray([[3, 3] + [12] * 70_000 + [40] * 10])
@@ -258,10 +413,12 @@ def test_segment_tiles_are_even_and_no_multiple_of_8_groups(rows, slots,
         assert tile * slots % 128 == 0 and tile * slots // 128 % 8
 
 
-def test_installed_returns_the_five_arrays_as_they_were_staged(toy_edges):
+def test_installed_returns_the_five_arrays_as_they_were_staged(toy_edges,
+                                                               monkeypatch):
     """The order is the counter's own: ``installed()`` is ``(nbr, msk,
     o_nbr, o_row, o_msk)`` in vertex order, row ``v`` of ``msk`` holding
     ``min(degree[v], max_degree)`` ones, the tail the rest."""
+    monkeypatch.setattr(SG, "_gather_tiles", lambda slots, width: (8, 64))
     deg = graph_like.degree_sequence(TOY)
     counter = SG.SubgraphCounter(_cfg(max_degree=16),
                                  WorkerMesh(jax.devices()[:4]))
@@ -273,6 +430,21 @@ def test_installed_returns_the_five_arrays_as_they_were_staged(toy_edges):
     assert ((msk > 0).sum(1) == np.minimum(deg, 16)).all()
     assert (msk[:, :-1] >= msk[:, 1:]).all()  # a row's entries come first
     assert np.asarray(o_msk).sum() == np.maximum(deg - 16, 0).sum()
+    # the counter's program reads its own tail rows; what is handed out
+    # is the flat tail as the program without a plan takes it (each
+    # worker's local rows ascending, padding in front), placed when asked,
+    # and the benchmark's check (a) adds up on it: every vertex's entries
+    # past max_degree
+    assert counter.tail_plan and o_nbr is not counter._tail[0]
+    local = np.asarray(o_row).reshape(4, -1)
+    assert (np.diff(local, axis=1) >= 0).all() and local.max() < 75
+    rows = (local + 75 * np.arange(4)[:, None]).reshape(-1)
+    in_tail = np.zeros(300, np.int64)
+    np.add.at(in_tail, rows, np.asarray(o_msk) > 0)
+    assert (in_tail == np.maximum(deg - 16, 0)).all()
+    again = counter.installed()
+    assert all((np.asarray(a) == np.asarray(b)).all()
+               for a, b in zip(again, (nbr, msk, o_nbr, o_row, o_msk)))
 
 
 def test_block_colour_query_returns_the_colours_the_block_used(toy_edges):
@@ -375,7 +547,9 @@ def test_install_and_run_leave_their_spans_and_records(toy_edges,
                                                        monkeypatch):
     """At ``max_degree`` 16 and tiles of 8 rows a worker's 75 rows are
     two segments, 41 rows at 8 slots and 34 at 16: 3,488 padded slots
-    over the four workers where 4,800 are staged."""
+    over the four workers where 4,800 are staged; and the 706 entries
+    past 16 are 71 tail rows, at most 22 on a worker, staged as 4 x 22
+    rows of 16 slots and summed as one segment of 16: 1,408 slots."""
     monkeypatch.setattr(SG, "_gather_tiles", lambda slots, width: (8, 64))
     SG._FN_CACHE.clear()  # the ledger prices a program when it is traced
     with telemetry.scope(True):
@@ -388,22 +562,28 @@ def test_install_and_run_leave_their_spans_and_records(toy_edges,
         install = by_name["subgraph.install"][0]
         assert (install["vertices"], install["entries"],
                 install["overflow_entries"]) == (300, 3000, 706)
-        tail = counter.installed()[2].size
         assert counter.plan == ((0, 41, 8), (41, 75, 16))
+        assert counter.tail_plan == ((0, 22, 16),)
+        assert [a.shape for a in counter._tail] == [(88, 16), (88,), (88, 16)]
         assert (install["segments"], install["slots_staged"],
-                install["slots_executed"]) == (2, 4800 + tail, 3488 + tail)
-        # the five arrays and the order
-        assert install["bytes"] == 8 * 300 * 16 + 12 * tail + 4 * 300
+                install["slots_executed"]) == (2, 4800 + 1408, 3488 + 1408)
+        assert (install["tail_segments"], install["tail_rows"],
+                install["tail_slots_executed"]) == (1, 71, 1408)
+        # nbr and msk, the tail's rows with their owners, and the order:
+        # what is placed; the flat tail stays on the host
+        assert install["bytes"] == 8 * 300 * 16 + (8 * 16 + 4) * 88 + 4 * 300
         for child in ("subgraph.pad_csr", "subgraph.overflow",
-                      "subgraph.order", "mesh.shard_array"):
+                      "subgraph.order", "subgraph.tail_rows",
+                      "mesh.shard_array"):
             assert all(r["path"].startswith("subgraph.install/")
                        for r in by_name[child])
         assert len(by_name["mesh.shard_array"]) == 6
         assert [r["trials"] for r in by_name["subgraph.colorings"]] == [4] * 3
-        # the skew record states the slots a neighbour sum executes
+        # the skew record states the slots a neighbour sum executes, the
+        # tail's as the rows gather them
         rec = skew.ledger.summary()["subgraph.partition"]
         assert rec["padding_frac"] == pytest.approx(
-            1 - 3000 / (3488 + tail))
+            1 - 3000 / (3488 + 1408))
         # two distinct child shapes, two allgathers of a worker's 75
         # rows: the leaf's packed colours (one word for the 4
         # colourings), the star's 10 columns x 4 colourings; and the
@@ -411,12 +591,18 @@ def test_install_and_run_leave_their_spans_and_records(toy_edges,
         led = telemetry.ledger.summary()["subgraph.colorings"]
         assert led["executions"] == 3
         assert led["bytes_per_execution"] == 4 * (75 * (1 + 10 * 4) + 4)
+        # asked for, the flat tail is placed: three more placements,
+        # outside the install
+        tail = counter.installed()[2].size
+        assert tail >= 706 and tail % 4 == 0
+        assert len(_spans_by_name()["mesh.shard_array"]) == 9
     SG._FN_CACHE.clear()
 
 
 def test_rows_all_full_install_as_before(toy_edges):
     """At ``max_degree`` 8 and the program's own tiles the toy graph's 300
-    rows are one segment of the whole width: five placements, no order,
+    rows are one segment of the whole width: the program without a plan,
+    which takes the flat tail: five placements, no order, no tail rows,
     and executed slots = staged slots."""
     with telemetry.scope(True):
         counter = SG.SubgraphCounter(_cfg(), WorkerMesh(jax.devices()[:4]))
@@ -425,11 +611,16 @@ def test_rows_all_full_install_as_before(toy_edges):
         install = by_name["subgraph.install"][0]
         tail = counter.installed()[2].size
         assert counter.plan == ((0, 75, 8),) and counter._order == ()
+        assert counter.tail_plan is None
+        assert counter.installed()[2] is counter._tail[0]
         assert install["slots_executed"] == install["slots_staged"] \
             == 300 * 8 + tail
-        assert install["segments"] == 1
+        assert (install["segments"], install["tail_segments"],
+                install["tail_rows"], install["tail_slots_executed"]) \
+            == (1, 0, 0, tail)
         assert install["bytes"] == 8 * 300 * 8 + 12 * tail
-        assert len(by_name["mesh.shard_array"]) == 5
+        assert "subgraph.tail_rows" not in by_name
+        assert len(_spans_by_name()["mesh.shard_array"]) == 5
         assert skew.ledger.summary()["subgraph.partition"][
             "padding_frac"] == pytest.approx(1 - 3000 / (300 * 8 + tail))
 
